@@ -121,6 +121,13 @@ class ClusterMgr:
     # either the old snapshot + its WAL tail or the new snapshot with the
     # old WAL keys deleted in the same atomic batch — never a double replay.
 
+    @property
+    def kv_engine(self) -> str:
+        """Which kvstore engine holds this clustermgr's state ("native" |
+        "python"; "none" when not persisted) — the auto-open falls back to the
+        Python engine where no toolchain exists, and operators should see it."""
+        return self._db.engine if self._db is not None else "none"
+
     @staticmethod
     def _wal_key(seq: int) -> bytes:
         return b"w/%020d" % seq

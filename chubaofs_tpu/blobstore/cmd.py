@@ -124,8 +124,14 @@ def add_admin_routes(router, cluster, runner: ModuleRunner | None = None):
                         json.dumps(data).encode())
 
     def stat(req):
+        from chubaofs_tpu.ops import device
+
         cm = cluster.cm
         return _json({
+            # platform / device_kind / device_count / lowering of the process
+            # doing the EC math, and the engine under clustermgr
+            "device": device.describe(),
+            "kv_engine": cm.kv_engine,
             "disks": len(cm.disks),
             "broken_disks": [d.disk_id for d in cm.broken_disks()],
             "volumes": len(cm.volumes),

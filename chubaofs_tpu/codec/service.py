@@ -12,8 +12,11 @@ service is the TPU-native replacement:
     device batch, runs ONE fused-kernel call, then scatters results back;
   * shard lengths are bucketed to powers of two (>= 16 KiB) so the jit cache
     stays small and the MXU sees few distinct shapes;
-  * with no accelerator (or in tests), the same code runs on the CPU backend —
-    same numerics, same API.
+  * the lowering is the process's, decided once from the resolved backend
+    (rs.lowering): the compiled fused kernel on a TPU, the XLA einsum when CPU
+    was asked for (tests). A backend that fails to initialise fails the job;
+    nothing falls back. cfs_codec_lowering_jobs_total{lowering=...} says which
+    one did the math.
 
 Batching trades a bounded latency (max_wait_ms) for throughput, exactly like the
 reference's proxy-side volume-allocation batching — but for math instead of
@@ -440,6 +443,11 @@ class CodecService:
             # on the device (bench_repair and the kill soak read this)
             reg.counter("kind_jobs_total", {"kind": kind}).add(jobs)
             reg.counter("kind_batches_total", {"kind": kind}).add()
+        # which lowering did the math: a daemon on the TPU and one that was
+        # asked for the CPU must not look the same from /metrics
+        lowering = (self._mesh_mm.lowering if self._mesh_mm is not None
+                    else rs.lowering())
+        reg.counter("lowering_jobs_total", {"lowering": lowering}).add(jobs)
         reg.summary("batch_jobs", buckets=BATCH_BUCKETS).observe(jobs)
         reg.summary("dispatch_seconds").observe(elapsed_s)
 

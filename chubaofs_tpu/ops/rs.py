@@ -65,31 +65,28 @@ def gf_matmul_bytes(mat_bits: jax.Array, shards: jax.Array) -> jax.Array:
     return pack_bits(acc & 1)
 
 
+FUSED = "pallas-fused"  # ops/pallas_gf.py, compiled by Mosaic (TPU only)
+EINSUM = "xla-einsum"  # gf_matmul_bytes above: what an explicit CPU request runs
+
+
+@functools.cache
+def lowering() -> str:
+    """The GF matmul lowering this process serves with, decided ONCE from the
+    resolved default backend: the compiled fused Pallas kernel on TPU, the
+    XLA einsum on anything else (CPU is an explicit request — tests, the
+    dryrun mesh). A backend that fails to initialise raises here; it is never
+    read as "not a TPU". ops/device.describe() and the codec's per-lowering
+    job counter make the answer visible from outside the process."""
+    return FUSED if jax.default_backend() == "tpu" else EINSUM
+
+
 def _use_fused() -> bool:
-    """The fused Pallas kernel runs on real TPU backends only; the XLA einsum
-    path serves CPU (tests, host fallback) and sharded tracing."""
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return lowering() == FUSED
 
 
 def gf_matmul_dispatch(mat_bits: jax.Array, shards: jax.Array) -> jax.Array:
-    """Pick the fastest available lowering for a standalone (non-traced) call."""
+    """The process's lowering (see ``lowering``) for a standalone call."""
     if _use_fused():
-        import os
-
-        pipe = os.environ.get("CFS_GF_PIPELINED", "")
-        if pipe in ("1", "static"):
-            # manual-DMA double-buffered variant (PERF.md headroom #1);
-            # opt-in until the bench proves it beats streaming fusion.
-            # "static" selects the static-slot plan-B lowering for chips
-            # where Mosaic rejects dynamic scratch indexing (kernel_ab's
-            # verdict names the variant to use).
-            from chubaofs_tpu.ops import pallas_gf_pipe
-
-            return pallas_gf_pipe.gf_matmul_bytes_pipelined(
-                mat_bits, shards, static_slots=pipe == "static")
         from chubaofs_tpu.ops import pallas_gf
 
         return pallas_gf.gf_matmul_bytes_fused(mat_bits, shards)
@@ -103,7 +100,8 @@ def group_stack(mat_bits: np.ndarray, batch: int) -> tuple[np.ndarray, int]:
     128x128 systolic array; kron(I_g, mat) over g stripes viewed as one wide
     (g*n, k) stripe raises encode from 54 to ~130 GB/s on v5e-1. g divides
     batch and respects the 128-row / 512-col caps (pallas_gf.pick_group);
-    g == 1 (and the matrix unchanged) off-TPU or for indivisible batches.
+    g == 1 (and the matrix unchanged) under the einsum lowering or for
+    indivisible batches.
     """
     mat_bits = np.asarray(mat_bits, np.int8)
     if not _use_fused() or mat_bits.shape[0] == 0:

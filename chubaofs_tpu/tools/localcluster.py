@@ -30,8 +30,6 @@ def launch(args) -> "ProcCluster":
         datanodes=args.datanodes,
         blobstore=args.blobstore or args.objectnode,
         objectnode=args.objectnode,
-        # config, not env: cmd.py prefers cfg['jaxPlatform'] and ProcCluster
-        # defaults it to cpu, so an env-only request would be silently lost
         jax_platform=args.jax_platform or None,
     )
 
@@ -49,7 +47,10 @@ def main(argv=None) -> int:
     p.add_argument("--objectnode", action="store_true",
                    help="also run the S3 gateway (implies --blobstore backing)")
     p.add_argument("--jax-platform", default="",
-                   help="force the daemons' JAX platform (e.g. cpu)")
+                   help="JAX platform of the blobstore daemon (e.g. cpu); "
+                        "default JAX_PLATFORMS, else JAX's own default — the "
+                        "TPU on a TPU host. Every other role runs on CPU: a "
+                        "chip belongs to one process")
     p.add_argument("--volume", default="",
                    help="create this volume once nodes register")
     args = p.parse_args(argv)

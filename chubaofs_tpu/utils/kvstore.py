@@ -46,9 +46,19 @@ _lib_lock = threading.Lock()
 
 
 def _build_native() -> bool:
+    """`make` the engine from the tracked sources — a no-op when the library
+    is fresh, a rebuild when kvstore.cc is newer than a library left on disk
+    (build/ is git-ignored, so a checkout never ships one). The flock keeps
+    daemons that boot together from loading a half-written library."""
+    import fcntl
+
+    build_dir = os.path.dirname(_SO_PATH)
     try:
-        subprocess.run(["make", "-C", os.path.abspath(_NATIVE_DIR)],
-                       check=True, capture_output=True, timeout=120)
+        os.makedirs(build_dir, exist_ok=True)
+        with open(os.path.join(build_dir, ".lock"), "w") as lk:
+            fcntl.flock(lk, fcntl.LOCK_EX)
+            subprocess.run(["make", "-C", os.path.abspath(_NATIVE_DIR)],
+                           check=True, capture_output=True, timeout=120)
         return os.path.exists(_SO_PATH)
     except (OSError, subprocess.SubprocessError):
         return False
@@ -61,7 +71,7 @@ def _load_native():
             return _lib
         if _lib_failed:
             return None
-        if not os.path.exists(_SO_PATH) and not _build_native():
+        if not _build_native():
             _lib_failed = True
             return None
         lib = ctypes.CDLL(_SO_PATH)
@@ -93,6 +103,8 @@ def _load_native():
 
 class NativeKV:
     """ctypes binding over libcfskv (the cgo-RocksDB analog)."""
+
+    engine = "native"
 
     def __init__(self, path: str):
         lib = _load_native()
@@ -181,6 +193,7 @@ class NativeKV:
 class PyKV:
     """Pure-Python engine writing the identical on-disk format."""
 
+    engine = "python"
     COMPACT_MIN_DEAD = 4 << 20
 
     def __init__(self, path: str):

@@ -99,6 +99,15 @@ def test_admin_api_and_cli(daemon, rng):
 
     stat = json.loads(run("stat"))
     assert stat["disks"] == 12 and stat["volumes"] >= 1
+    # which device and lowering did the math is visible from outside: the
+    # boot line, /admin/stat and /metrics agree (tests ask for the CPU)
+    assert stat["device"] == {"platform": "cpu", "device_kind": "cpu",
+                              "device_count": 8, "lowering": "xla-einsum"}
+    assert stat["kv_engine"] in ("native", "python")
+    assert daemon.boot_info == {**stat["device"], "kv_engine": stat["kv_engine"]}
+    from chubaofs_tpu.utils.exporter import render_all
+
+    assert 'cfs_codec_lowering_jobs_total{lowering="xla-einsum"}' in render_all()
 
     disks = run("disk", "ls")
     assert "DISK_ID" in disks and disks.count("\n") >= 12

@@ -130,52 +130,6 @@ def test_fused_pallas_kernel_interpret(rng):
     assert np.array_equal(got, want)
 
 
-def test_pipelined_pallas_kernel_interpret(rng):
-    """The manual-DMA double-buffered kernel (interpret mode) matches the XLA
-    lowering — multi-tile (odd AND even tile counts, exercising both skew
-    phases and the epilogue drains) plus the single-tile degenerate case.
-    Both slot strategies (dynamic indexing and the static-unrolled plan-B
-    variant) must agree."""
-    from chubaofs_tpu.ops import pallas_gf_pipe
-
-    ker = rs.get_kernel(6, 3)
-    for k in (128, 256, 384, 640):  # 1, 2, 3, 5 tiles at tile_k=128
-        data = rng.integers(0, 256, (2, 6, k), dtype=np.uint8)
-        want = np.asarray(rs.gf_matmul_bytes(ker.parity_bits, data))
-        for static in (False, True):
-            got = np.asarray(pallas_gf_pipe.gf_matmul_bytes_pipelined(
-                ker.parity_bits, data, tile_k=128, interpret=True,
-                static_slots=static))
-            assert np.array_equal(got, want), (k, static)
-
-
-def test_pipelined_kernel_group_stacked_interpret(rng):
-    """Group-stacked operands run through the pipelined kernel unchanged."""
-    from chubaofs_tpu.ops import pallas_gf_pipe
-
-    ker = rs.get_kernel(4, 2)
-    b, n, k = 4, 4, 384
-    host = rng.integers(0, 256, (b, n, k), dtype=np.uint8)
-    g = 2
-    mat_s = np.kron(np.eye(g, dtype=np.int8), ker.parity_bits)
-    want = np.asarray(rs.gf_matmul_bytes(ker.parity_bits, host))
-    got = np.asarray(pallas_gf_pipe.gf_matmul_bytes_pipelined(
-        mat_s, host.reshape(b // g, g * n, k), tile_k=128, interpret=True))
-    assert np.array_equal(got.reshape(b, 2, k), want)
-
-
-def test_pipelined_kernel_unaligned_k(rng):
-    """k not a multiple of the tile pads internally and slices back."""
-    from chubaofs_tpu.ops import pallas_gf_pipe
-
-    ker = rs.get_kernel(3, 2)
-    data = rng.integers(0, 256, (1, 3, 300), dtype=np.uint8)
-    want = np.asarray(rs.gf_matmul_bytes(ker.parity_bits, data))
-    got = np.asarray(pallas_gf_pipe.gf_matmul_bytes_pipelined(
-        ker.parity_bits, data, tile_k=128, interpret=True))
-    assert np.array_equal(got, want)
-
-
 def test_plane_major_permutation_exact():
     """pm[b*r+p, b2*n+j] must equal bits[p*8+b, j*8+b2] elementwise."""
     from chubaofs_tpu.ops import bitmatrix, pallas_gf
@@ -268,3 +222,38 @@ def test_fused_kernel_empty_repair_matrix():
     plan = ker.repair_plan([7], data_only=True)
     fixed = np.asarray(ker.apply_repair(plan, jnp.asarray(stripe)))
     assert np.array_equal(fixed, stripe)
+
+
+def test_lowering_propagates_backend_errors(monkeypatch):
+    """A backend that cannot initialise must surface, never read as "not a
+    TPU": the old _use_fused() turned any exception into the CPU einsum."""
+    import jax
+
+    def broken():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    rs.lowering.cache_clear()
+    monkeypatch.setattr(jax, "default_backend", broken)
+    try:
+        with pytest.raises(RuntimeError, match="Unable to initialize"):
+            rs._use_fused()
+        with pytest.raises(RuntimeError):
+            rs.gf_matmul_hostbatch(rs.get_kernel(4, 2).parity_bits,
+                                   np.zeros((1, 4, 128), np.uint8))
+    finally:
+        rs.lowering.cache_clear()
+
+
+def test_lowering_decided_once_from_the_backend(monkeypatch):
+    import jax
+
+    rs.lowering.cache_clear()
+    try:
+        assert rs.lowering() == rs.EINSUM and not rs._use_fused()  # tests: cpu
+        rs.lowering.cache_clear()
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert rs.lowering() == rs.FUSED and rs._use_fused()
+        monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+        assert rs.lowering() == rs.FUSED  # once per process
+    finally:
+        rs.lowering.cache_clear()
