@@ -34,6 +34,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from chubaofs_tpu import chaos
+from chubaofs_tpu.blobstore import trace
 from chubaofs_tpu.blobstore.blobnode import BlobNode
 from chubaofs_tpu.blobstore.clustermgr import ClusterMgr, VolumeInfo
 from chubaofs_tpu.blobstore.proxy import Proxy
@@ -278,12 +279,13 @@ class Access:
     # -- PUT -----------------------------------------------------------------
 
     def put(self, data: bytes, code_mode: CodeMode | int | None = None) -> Location:
-        from chubaofs_tpu.blobstore import trace
-
         if self.qos is not None and not self.qos.wait("put", len(data), timeout=self.qos_timeout):
             registry("access").counter("qos_reject", {"op": "put"}).add()
             raise AccessError("put bandwidth limit exceeded")
-        with trace.child_of(trace.current_span(), "access.put") as span, \
+        # the stage is entered first: it lands on the CALLER's span (the
+        # gateway's request span), not on the child span it mirrors
+        with trace.stage("access.put"), \
+                trace.child_of(trace.current_span(), "access.put") as span, \
                 registry("access").tp("put"):
             span.set_tag("size", len(data))
             err: Exception | None = None
@@ -300,27 +302,19 @@ class Access:
                                err=type(err).__name__ if err else "")
 
     def _put(self, data: bytes, code_mode: CodeMode | int | None = None) -> Location:
-        from chubaofs_tpu.blobstore import trace
-
         if not data:
             raise AccessError("empty put")
-        span = trace.current_span()
-        t_prep = time.perf_counter()
-        mode = (
-            int(code_mode)
-            if code_mode is not None
-            else int(select_code_mode(len(data), self.policies))
-        )
-        loc = Location(cluster_id=self.cluster_id, code_mode=mode, size=len(data), crc=zlib.crc32(data))
-
-        blobs = [data[i : i + self.max_blob_size]
-                 for i in range(0, len(data), self.max_blob_size)]
-        if span is not None:  # crc + blob split: the host-prepare stage
-            span.add_stage("prepare", start=t_prep)
-        t_alloc = time.perf_counter()
-        first_bid, _ = self._alloc_breaker.call(self.proxy.alloc_bids, len(blobs))
-        if span is not None:
-            span.add_stage("alloc", start=t_alloc)
+        with trace.stage("access.prepare"):  # crc + blob split
+            mode = (
+                int(code_mode)
+                if code_mode is not None
+                else int(select_code_mode(len(data), self.policies))
+            )
+            loc = Location(cluster_id=self.cluster_id, code_mode=mode, size=len(data), crc=zlib.crc32(data))
+            blobs = [data[i : i + self.max_blob_size]
+                     for i in range(0, len(data), self.max_blob_size)]
+        with trace.stage("access.alloc"):
+            first_bid, _ = self._alloc_breaker.call(self.proxy.alloc_bids, len(blobs))
         t = get_tactic(mode)
         window = int(self.pipeline_window)
         if window >= 1 and len(blobs) > 1:
@@ -376,30 +370,21 @@ class Access:
         """Pre-pipeline path (pipeline_window=0 or single blob): encode all
         blobs first (they batch inside the codec service), then fan shard
         writes out per blob, one blob at a time."""
-        from chubaofs_tpu.blobstore import trace
-
-        span = trace.current_span()
         futures = []
         metas = []
         for i, blob in enumerate(blobs):
-            t_alloc = time.perf_counter()
-            vol = self._alloc_breaker.call(self.proxy.alloc_volume, mode)
-            if span is not None:
-                span.append_track_log("proxy", start=t_alloc)
-                span.add_stage("alloc", start=t_alloc)
+            with trace.stage("access.alloc", track="proxy"):
+                vol = self._alloc_breaker.call(self.proxy.alloc_volume, mode)
             futures.append(self._encode_blob(t, blob))
             metas.append((first_bid + i, vol, len(blob)))
 
         out = []
         for fut, (bid, vol, size) in zip(futures, metas):
-            t_enc = time.perf_counter()
-            stripe = fut.result()  # (total, shard_len), locals included
-            if span is not None:
-                span.append_track_log("codec", start=t_enc)
-                # wait-for-stripe: codec queue + device batch, as the PUT
-                # experiences it (the codec side adds its own host/device
-                # sub-stages to the same span)
-                span.add_stage("encode", start=t_enc)
+            # wait-for-stripe: codec queue + device batch, as the PUT
+            # experiences it (the codec side adds its own codec.* stages to
+            # the same span)
+            with trace.stage("access.encode_wait", track="codec"):
+                stripe = fut.result()  # (total, shard_len), locals included
             vol = self._write_blob(t, mode, vol, bid, stripe)
             out.append(Blob(bid=bid, vid=vol.vid, size=size))
         return out
@@ -415,14 +400,10 @@ class Access:
         not yet started are skipped (no orphaned writes, no repair-queue spam
         for blobs the client will never see), in-flight ones finish, and the
         first failing blob's error is raised."""
-        from chubaofs_tpu.blobstore import trace
-
         span = trace.current_span()
         if span is not None:  # pipeline shape rides the span record
             span.set_tag("pipeline_window", window)
             span.set_tag("encode_ahead", self.encode_ahead)
-        reg = registry("access")
-        occ = reg.summary("put_pipeline_occupancy", buckets=BATCH_BUCKETS)
         abort = threading.Event()
         vols: list[VolumeInfo | None] = [None] * len(blobs)
         write_secs = [0.0] * len(blobs)
@@ -435,13 +416,10 @@ class Access:
             if span is not None:
                 trace.push_span(span)
             try:
-                t_enc = time.perf_counter()
-                stripe = enc_fut.result()
-                if span is not None:
-                    span.append_track_log("codec", start=t_enc)
-                    # encode-ahead wait as THIS stage saw it (queue depth
-                    # already bought most of it during older blobs' writes)
-                    span.add_stage("encode", start=t_enc)
+                # encode-ahead wait as THIS stage saw it (queue depth
+                # already bought most of it during older blobs' writes)
+                with trace.stage("access.encode_wait", track="codec"):
+                    stripe = enc_fut.result()
                 if abort.is_set():
                     raise _PipelineAborted()
                 t_w = time.perf_counter()
@@ -494,15 +472,11 @@ class Access:
                 encode_up_to(i + ahead)
                 # alloc for blob i rides the caller thread while blob i-1's
                 # (and older, up to the window) fan-outs are still in flight
-                t_alloc = time.perf_counter()
-                vol = self._alloc_breaker.call(self.proxy.alloc_volume, mode)
-                if span is not None:
-                    span.append_track_log("proxy", start=t_alloc)
-                    span.add_stage("alloc", start=t_alloc)
+                with trace.stage("access.alloc", track="proxy"):
+                    vol = self._alloc_breaker.call(self.proxy.alloc_volume, mode)
                 inflight.append(
                     (i, self._pipe_pool.submit(stage, i, enc_futs.pop(i), vol,
                                                first_bid + i)))
-                occ.observe(len(inflight))
         except BaseException:
             # a CALLER-side failure mid-window (alloc breaker open, cluster
             # can't place a volume) must honor the same abort contract as a
@@ -531,25 +505,22 @@ class Access:
         wall = time.perf_counter() - t_wall
         busy = sum(write_secs)
         if wall > 0 and busy > 0:
-            reg.summary("put_overlap_ratio",
-                        buckets=BATCH_BUCKETS).observe(busy / wall)
-        reg.counter("put_pipeline_blobs").add(len(blobs))
+            registry("access").summary(
+                "put_overlap_ratio", buckets=BATCH_BUCKETS).observe(busy / wall)
         return [Blob(bid=first_bid + i, vid=vols[i].vid, size=len(b))
                 for i, b in enumerate(blobs)]
 
     def _write_stripe(self, t, vol: VolumeInfo, bid: int, stripe: np.ndarray):
-        from chubaofs_tpu.blobstore import trace
         from chubaofs_tpu.blobstore.blobnode import ChunkFull
 
-        # the stripe-write fan-out is the blobnode hop as the gateway sees
-        # it; one track entry covers the whole shard fan-out (stream_put.go
-        # logs the same aggregate)
-        span = trace.current_span()
-        t_hop = time.perf_counter()
         deadline = time.monotonic() + self.write_deadline
         started = [False] * t.total
 
-        def write_one(idx: int):
+        def write_one(idx: int, t_submit: float):
+            # write workers carry no request span: their stages join a
+            # request by falling inside its access.write_stripe interval
+            trace.observe_stage("access.pool_wait", t_submit,
+                                time.perf_counter() - t_submit)
             started[idx] = True
             unit = vol.units[idx]
             if self._is_punished(unit.disk_id):
@@ -557,7 +528,9 @@ class Access:
             node = self.nodes[unit.node_id]
             sem = self._sem(unit.disk_id)
             budget = deadline - time.monotonic()
-            if budget <= 0 or not sem.acquire(timeout=budget):
+            with trace.mark("access.sem_wait"):
+                got = budget > 0 and sem.acquire(timeout=budget)
+            if not got:
                 # concurrency cap exhausted within the deadline: the disk is
                 # wedged — punish it so later PUTs fail fast
                 self.punish_disk(unit.disk_id, "cap_exhausted")
@@ -575,23 +548,25 @@ class Access:
                 sem.release()
             return idx
 
-        futs = [self._pool.submit(self._try, write_one, i) for i in range(t.total)]
-        results = []
-        for idx, f in enumerate(futs):
-            budget = deadline + 0.25 - time.monotonic()  # workers self-deadline
-            try:
-                results.append(f.result(timeout=max(0.01, budget)))
-            except FutureTimeout:
-                # a RUNNING write that outlives the deadline is the wedged-disk
-                # signal (stream_put.go:343-346 punishDiskWith on timeout); a
-                # task still queued behind a busy pool says nothing about its
-                # disk — punishing it would blacklist healthy devices
-                if started[idx]:
-                    self.punish_disk(vol.units[idx].disk_id, "timeout")
-                results.append(TimeoutError("stripe write deadline"))
-        if span is not None:
-            span.append_track_log("blobnode", start=t_hop)
-            span.add_stage("write", start=t_hop)  # whole shard fan-out
+        # the stripe-write fan-out is the blobnode hop as the gateway sees
+        # it; one track entry covers the whole shard fan-out (stream_put.go
+        # logs the same aggregate)
+        with trace.stage("access.write_stripe", track="blobnode"):
+            futs = [self._pool.submit(self._try, write_one, i, time.perf_counter())
+                    for i in range(t.total)]
+            results = []
+            for idx, f in enumerate(futs):
+                budget = deadline + 0.25 - time.monotonic()  # workers self-deadline
+                try:
+                    results.append(f.result(timeout=max(0.01, budget)))
+                except FutureTimeout:
+                    # a RUNNING write that outlives the deadline is the wedged-disk
+                    # signal (stream_put.go:343-346 punishDiskWith on timeout); a
+                    # task still queued behind a busy pool says nothing about its
+                    # disk — punishing it would blacklist healthy devices
+                    if started[idx]:
+                        self.punish_disk(vol.units[idx].disk_id, "timeout")
+                    results.append(TimeoutError("stripe write deadline"))
         ok = {i for i, r in zip(range(t.total), results) if r is None}
         failed = sorted(set(range(t.total)) - ok)
         # quorum counts global-stripe shards only (stream_put.go:226,362:
@@ -635,8 +610,6 @@ class Access:
     # -- GET -----------------------------------------------------------------
 
     def get(self, loc: Location | str, offset: int = 0, size: int | None = None) -> bytes:
-        from chubaofs_tpu.blobstore import trace
-
         if isinstance(loc, str):
             loc = Location.from_json(loc)
         if self.qos is not None:
@@ -645,7 +618,8 @@ class Access:
             if not self.qos.wait("get", max(1, want), timeout=self.qos_timeout):
                 registry("access").counter("qos_reject", {"op": "get"}).add()
                 raise AccessError("get bandwidth limit exceeded")
-        with trace.child_of(trace.current_span(), "access.get") as span, \
+        with trace.stage("access.get"), \
+                trace.child_of(trace.current_span(), "access.get") as span, \
                 registry("access").tp("get"):
             err: Exception | None = None
             try:
@@ -660,35 +634,30 @@ class Access:
                                err=type(err).__name__ if err else "")
 
     def _get(self, loc: Location | str, offset: int = 0, size: int | None = None) -> bytes:
-        from chubaofs_tpu.blobstore import trace
+        with trace.stage("access.prepare"):  # location parse + sig check + range plan
+            if isinstance(loc, str):
+                loc = Location.from_json(loc)
+            self._check_sig(loc)
+            if size is None:
+                size = loc.size - offset
+            if offset < 0 or size < 0 or offset + size > loc.size:
+                raise AccessError(f"range [{offset}, {offset+size}) outside object of {loc.size}")
+            # read-amp ledger (window bytes the CALLER asked for; the shard
+            # reads below count what the backend actually moved for them —
+            # cfs-top's RDAMP column is the window ratio of the two)
+            registry("access").counter(
+                "read_bytes", {"kind": "requested"}).add(size)
 
-        span = trace.current_span()
-        t_prep = time.perf_counter()
-        if isinstance(loc, str):
-            loc = Location.from_json(loc)
-        self._check_sig(loc)
-        if size is None:
-            size = loc.size - offset
-        if offset < 0 or size < 0 or offset + size > loc.size:
-            raise AccessError(f"range [{offset}, {offset+size}) outside object of {loc.size}")
-        # read-amp ledger (window bytes the CALLER asked for; the shard
-        # reads below count what the backend actually moved for them —
-        # cfs-top's RDAMP column is the window ratio of the two)
-        registry("access").counter(
-            "read_bytes", {"kind": "requested"}).add(size)
-
-        segs = []  # (blob, intra-blob offset, length) the range touches
-        pos = 0
-        for blob in loc.blobs:
-            blob_start, blob_end = pos, pos + blob.size
-            pos = blob_end
-            if blob_end <= offset or blob_start >= offset + size:
-                continue
-            lo = max(0, offset - blob_start)
-            hi = min(blob.size, offset + size - blob_start)
-            segs.append((blob, lo, hi - lo))
-        if span is not None:  # location parse + sig check + range plan
-            span.add_stage("prepare", start=t_prep)
+            segs = []  # (blob, intra-blob offset, length) the range touches
+            pos = 0
+            for blob in loc.blobs:
+                blob_start, blob_end = pos, pos + blob.size
+                pos = blob_end
+                if blob_end <= offset or blob_start >= offset + size:
+                    continue
+                lo = max(0, offset - blob_start)
+                hi = min(blob.size, offset + size - blob_start)
+                segs.append((blob, lo, hi - lo))
         window = int(self.pipeline_window)
         if len(segs) > 1 and window >= 1:
             return self._get_readahead(loc.code_mode, segs, window)
@@ -706,11 +675,7 @@ class Access:
         the read pool) while the current blob's bytes are consumed, bounded
         by the same pipeline window as PUT. Byte order is segment order —
         results are consumed strictly FIFO however the gathers complete."""
-        from chubaofs_tpu.blobstore import trace
-
         span = trace.current_span()
-        reg = registry("access")
-        occ = reg.summary("get_readahead_occupancy", buckets=BATCH_BUCKETS)
 
         def gather(blob, lo, n):
             if span is not None:
@@ -729,9 +694,9 @@ class Access:
                 while nxt < len(segs) and len(q) < window:
                     q.append(self._pipe_pool.submit(gather, *segs[nxt]))
                     if nxt > 0:  # segment 0 is the current read, not readahead
-                        reg.counter("get_readahead_prefetch").add()
+                        registry("access").counter(
+                            "get_readahead_prefetch").add()
                     nxt += 1
-                occ.observe(len(q))
                 out += q.popleft().result()
         except BaseException:
             for f in q:  # queued prefetches must not run for a dead request
@@ -829,30 +794,21 @@ class Access:
         # read_deadline (wedged node/disk) is treated as missing and the
         # degraded path reconstructs around it — the stall is bounded even
         # when the node never errors (stream_get races laggards the same way)
-        from chubaofs_tpu.blobstore import trace
-
-        span = trace.current_span()
-        t_hop = time.perf_counter()
         idxs = list(range(first_shard, last_shard + 1))
-        futs = [self._read_pool.submit(read_one, i) for i in idxs]
-        deadline = time.monotonic() + self.read_deadline
         pieces = []
         slow: set[int] = set()  # timed out, node possibly wedged
-        for i, f in zip(idxs, futs):
-            try:
-                pieces.append(f.result(timeout=max(0.0, deadline - time.monotonic())))
-            except FutureTimeout:
-                pieces.append(None)
-                slow.add(i)
-        if span is not None:
-            span.append_track_log("blobnode", start=t_hop)
-        if all(p is not None for p in pieces):
-            data = b"".join(pieces)
-            if span is not None:  # fan-out + reassembly: the read stage
-                span.add_stage("read", start=t_hop)
-            return data
-        if span is not None:
-            span.add_stage("read", start=t_hop)  # the failed direct attempt
+        # fan-out + reassembly (or the failed direct attempt)
+        with trace.stage("access.read", track="blobnode"):
+            futs = [self._read_pool.submit(read_one, i) for i in idxs]
+            deadline = time.monotonic() + self.read_deadline
+            for i, f in zip(idxs, futs):
+                try:
+                    pieces.append(f.result(timeout=max(0.0, deadline - time.monotonic())))
+                except FutureTimeout:
+                    pieces.append(None)
+                    slow.add(i)
+            if all(p is not None for p in pieces):
+                return b"".join(pieces)
         for f in futs:  # queued laggards must not hold pool workers
             f.cancel()
         # hand the degraded path everything the direct phase learned: the
@@ -1065,10 +1021,6 @@ class Access:
         decode the missing rows' slice exactly (RSKernel.window_matrix).
         Returns None when the gather can't reach N global survivors — deep
         damage, which the full-stripe path (with AZ-local recovery) owns."""
-        from chubaofs_tpu.blobstore import trace
-
-        span = trace.current_span()
-        t_gather = time.perf_counter()
         first = offset // shard_len
         last = (offset + size - 1) // shard_len
 
@@ -1098,23 +1050,20 @@ class Access:
                       if i not in reuse and i not in failed_direct
                       and i not in need]
         candidates.sort(key=lambda i: (i in slow, i))
-        got, gather_failed = self._gather_survivors(
-            vol, blob.bid, candidates, t.N - len(reuse), col_lo, width)
+        with trace.stage("access.gather"):  # windowed sub-reads
+            got, gather_failed = self._gather_survivors(
+                vol, blob.bid, candidates, t.N - len(reuse), col_lo, width)
         got.update(reuse)
-        if span is not None:
-            span.add_stage("gather", start=t_gather)  # windowed sub-reads
         if len(got) < t.N:
             return None  # the full path re-proves and reports damage
         present = sorted(got)[: t.N]
         survivors = np.stack(
             [np.frombuffer(got[i], np.uint8) for i in present])
-        t_dec = time.perf_counter()
-        rows = self.codec.decode_rows(t.N, t.M, present, survivors,
-                                      need).result()
+        with trace.stage("access.decode_wait"):  # row-sliced window decode
+            rows = self.codec.decode_rows(t.N, t.M, present, survivors,
+                                          need).result()
         registry("access").counter(
             "read_bytes", {"kind": "decoded"}).add(len(need) * width)
-        if span is not None:
-            span.add_stage("decode", start=t_dec)  # row-sliced window decode
         # assemble: verbatim direct-phase bytes, decoded rows sliced to each
         # missing shard's own sub-window
         rowpos = {i: p for p, i in enumerate(need)}
@@ -1146,23 +1095,18 @@ class Access:
         stripe alone can't reach N and the mode carries local parities,
         AZ-local stripes are tried next (work_shard_recover.go:517
         recoverByLocalStripe applied at READ time)."""
-        from chubaofs_tpu.blobstore import trace
-
-        span = trace.current_span()
-        t_gather = time.perf_counter()
         total = t.N + t.M
         # data shards first (they skip the matmul); known-wedged ones last
         order = sorted(range(total), key=lambda i: (i in slow, i))
         gather_deadline = time.monotonic() + self.write_deadline
-        got, failed = self._gather_survivors(vol, blob.bid, order, t.N,
-                                             0, shard_len)
         stripe = np.zeros((total, shard_len), np.uint8)
         present: list[int] = []
-        for i, data in got.items():
-            stripe[i] = np.frombuffer(data, np.uint8)
-            present.append(i)
-        if span is not None:
-            span.add_stage("gather", start=t_gather)  # hedged stripe reads
+        with trace.stage("access.gather"):  # hedged stripe reads
+            got, failed = self._gather_survivors(vol, blob.bid, order, t.N,
+                                                 0, shard_len)
+            for i, data in got.items():
+                stripe[i] = np.frombuffer(data, np.uint8)
+                present.append(i)
         # the repair plane must hear about everything the gather PROVED
         # damaged — including shards the local-stripe pass then fixes only
         # in memory (they are still broken on disk). Shards the hedge never
@@ -1177,13 +1121,11 @@ class Access:
             raise AccessError(
                 f"blob {blob.bid}: only {len(present)} shards readable, need {t.N}"
             )
-        t_dec = time.perf_counter()
-        fixed = self.codec.reconstruct_tactic(
-            t, stripe, missing, data_only=True).result()
+        with trace.stage("access.decode_wait"):  # on-the-fly reconstruct
+            fixed = self.codec.reconstruct_tactic(
+                t, stripe, missing, data_only=True).result()
         registry("access").counter("read_bytes", {"kind": "decoded"}).add(
             sum(shard_len for i in missing if i < t.N))
-        if span is not None:
-            span.add_stage("decode", start=t_dec)  # on-the-fly reconstruct
         self.proxy.send_shard_repair(vol.vid, blob.bid, damaged, "get_miss")
         self._probe_unread(t, vol, blob, shard_len,
                            [i for i in range(total)

@@ -34,6 +34,7 @@ import zlib
 from dataclasses import dataclass
 
 from chubaofs_tpu import chaos
+from chubaofs_tpu.blobstore import trace
 from chubaofs_tpu.blobstore.clustermgr import DISK_BROKEN, DISK_NORMAL
 from chubaofs_tpu.utils import crc32block
 from chubaofs_tpu.utils.locks import SanitizedLock
@@ -216,27 +217,38 @@ class Chunk:
         return self._size
 
     def put(self, bid: int, vuid: int, payload: bytes) -> ShardMeta:
-        framed = crc32block.encode(payload)
-        with self._lock:
-            if self._size + HEADER_LEN + len(framed) > self.max_size:
-                raise ChunkFull(self.chunk_id)
-            old = self.shards.get(bid)
-            offset = self._size
-            head = _HEADER.pack(MAGIC, bid, vuid, len(payload), 0)[:-4]
+        with trace.mark("chunk.crc"):
+            framed = crc32block.encode(payload)
+        with trace.mark("chunk.lock_wait"):
+            self._lock.acquire()
+        try:
+            return self._put_locked(bid, vuid, len(payload), framed)
+        finally:
+            self._lock.release()
+
+    def _put_locked(self, bid: int, vuid: int, size: int,
+                    framed: bytes) -> ShardMeta:
+        if self._size + HEADER_LEN + len(framed) > self.max_size:
+            raise ChunkFull(self.chunk_id)
+        old = self.shards.get(bid)
+        offset = self._size
+        head = _HEADER.pack(MAGIC, bid, vuid, size, 0)[:-4]
+        with trace.mark("chunk.write"):
             self._f.seek(offset)
             self._f.write(head + struct.pack("<I", zlib.crc32(head)) + framed)
             self._f.flush()
-            self._size = offset + HEADER_LEN + len(framed)
-            meta = ShardMeta(bid=bid, vuid=vuid, offset=offset, size=len(payload))
-            self.shards[bid] = meta
-            self.tombstones.discard(bid)  # re-put over a tombstone revives it
+        self._size = offset + HEADER_LEN + len(framed)
+        meta = ShardMeta(bid=bid, vuid=vuid, offset=offset, size=size)
+        self.shards[bid] = meta
+        self.tombstones.discard(bid)  # re-put over a tombstone revives it
+        with trace.mark("chunk.meta"):
             self._log_idx(meta)
-            if old is not None:
-                # re-put (e.g. repeated repair): release the superseded record
-                length = HEADER_LEN + crc32block.encoded_len(old.size)
-                _punch_hole(self._f.fileno(), old.offset, length)
-                self.holes += length
-            return meta
+        if old is not None:
+            # re-put (e.g. repeated repair): release the superseded record
+            length = HEADER_LEN + crc32block.encoded_len(old.size)
+            _punch_hole(self._f.fileno(), old.offset, length)
+            self.holes += length
+        return meta
 
     def get(self, bid: int, offset: int = 0, size: int | None = None) -> bytes:
         with self._lock:
@@ -563,7 +575,7 @@ class BlobNode:
         if self._iostat is not None:
             self._iostat.write_begin()
         try:
-            with self._reg.tp("shard_put"):
+            with self._reg.tp("shard_put"), trace.mark("blobnode.put_shard"):
                 chaos.failpoint("blobnode.put_shard", node=self.node_id)
                 # corrupt-on-write models a bad controller: the framing CRCs
                 # the already-flipped bytes, so only a later stripe-level
@@ -586,7 +598,7 @@ class BlobNode:
         if self._iostat is not None:
             self._iostat.read_begin()
         try:
-            with self._reg.tp("shard_get"):
+            with self._reg.tp("shard_get"), trace.mark("blobnode.get_shard"):
                 chaos.failpoint("blobnode.get_shard", node=self.node_id)
                 data = self._disk_io(
                     vuid, lambda: self._chunk(vuid).get(bid, offset, size))
@@ -619,7 +631,7 @@ class BlobNode:
         if self._iostat is not None:
             self._iostat.read_begin()
         try:
-            with self._reg.tp("shard_get"):
+            with self._reg.tp("shard_get"), trace.mark("blobnode.get_shard"):
                 # same failpoint as get_shard: wire-delay/error chaos regimes
                 # apply to beta reads and full reads alike
                 chaos.failpoint("blobnode.get_shard", node=self.node_id)

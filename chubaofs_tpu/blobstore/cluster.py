@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import os
 
+from chubaofs_tpu.blobstore import trace
 from chubaofs_tpu.blobstore.access import Access
 from chubaofs_tpu.blobstore.blobnode import BlobNode
 from chubaofs_tpu.blobstore.clustermgr import ClusterMgr
@@ -74,8 +75,10 @@ class MiniCluster:
                 pass
         dead_disks = self.scheduler.check_node_health()
         reaped = self.scheduler.reap_expired()
-        scrubbed = self.scheduler.run_scrub()
-        inspected = self.scheduler.inspect_volumes()
+        with trace.stage("scheduler.scrub"):
+            scrubbed = self.scheduler.run_scrub()
+        with trace.stage("scheduler.inspect"):
+            inspected = self.scheduler.inspect_volumes()
         polled = self.scheduler.poll_repair_topic()
         tier_msgs = self.scheduler.run_tier()
         disk_tasks = self.scheduler.check_disks()

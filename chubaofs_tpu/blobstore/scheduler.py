@@ -820,7 +820,7 @@ class RepairWorker:
     CFS_REPAIR_WINDOW stripes' survivor downloads in flight while earlier
     stripes decode on the device (the PUT pipeline's window pattern applied
     to repair-GET). Every task runs under a `scheduler.repair` span whose
-    `download` stages and the codec's `codec.host`/`codec.device` stages let
+    `download` stages and the codec's `codec.stack`/`codec.matmul` stages let
     cfs-trace prove the overlap.
     """
 

@@ -18,14 +18,28 @@ Beyond the wire-form track log, every span is a STRUCTURED record: a span id,
 its parent (in-process parent span, or the remote caller's span id carried
 next to the trace id), a wall-clock start stamp plus monotonic duration, and
 named STAGES — (name, offset, duration) attributions inside the span
-(encode device time, raft commit wait, pool checkout...) that the
+(codec queue wait, raft commit wait, pool checkout...) that the
 critical-path analyzer (tools/cfstrace.py) projects onto the request's wall
 time. `finish()` hands the span to the trace sink (utils/tracesink.py) when
 one is installed; with no sink the hook is a single None check.
+
+`stage(name)` is how a stage gets recorded: a context manager entered where
+the work happens, which on exit lands one pair of clock reads in three places
+— the profiler's clock (a jax.profiler.TraceAnnotation "cfs:<name>" on the
+/host:CPU plane, beside the device's "XLA Ops" line, whenever a profiler
+session is on and at no other time), the always-on counter
+cfs_trace_stage_seconds{stage=<name>}, and the current request Span's stages.
+`observe_stage` is the same without the annotation, for intervals that belong
+to no thread (a job's time in a queue); `mark` is the annotation alone, for
+the per-shard steps whose always-on cost would show (MARKS; ~1 us with no
+session, against ~3 for a stage). STAGES is the closed set of counted names.
+This module never imports jax: a process that has not imported it has no
+profiler to share a clock with.
 """
 
 from __future__ import annotations
 
+import sys
 import threading
 import time
 import uuid
@@ -326,3 +340,111 @@ def pop_span():
 def current_span() -> Span | None:
     stack = getattr(_local, "stack", None)
     return stack[-1] if stack else None
+
+
+# -- stages: profiler's clock + counter + request record -----------------------
+
+# <layer>.<what>; each name is used on one kind of thread only (HTTP worker,
+# access-pipe stage, access write worker, codec dispatcher, background tick),
+# so a name also says which thread. The set is closed: it is the declared
+# value set of the counter's `stage` label (exporter._check_bounded).
+STAGES = frozenset((
+    "gateway.recv", "gateway.queue", "gateway.handle",
+    "access.put", "access.get", "access.prepare", "access.alloc",
+    "access.encode_wait", "access.decode_wait", "access.write_stripe",
+    "access.pool_wait", "access.read", "access.gather",
+    "codec.queue_wait", "codec.drain", "codec.stack", "codec.expand",
+    "codec.concat", "codec.deliver",
+    "hostbatch.group", "hostbatch.launch", "hostbatch.fetch",
+    "scheduler.tick", "scheduler.scrub", "scheduler.inspect",
+))
+# per-shard steps, on the profiler's clock only (`mark`): six to sixteen of
+# each run per blob, and the background tick reads thousands of shards a
+# second, so as counted stages they cost a small-object op a tenth of its
+# median (PERF.md, PR 25). blobnode.* keep their own TP summaries
+# (cfs_blobnode_shard_put / _get).
+MARKS = frozenset((
+    "access.sem_wait", "blobnode.put_shard", "blobnode.get_shard",
+    "chunk.crc", "chunk.lock_wait", "chunk.write", "chunk.meta",
+))
+
+_stage_summaries: dict[str, object] = {}
+
+
+def _stage_summary(name: str):
+    from chubaofs_tpu.utils import exporter
+
+    exporter.declare_label_values("stage", STAGES)
+    s = _stage_summaries[name] = exporter.registry("trace").summary(
+        "stage_seconds", {"stage": name})
+    return s
+
+
+def observe_stage(name: str, start: float, dur: float,
+                  span: Span | None = None) -> None:
+    """Record a stage that ran [start, start + dur) on perf_counter: the
+    counter always, `span`'s record when given. No annotation — for
+    intervals no thread was inside (queue waits), stamped after the fact."""
+    (_stage_summaries.get(name) or _stage_summary(name)).observe(dur)
+    if span is not None:
+        span.add_stage(name, start, dur)
+
+
+def _annotate(name: str, span: Span | None):
+    """The entered profiler annotation of a stage, or None with no session
+    on (or no jax in this process); req= joins one request's stages across
+    threads."""
+    prof = sys.modules.get("jax.profiler")
+    if prof is None or not prof.TraceAnnotation.is_enabled():
+        return None
+    kw = {"req": span.trace_id} if span is not None else {}
+    ann = prof.TraceAnnotation("cfs:" + name, **kw)
+    ann.__enter__()
+    return ann
+
+
+class mark:
+    """`with trace.mark("chunk.write"): ...` — the profiler's clock only."""
+
+    __slots__ = ("name", "_ann")
+
+    def __init__(self, name: str):
+        self.name = name
+
+    def __enter__(self) -> "mark":
+        self._ann = _annotate(self.name, None)
+        return self
+
+    def __exit__(self, et, ev, tb):
+        if self._ann is not None:
+            self._ann.__exit__(et, ev, tb)
+        return False
+
+
+class stage:
+    """`with trace.stage("access.alloc"): ...` — see the module docstring.
+    The request span is the thread's current one. `track` names the module
+    of a track-log entry the same interval appends to it when the block
+    did not raise (stream_put.go's per-hop `module:ms`)."""
+
+    __slots__ = ("name", "track", "span", "start", "_ann")
+
+    def __init__(self, name: str, track: str | None = None):
+        self.name = name
+        self.track = track
+
+    def __enter__(self) -> "stage":
+        self.span = span = current_span()
+        self._ann = _annotate(self.name, span)
+        self.start = time.perf_counter()
+        return self
+
+    def __exit__(self, et, ev, tb):
+        dur = time.perf_counter() - self.start
+        if self._ann is not None:
+            self._ann.__exit__(et, ev, tb)
+        span = self.span
+        observe_stage(self.name, self.start, dur, span)
+        if span is not None and self.track is not None and et is None:
+            span.append_track_log(self.track, start=self.start)
+        return False

@@ -783,9 +783,12 @@ class BlobstoreDaemon(_Daemon):
         self._every(1.0, self._bg_tick, "blobstore-bg")
 
     def _bg_tick(self):
+        from chubaofs_tpu.blobstore import trace
+
         # under the runner lock, so a tick can never race a concurrent
         # reload's teardown of the cluster it is sweeping
-        self.runner.call_with("cluster", lambda c: c.run_background_once())
+        with trace.stage("scheduler.tick"):
+            self.runner.call_with("cluster", lambda c: c.run_background_once())
 
     def stop(self):
         super().stop()

@@ -27,11 +27,13 @@ from __future__ import annotations
 
 import queue
 import threading
+import time
 from concurrent.futures import Future, InvalidStateError
 from dataclasses import dataclass, field
 
 import numpy as np
 
+from chubaofs_tpu.blobstore import trace
 from chubaofs_tpu.ops import rs
 from chubaofs_tpu.utils.locks import SanitizedLock
 
@@ -71,10 +73,11 @@ class _Job:
     future: Future = field(default_factory=Future)
     # matmul jobs carry their GF matrix (repair rows x survivors)
     mat: np.ndarray | None = None
-    # the SUBMITTER's trace span (if any): the dispatcher attributes its
-    # batch's host/device time back onto it as named stages, so a PUT's
-    # critical-path report splits encode wait into host-ms vs device-ms
+    # the SUBMITTER's trace span (if any): the dispatcher attributes the
+    # job's queue wait and its batch's stack/matmul intervals back onto it
+    # as named stages, so a PUT's critical-path report splits encode wait
     span: object | None = None
+    t_submit: float = 0.0  # perf_counter at _submit: codec.queue_wait starts
 
 
 def _pad_to_bucket(data: np.ndarray, k: int, kb: int) -> np.ndarray:
@@ -349,10 +352,9 @@ class CodecService:
     # -- dispatcher --------------------------------------------------------
 
     def _submit(self, job: _Job):
-        from chubaofs_tpu.blobstore import trace
-
         job.span = trace.current_span()
         self._ensure_started()
+        job.t_submit = time.perf_counter()
         self._q.put(job)
 
     def _drain(self) -> list[_Job]:
@@ -364,19 +366,20 @@ class CodecService:
             raise StopIteration
         batch = [first]
         deadline = self.max_wait
-        import time
-
-        t0 = time.monotonic()
-        while len(batch) < self.max_batch:
-            remaining = deadline - (time.monotonic() - t0)
-            try:
-                job = self._q.get(timeout=max(0.0, remaining))
-            except queue.Empty:
-                break
-            if job is None:
-                self._q.put(None)  # re-post sentinel for the outer loop
-                break
-            batch.append(job)
+        # the max_wait hold, from the first job taken to the batch closing
+        # (the empty poll above is not the dispatcher's work)
+        with trace.stage("codec.drain"):
+            t0 = time.monotonic()
+            while len(batch) < self.max_batch:
+                remaining = deadline - (time.monotonic() - t0)
+                try:
+                    job = self._q.get(timeout=max(0.0, remaining))
+                except queue.Empty:
+                    break
+                if job is None:
+                    self._q.put(None)  # re-post sentinel for the outer loop
+                    break
+                batch.append(job)
         return batch
 
     def _run(self):
@@ -442,7 +445,6 @@ class CodecService:
             # the encode/matmul split: proves repair DECODE really batches
             # on the device (bench_repair and the kill soak read this)
             reg.counter("kind_jobs_total", {"kind": kind}).add(jobs)
-            reg.counter("kind_batches_total", {"kind": kind}).add()
         # which lowering did the math: a daemon on the TPU and one that was
         # asked for the CPU must not look the same from /metrics
         lowering = (self._mesh_mm.lowering if self._mesh_mm is not None
@@ -452,38 +454,49 @@ class CodecService:
         reg.summary("dispatch_seconds").observe(elapsed_s)
 
     def _run_group(self, sig: tuple, jobs: list[_Job]):
-        import time as _time
-
-        t0 = _time.perf_counter()
+        t0 = time.perf_counter()
+        for j in jobs:
+            trace.observe_stage("codec.queue_wait", j.t_submit,
+                                t0 - j.t_submit, span=j.span)
         # jobs arrive pre-padded to the bucket: stacking is the whole job here
-        stack = np.stack([j.data for j in jobs])
-        t_dev = _time.perf_counter()
+        with trace.stage("codec.stack"):
+            stack = np.stack([j.data for j in jobs])
+        t_mm = time.perf_counter()
         # both paths go through the host-boundary grouped entry: batches of
         # stripes are viewed (free numpy reshape) as MXU-row-filling groups
-        # before they ever reach the device (rs.gf_matmul_hostbatch) — or,
-        # with a mesh, fan out dp/sp-sharded across every device
+        # before they ever reach the device (rs.gf_matmul_hostbatch, which
+        # records the hostbatch.* stages) — or, with a mesh, fan out
+        # dp/sp-sharded across every device
         mm = self._mesh_mm or rs.gf_matmul_hostbatch
         if sig[0] == "encode":
             kernel = rs.get_kernel(jobs[0].n, jobs[0].m)
             parity = mm(kernel.parity_bits, stack)
-            out = np.concatenate([stack, parity], axis=1)  # (B, n+m, kb)
+            with trace.stage("codec.concat"):
+                out = np.concatenate([stack, parity], axis=1)  # (B, n+m, kb)
         else:
             from chubaofs_tpu.ops import bitmatrix
 
-            out = mm(bitmatrix.expand_matrix(jobs[0].mat).astype(np.int8), stack)
-        t_done = _time.perf_counter()
-        self._record_batch(len(jobs), t_done - t0, kind=str(sig[0]))
-        for j in jobs:
-            if j.span is not None:
-                # the BATCH's wall intervals, attributed to every rider: the
-                # job was on the host/device during exactly these windows
-                # (shared across the batch — sums can exceed device seconds,
-                # wall-clock union cannot)
-                j.span.add_stage("codec.host", start=t0, dur=t_dev - t0)
-                j.span.add_stage("codec.device", start=t_dev,
-                                 dur=t_done - t_dev)
-        for i, j in enumerate(jobs):
-            j.future.set_result(out[i, :, : j.k])
+            # a dozen small numpy calls: microseconds of work, but each may
+            # hand the interpreter lock over, so it gets a name of its own
+            with trace.stage("codec.expand"):
+                bits = bitmatrix.expand_matrix(jobs[0].mat).astype(np.int8)
+            out = mm(bits, stack)
+        t_done = time.perf_counter()
+        with trace.stage("codec.deliver"):  # bookkeeping, then the results
+            self._record_batch(len(jobs), t_done - t0, kind=str(sig[0]))
+            for j in jobs:
+                if j.span is not None:
+                    # the BATCH's wall intervals, attributed to every rider:
+                    # the job was being stacked / in the matmul (expand,
+                    # group, H2D, kernel, D2H, concat: host wall, not device
+                    # time) during exactly these windows (shared across the
+                    # batch — sums can exceed the dispatcher's seconds,
+                    # wall-clock union cannot)
+                    j.span.add_stage("codec.stack", start=t0, dur=t_mm - t0)
+                    j.span.add_stage("codec.matmul", start=t_mm,
+                                     dur=t_done - t_mm)
+            for i, j in enumerate(jobs):
+                j.future.set_result(out[i, :, : j.k])
 
 
 _default: CodecService | None = None

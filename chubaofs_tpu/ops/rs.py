@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from chubaofs_tpu import chaos
+from chubaofs_tpu.blobstore import trace
 from chubaofs_tpu.ops import bitmatrix, gf256
 
 BITS = 8
@@ -123,6 +124,9 @@ def gf_matmul_hostbatch(mat_bits: np.ndarray, shards: np.ndarray) -> np.ndarray:
     131 -> 53 GB/s), which is why stacking lives at the host boundary — where
     this storage system's stripes originate anyway (network buffers, chunk
     files). This is the batch entry the codec service and repair planes use.
+    Three stages split its wall time: hostbatch.group (the kron stack),
+    hostbatch.launch (H2D + enqueue; returns a device array) and
+    hostbatch.fetch (the wait for the kernel + D2H).
     """
     shards = np.asarray(shards, np.uint8)
     mat_bits = np.asarray(mat_bits, np.int8)
@@ -133,9 +137,12 @@ def gf_matmul_hostbatch(mat_bits: np.ndarray, shards: np.ndarray) -> np.ndarray:
         b *= d
     if b == 0 or r == 0 or k == 0:
         return np.zeros((*lead, r, k), np.uint8)
-    mat_s, g = group_stack(mat_bits, b)
-    out = gf_matmul_dispatch(mat_s, shards.reshape(b // g, g * n, k))
-    return np.asarray(out).reshape(*lead, r, k)
+    with trace.stage("hostbatch.group"):
+        mat_s, g = group_stack(mat_bits, b)
+    with trace.stage("hostbatch.launch"):
+        out = gf_matmul_dispatch(mat_s, shards.reshape(b // g, g * n, k))
+    with trace.stage("hostbatch.fetch"):
+        return np.asarray(out).reshape(*lead, r, k)
 
 
 @jax.jit

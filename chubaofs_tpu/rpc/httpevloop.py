@@ -46,6 +46,7 @@ import threading
 import time
 from http.client import responses as _REASONS
 
+from chubaofs_tpu.blobstore import trace
 from chubaofs_tpu.proto.packet import MAX_DATA_LEN
 from chubaofs_tpu.rpc.evloop import EvloopServer
 
@@ -75,7 +76,7 @@ class HttpRequest:
     and the connection closes."""
 
     __slots__ = ("method", "target", "headers", "body", "remote", "close",
-                 "err")
+                 "err", "t_first", "t_done")
 
     def __init__(self, method: str = "", target: str = "",
                  headers: dict | None = None, body: bytes = b"",
@@ -87,6 +88,9 @@ class HttpRequest:
         self.remote = remote
         self.close = close
         self.err = err  # (status, reason-body) tuple for framing errors
+        # perf_counter stamps the framer sets: first header byte seen, body
+        # complete (the gateway.recv / gateway.queue stages; 0.0 = unset)
+        self.t_first = self.t_done = 0.0
 
 
 class HttpReply:
@@ -149,6 +153,7 @@ class HttpFramer:
         self._msg: HttpRequest | None = None
         self._dead = False
         self._remote = "-"
+        self._t_first = 0.0          # when the current request's first byte came
 
     def on_connect(self, sock: socket.socket) -> None:
         try:
@@ -169,6 +174,8 @@ class HttpFramer:
         block plus one scratch chunk."""
         out: list = []
         mv = memoryview(data)
+        if not self._t_first:
+            self._t_first = time.perf_counter()
         while not self._dead:
             if self._msg is not None:
                 # body phase: leftover head over-read first, then the chunk
@@ -189,6 +196,7 @@ class HttpFramer:
                 if self._body_got == len(self._body):
                     msg, self._msg = self._msg, None
                     msg.body = bytes(self._body)
+                    self._complete(msg, len(self._buf) + len(mv))
                     out.append((msg, self._head_bytes + self._body_got))
                     self._body, self._body_got = None, 0
                 continue
@@ -199,7 +207,8 @@ class HttpFramer:
                 del self._buf[:idx + 4]
                 self._head_bytes = idx + 4
                 self._scan = 0
-                self._parse_head(head, out)
+                if self._parse_head(head, out) and self._msg is None:
+                    self._complete(out[-1][0], len(self._buf) + len(mv))
                 continue  # error sets _dead; else body/next-head follows
             # resume the terminator scan where this pass left off (minus
             # the 3 bytes a split \r\n\r\n could straddle) — no rescans
@@ -215,6 +224,12 @@ class HttpFramer:
             self._buf += mv[:take]
             mv = mv[take:]
         return out
+
+    def _complete(self, msg: HttpRequest, unread: int) -> None:
+        """Stamp a whole request; bytes still unread belong to the next
+        (pipelined) one, whose receive starts now."""
+        msg.t_first, msg.t_done = self._t_first, time.perf_counter()
+        self._t_first = msg.t_done if unread else 0.0
 
     def _error(self, out: list, status: int, detail: str) -> None:
         out.append((HttpRequest(remote=self._remote, close=True,
@@ -319,6 +334,11 @@ class HttpEvloopCore:
             return HttpReply(status, {"Content-Type": "application/json"},
                              json.dumps({"error": detail}).encode(),
                              close=True)
+        if msg.t_done:  # stamped by the framer: a worker has the request now
+            trace.observe_stage("gateway.recv", msg.t_first,
+                                msg.t_done - msg.t_first)
+            trace.observe_stage("gateway.queue", msg.t_done,
+                                time.perf_counter() - msg.t_done)
         req = self._parse_request(msg.method, msg.target, msg.headers,
                                   msg.body, remote=msg.remote)
         with self._drain:

@@ -92,7 +92,8 @@ def dispatch_request(router: Router, module: str, req: Request) -> Response:
     trace.push_span(span)
     t0 = time.perf_counter()
     try:
-        resp = router.dispatch(req)
+        with trace.stage("gateway.handle"):
+            resp = router.dispatch(req)
     finally:
         span.append_track_log(module or "rpc", start=t0)
         span.finish()

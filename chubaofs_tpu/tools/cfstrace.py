@@ -4,7 +4,7 @@ The analysis half of the trace sink (utils/tracesink.py): span records are
 flat JSON lines; this tool reassembles the hop tree (parent span ids link
 in-process children; the carrier's span id links cross-process hops), renders
 it as a WATERFALL or text FLAMEGRAPH, and runs the CRITICAL-PATH analyzer —
-projecting every named stage (encode host/device ms, raft commit wait, shard
+projecting every named stage (codec queue/stack/matmul ms, raft commit wait, shard
 fan-out, pool checkout) onto the root span's wall time so "what fraction of
 this PUT was encode vs raft vs wire?" has a printable answer. `--top`
 aggregates per-hop p50/p99 over the recent-trace window instead.
@@ -156,7 +156,7 @@ def critical_path(records: list[dict], root_op: str | None = None) -> dict:
 def stage_overlap(records: list[dict], a: str, b: str) -> dict:
     """How much two stage families of a trace ran CONCURRENTLY: collect the
     intervals of every stage whose name matches `a` (exact or prefix — pass
-    "codec." to cover codec.host+codec.device) and likewise `b`, then
+    "codec." to cover codec.stack+codec.matmul) and likewise `b`, then
     measure the intersection of the two interval unions. `ratio` is that
     intersection over the SMALLER union — 1.0 means the lesser stage was
     entirely hidden behind the greater (perfect pipelining), 0.0 means they
@@ -207,7 +207,10 @@ def waterfall(records: list[dict], stages: bool = True) -> str:
     head = records[0]
     lines = [f"trace {head.get('trace_id', '?')}  "
              f"wall {(t1 - t0) * 1e3:.2f}ms  spans {len(records)}"]
-    label_w = max(min(36, max(len(r.get("op", "?")) + 2 for r in records)), 12)
+    names = [r.get("op", "?") for r in records]
+    if stages:  # "· <layer>.<what>" rows must stay readable too
+        names += ["· " + str(st[0]) for r in records for st in r.get("stages", ())]
+    label_w = max(min(36, max(len(n) + 2 for n in names)), 12)
     seen: set[str] = set()
 
     def visit(rec: dict, depth: int):
@@ -239,8 +242,8 @@ def waterfall(records: list[dict], stages: bool = True) -> str:
 def _stage_tree(rec: dict) -> tuple[list[tuple[str, float, float]],
                                     dict[int, list[int]], list[int]]:
     """A span's stages as a containment hierarchy: stage B whose interval
-    sits inside a strictly-larger stage A is A's child (encode contains
-    codec.host/codec.device). Returns (intervals, children-by-idx, tops)."""
+    sits inside a strictly-larger stage A is A's child (access.encode_wait contains
+    codec.stack/codec.matmul). Returns (intervals, children-by-idx, tops)."""
     base = float(rec.get("start", 0.0))
     ivs = [(str(n), base + off / 1e6, base + (off + dur) / 1e6)
            for n, off, dur in rec.get("stages", ())]
@@ -265,7 +268,7 @@ def flamegraph(records: list[dict]) -> str:
     """Collapsed-stack text flamegraph: one `path;to;frame <ms>` line per
     span and per stage (the format flamegraph.pl and speedscope ingest),
     self-time style. Stages nest by interval containment (a 10ms encode
-    wait containing 7ms of codec.device emits 3/7, not 10/7), and a span
+    wait containing 7ms of codec.matmul emits 3/7, not 10/7), and a span
     frame excludes its child spans and top-level stages — summing a frame
     with its prefixed children reproduces the span's width, never more."""
     roots, children = build_tree(records)

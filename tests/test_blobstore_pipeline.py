@@ -41,11 +41,11 @@ def test_pipelined_put_bid_order_and_roundtrip(cluster, rng):
     assert cluster.access.get(loc) == data
     # cross-blob ranged read through the readahead path
     assert cluster.access.get(loc, BLOB - 10, 20) == data[BLOB - 10: BLOB + 10]
-    # the pipeline actually ran: occupancy histogram saw multi-stripe flight
+    # the pipelined path ran to its end: it recorded its realized overlap
     from chubaofs_tpu.utils.exporter import registry
 
-    occ = registry("access").summary("put_pipeline_occupancy").snapshot()
-    assert occ["count"] > 0 and occ["max"] >= 2
+    ov = registry("access").summary("put_overlap_ratio").snapshot()
+    assert ov["count"] > 0 and ov["sum"] > 0
 
 
 def test_bid_order_survives_out_of_order_encode(cluster, rng):

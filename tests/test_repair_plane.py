@@ -537,17 +537,17 @@ def test_kill_blobnode_soak_smoke(tmp_path):
 
 
 def test_stage_overlap_ratio_math():
-    full = [("download", 0.0, 1.0), ("codec.device", 0.0, 1.0)]
+    full = [("download", 0.0, 1.0), ("codec.matmul", 0.0, 1.0)]
     assert stage_overlap_ratio(full) == 1.0
-    half = [("download", 0.0, 1.0), ("codec.host", 0.5, 1.0)]
+    half = [("download", 0.0, 1.0), ("codec.stack", 0.5, 1.0)]
     assert stage_overlap_ratio(half) == pytest.approx(0.5)
-    serial = [("download", 0.0, 1.0), ("codec.device", 1.0, 1.0)]
+    serial = [("download", 0.0, 1.0), ("codec.matmul", 1.0, 1.0)]
     assert stage_overlap_ratio(serial) == 0.0
     assert stage_overlap_ratio([("download", 0.0, 1.0)]) is None
     assert stage_overlap_ratio([]) is None
     # overlapping same-family intervals count once (union, not sum)
     stacked = [("download", 0.0, 1.0), ("download", 0.0, 1.0),
-               ("codec.device", 0.5, 0.5)]
+               ("codec.matmul", 0.5, 0.5)]
     assert stage_overlap_ratio(stacked) == pytest.approx(1.0)
 
 
@@ -556,8 +556,8 @@ def test_cfstrace_stage_overlap_report():
 
     rec = {"start": 100.0, "dur_us": 2_000_000,
            "stages": [["download", 0, 1_000_000],
-                      ["codec.host", 500_000, 250_000],
-                      ["codec.device", 750_000, 750_000]]}
+                      ["codec.stack", 500_000, 250_000],
+                      ["codec.matmul", 750_000, 750_000]]}
     ov = stage_overlap([rec], "download", "codec.")
     assert ov["ratio"] == pytest.approx(0.5, abs=0.01)
     assert ov["overlap_ms"] == pytest.approx(500.0, abs=1.0)

@@ -1,0 +1,264 @@
+"""trace.stage from gateway to blobnode (ISSUE 25): one pair of clock reads
+lands on the profiler's clock, in cfs_trace_stage_seconds and on the request
+Span. One PUT + degraded GET through Access with the CPU codec is recorded
+once per arm (profiler session on / off); the cases read that recording."""
+
+import glob
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+
+from chubaofs_tpu.blobstore import trace
+from chubaofs_tpu.blobstore.cluster import MiniCluster
+from chubaofs_tpu.utils import exporter
+
+BLOB = 64 * 1024
+
+# what an in-process PUT + degraded GET reaches: no gateway, no background tick
+LIVE = ["access.put", "access.get", "access.prepare", "access.alloc",
+        "access.encode_wait", "access.decode_wait", "access.write_stripe",
+        "access.read", "access.gather",
+        "codec.drain", "codec.stack", "codec.expand", "codec.concat", "codec.deliver",
+        "hostbatch.group", "hostbatch.launch", "hostbatch.fetch"]
+MARKS = ["access.sem_wait", "blobnode.put_shard", "blobnode.get_shard",
+         "chunk.crc", "chunk.lock_wait", "chunk.write", "chunk.meta"]  # profiler's clock only
+OBSERVED = ["access.pool_wait", "codec.queue_wait"]  # no thread: counters only
+DISPATCHER = ("codec.drain", "codec.stack", "codec.expand", "codec.concat", "codec.deliver",
+              "hostbatch.group", "hostbatch.launch", "hostbatch.fetch")
+
+
+def scrape() -> dict[str, float]:
+    """/metrics as the daemon renders it: {'name{labels}': value}."""
+    out = {}
+    for line in exporter.render_all().splitlines():
+        if line and not line.startswith("#"):
+            key, _, val = line.rpartition(" ")
+            out[key] = float(val)
+    return out
+
+
+def stage_counts() -> dict[str, float]:
+    return {k.split('"')[1]: v for k, v in scrape().items()
+            if k.startswith("cfs_trace_stage_seconds_count{")}
+
+
+def put_and_degraded_get(root: str):
+    """-> (client trace id, finished span records). 200 KiB in 64 KiB blobs
+    is four EC6P3 stripes through the pipelined PUT; with node 1 gone every
+    stripe's GET decodes."""
+    records = []
+    prev = trace.finish_hook()
+
+    def hook(span):
+        records.append(span.to_record())
+        if prev is not None:
+            prev(span)
+
+    c = MiniCluster(root, n_nodes=9, disks_per_node=2)
+    c.access.max_blob_size = BLOB
+    data = np.random.default_rng(25).integers(0, 256, 200 * 1024, dtype=np.uint8).tobytes()
+    trace.set_finish_hook(hook)
+    try:
+        with trace.Span("client.put") as client:
+            loc = c.access.put(data)
+        c.nodes.pop(1).close()
+        with trace.Span("client.get"):
+            assert c.access.get(loc) == data
+    finally:
+        trace.set_finish_hook(prev)
+        c.close()
+    return client.trace_id, records
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    """The run under a jax.profiler session: host-plane cfs: events as
+    (stage, start_ns, end_ns, line index, req)."""
+    import jax.profiler as prof
+
+    root = tmp_path_factory.mktemp("traced")
+    opts = prof.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    prof.start_trace(str(root / "trace"), profiler_options=opts)
+    try:
+        trace_id, records = put_and_degraded_get(str(root / "cluster"))
+    finally:
+        prof.stop_trace()
+    path = sorted(glob.glob(str(root / "trace" / "**" / "*.xplane.pb"), recursive=True))[-1]
+    events = []
+    for plane in prof.ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for i, line in enumerate(plane.lines):
+            for e in line.events:
+                if e.name.startswith("cfs:"):
+                    events.append((e.name[4:], e.start_ns, e.start_ns + e.duration_ns,
+                                   i, dict(e.stats).get("req")))
+    return {"events": events, "trace_id": trace_id, "records": records}
+
+
+@pytest.fixture(scope="module")
+def untraced(tmp_path_factory):
+    """The same run with no profiler session: counter deltas and records."""
+    before = stage_counts()
+    trace_id, records = put_and_degraded_get(str(tmp_path_factory.mktemp("untraced")))
+    after = stage_counts()
+    return {"delta": {k: v - before.get(k, 0.0) for k, v in after.items()},
+            "trace_id": trace_id, "records": records}
+
+
+def test_the_name_sets_are_the_programs():
+    assert set(LIVE + OBSERVED) <= trace.STAGES and set(MARKS) == trace.MARKS
+    assert not trace.STAGES & trace.MARKS
+
+
+@pytest.mark.parametrize("name", LIVE + MARKS)
+def test_live_stage_is_on_the_host_plane(traced, name):
+    assert any(e[0] == name for e in traced["events"])
+
+
+@pytest.mark.parametrize("name", OBSERVED)
+def test_observed_stage_has_no_annotation(traced, name):
+    assert not any(e[0] == name for e in traced["events"])
+
+
+def test_dispatcher_stages_nest_in_time(traced):
+    """The dispatcher's stages sit on one thread and never partly overlap;
+    every batch reads stack < group < launch < fetch < deliver."""
+    evs = sorted((e for e in traced["events"] if e[0] in DISPATCHER), key=lambda e: e[1])
+    assert len({e[3] for e in evs}) == 1, "one dispatcher thread"
+    for a, b in zip(evs, evs[1:]):
+        assert a[2] <= b[1], f"{a[0]} overlaps {b[0]}"
+    # expand comes with matmul (decode) batches only, concat with encode ones
+    order = [e[0] for e in evs if e[0] not in ("codec.drain", "codec.expand", "codec.concat")]
+    batch = ["codec.stack", "hostbatch.group", "hostbatch.launch", "hostbatch.fetch",
+             "codec.deliver"]
+    assert len(order) >= 5 and order == batch * (len(order) // 5)
+
+
+def test_storage_stages_nest_inside_put_shard(traced):
+    puts = [e for e in traced["events"] if e[0] == "blobnode.put_shard"]
+    for name in ("chunk.crc", "chunk.lock_wait", "chunk.write", "chunk.meta"):
+        for e in (e for e in traced["events"] if e[0] == name):
+            assert any(p[3] == e[3] and p[1] <= e[1] and e[2] <= p[2] for p in puts), name
+
+
+def test_request_id_joins_annotation_and_rider_record(traced):
+    """access.put's annotation carries the client's trace id, and the span of
+    that trace got the codec.queue_wait stage its encode jobs rode in with."""
+    reqs = {e[4] for e in traced["events"] if e[0] == "access.put"}
+    assert reqs == {traced["trace_id"]}
+    rider = [r for r in traced["records"]
+             if r["trace_id"] == traced["trace_id"] and r["op"] == "access.put"]
+    assert len(rider) == 1
+    assert "codec.queue_wait" in {s[0] for s in rider[0]["stages"]}
+    # write workers carry no span: their stages join by time, not by req
+    assert {e[4] for e in traced["events"] if e[0] == "chunk.write"} == {None}
+
+
+@pytest.mark.parametrize("name", LIVE + OBSERVED)
+def test_stage_counter_grows_without_a_session(untraced, name):
+    assert untraced["delta"].get(name, 0) >= 1
+
+
+def test_marks_are_not_counted(untraced):
+    assert not set(untraced["delta"]) & set(MARKS)
+
+
+def test_untraced_span_record_is_what_cfs_trace_expects(untraced):
+    from chubaofs_tpu.tools import cfstrace
+
+    recs = [r for r in untraced["records"] if r["trace_id"] == untraced["trace_id"]]
+    put = next(r for r in recs if r["op"] == "access.put")
+    names = {s[0] for s in put["stages"]}
+    assert names >= {"access.prepare", "access.alloc", "access.encode_wait",
+                     "access.write_stripe", "codec.queue_wait", "codec.stack",
+                     "codec.matmul"}
+    # per-shard stages stay off the record (write workers carry no span)
+    assert not names & {"chunk.write", "access.sem_wait", "blobnode.put_shard"}
+    client = next(r for r in recs if r["op"] == "client.put")
+    assert "access.put" in {s[0] for s in client["stages"]}
+    assert {e.split(":")[0] for e in put["track"].split(";")} >= \
+        {"proxy", "codec", "blobnode", "access"}
+    rep = cfstrace.critical_path(recs, root_op="access.put")
+    assert rep["coverage"] > 0.5 and rep["stages"]
+
+
+@pytest.fixture(scope="module")
+def served(tmp_path_factory):
+    """Counter deltas of one PUT + GET over HTTP (the evloop core) and one
+    background tick: the stages an in-process call never reaches."""
+    from chubaofs_tpu.blobstore.gateway import AccessClient, AccessGateway
+
+    c = MiniCluster(str(tmp_path_factory.mktemp("served")), n_nodes=9, disks_per_node=2)
+    gw = AccessGateway(c.access)
+    try:
+        before = stage_counts()
+        client = AccessClient([gw.addr])
+        data = bytes(range(256)) * 1024
+        assert client.get(client.put(data)) == data
+        c.run_background_once()
+        after = stage_counts()
+    finally:
+        gw.stop()
+        c.close()
+    return {k: v - before.get(k, 0.0) for k, v in after.items()}
+
+
+@pytest.mark.parametrize("name,least", [
+    ("gateway.recv", 2), ("gateway.queue", 2), ("gateway.handle", 2),
+    ("scheduler.scrub", 1), ("scheduler.inspect", 1)])
+def test_gateway_and_background_stages_count(served, name, least):
+    assert served.get(name, 0) >= least
+
+
+def test_undeclared_stage_is_refused():
+    with pytest.raises(ValueError):
+        with trace.stage("made.up"):
+            pass
+
+
+def test_trace_module_runs_a_stage_without_jax():
+    code = ("import sys\n"
+            "from chubaofs_tpu.blobstore import trace\n"
+            "with trace.stage('access.alloc'):\n    pass\n"
+            "with trace.mark('chunk.crc'):\n    pass\n"
+            "trace.observe_stage('codec.queue_wait', 0.0, 0.001)\n"
+            "assert 'jax' not in sys.modules, sorted(m for m in sys.modules if 'jax' in m)\n")
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=60)
+
+
+def test_dispatcher_stages_sum_to_dispatch_seconds():
+    """codec.stack + codec.expand + hostbatch.* + codec.concat + codec.deliver
+    is the dispatcher's batch time: within 5% of cfs_codec_dispatch_seconds
+    over a hundred batches (deliver, the bookkeeping and the futures'
+    wake-up, lies just outside that counter's interval)."""
+    from chubaofs_tpu.codec.service import CodecService
+
+    parts = ("codec.stack", "codec.expand", "hostbatch.group", "hostbatch.launch",
+             "hostbatch.fetch", "codec.concat", "codec.deliver")
+
+    def read():
+        m = scrape()
+        out = {p: m.get('cfs_trace_stage_seconds_sum{stage="%s"}' % p, 0.0) for p in parts}
+        out["count"] = m.get("cfs_codec_dispatch_seconds_count", 0.0)
+        out["whole"] = m.get("cfs_codec_dispatch_seconds_sum", 0.0)
+        return out
+
+    svc = CodecService(max_wait_ms=0.0)
+    data = np.random.default_rng(4).integers(0, 256, (12, 256 * 1024), dtype=np.uint8)
+    try:
+        svc.encode(12, 4, data).result()  # compile outside the count
+        a = read()
+        for _ in range(100):
+            svc.encode(12, 4, data).result()
+        b = read()
+    finally:
+        svc.close()
+    assert b["count"] - a["count"] == 100
+    whole = b["whole"] - a["whole"]
+    split = sum(b[p] - a[p] for p in parts)
+    assert abs(split - whole) <= 0.05 * whole, (split, whole)

@@ -189,19 +189,21 @@ def test_put_get_critical_path_attribution(sink, blob_cluster):
     # time lands in named stages, with a nonzero encode stage
     assert rep["coverage"] >= 0.95, rep
     stages = {s["stage"]: s["ms"] for s in rep["stages"]}
-    assert stages.get("encode", 0) > 0
-    assert stages.get("write", 0) > 0
-    assert stages.get("alloc", 0) > 0
-    # codec batch timing rode the span: device time is visible per-request
-    assert stages.get("codec.device", 0) > 0
+    assert stages.get("access.encode_wait", 0) > 0
+    assert stages.get("access.write_stripe", 0) > 0
+    assert stages.get("access.alloc", 0) > 0
+    # codec batch timing rode the span: queue wait and the batch's matmul
+    # wall (host boundary + kernel) are visible per-request
+    assert stages.get("codec.matmul", 0) > 0
+    assert "codec.queue_wait" in stages
 
     # GET: same attribution proof, overhead-aware bar (see GET_BAR above)
     assert grep_["coverage"] >= GET_BAR, grep_
-    assert {s["stage"] for s in grep_["stages"]} >= {"read"}
+    assert {s["stage"] for s in grep_["stages"]} >= {"access.read"}
 
     # waterfall + flamegraph render from the same persisted records
     wf = cfstrace.waterfall(recs)
-    assert "access.put" in wf and "encode" in wf and "ms" in wf
+    assert "access.put" in wf and "access.encode_wait" in wf and "ms" in wf
     fl = cfstrace.flamegraph(recs)
     assert any(line.startswith("client.put;access.put") for line in
                fl.splitlines())
@@ -338,13 +340,13 @@ def test_cfstrace_cli_reads_sink_dir(sink):
 def test_flamegraph_nests_contained_stages_without_double_count():
     recs = [{"trace_id": "t", "span_id": "a", "parent_span_id": None,
              "op": "put", "start": 10.0, "dur_us": 10_000,
-             "stages": [["encode", 0, 10_000], ["codec.host", 1000, 2000],
-                        ["codec.device", 3000, 6000]]}]
+             "stages": [["encode", 0, 10_000], ["codec.stack", 1000, 2000],
+                        ["codec.matmul", 3000, 6000]]}]
     lines = dict(ln.rsplit(" ", 1) for ln in cfstrace.flamegraph(recs).splitlines())
     # contained stages nest under their container; self-times partition the
     # span's width instead of summing past it
     assert float(lines["put"]) == pytest.approx(0.0)
     assert float(lines["put;encode"]) == pytest.approx(2.0)
-    assert float(lines["put;encode;codec.host"]) == pytest.approx(2.0)
-    assert float(lines["put;encode;codec.device"]) == pytest.approx(6.0)
+    assert float(lines["put;encode;codec.stack"]) == pytest.approx(2.0)
+    assert float(lines["put;encode;codec.matmul"]) == pytest.approx(6.0)
     assert sum(float(v) for v in lines.values()) == pytest.approx(10.0)
