@@ -420,7 +420,7 @@ def phase_served(seed: int, deadline: float) -> dict:
         blob["proc"] = cluster.spawn("blobstore", dict(blob_cfg))
         boot = blob["boot"] = cluster.boot_info("blobstore", timeout=300)
         for key in ("platform", "device_kind", "device_count", "lowering",
-                    "kv_engine"):
+                    "kv_engine", "frame_engine"):
             check(key in boot, f"boot line lacks {key!r}: {boot}")
         check(boot["platform"] == plat, f"boot line platform: {boot}")
         stat = _admin(boot["addr"], "/admin/stat")
@@ -479,6 +479,7 @@ def phase_served(seed: int, deadline: float) -> dict:
         cluster.boot_info("master1", timeout=300)
         addr = boot_blobstore()
         res["kv_engine"] = blob["boot"]["kv_engine"]
+        res["frame_engine"] = blob["boot"]["frame_engine"]
         res["device"] = {k: blob["boot"][k] for k in
                          ("platform", "device_kind", "device_count", "lowering")}
         # -- one owner per chip, seen from outside the processes ------------

@@ -217,27 +217,29 @@ class Chunk:
         return self._size
 
     def put(self, bid: int, vuid: int, payload: bytes) -> ShardMeta:
+        head = _HEADER.pack(MAGIC, bid, vuid, len(payload), 0)[:-4]
         with trace.mark("chunk.crc"):
-            framed = crc32block.encode(payload)
+            record = crc32block.encode(
+                payload, prefix=head + struct.pack("<I", zlib.crc32(head)))
         with trace.mark("chunk.lock_wait"):
             self._lock.acquire()
         try:
-            return self._put_locked(bid, vuid, len(payload), framed)
+            return self._put_locked(bid, vuid, len(payload), record)
         finally:
             self._lock.release()
 
     def _put_locked(self, bid: int, vuid: int, size: int,
-                    framed: bytes) -> ShardMeta:
-        if self._size + HEADER_LEN + len(framed) > self.max_size:
+                    record: bytes) -> ShardMeta:
+        """`record` is the shard as the file holds it: header, framed payload."""
+        if self._size + len(record) > self.max_size:
             raise ChunkFull(self.chunk_id)
         old = self.shards.get(bid)
         offset = self._size
-        head = _HEADER.pack(MAGIC, bid, vuid, size, 0)[:-4]
         with trace.mark("chunk.write"):
             self._f.seek(offset)
-            self._f.write(head + struct.pack("<I", zlib.crc32(head)) + framed)
+            self._f.write(record)
             self._f.flush()
-        self._size = offset + HEADER_LEN + len(framed)
+        self._size = offset + len(record)
         meta = ShardMeta(bid=bid, vuid=vuid, offset=offset, size=size)
         self.shards[bid] = meta
         self.tombstones.discard(bid)  # re-put over a tombstone revives it
@@ -263,7 +265,8 @@ class Chunk:
             self._f.seek(meta.offset + HEADER_LEN + fstart)
             framed_total = crc32block.encoded_len(meta.size)
             framed = self._f.read(min(fend, framed_total) - fstart)
-        blocks = crc32block.decode(framed)
+        with trace.mark("chunk.verify"):
+            blocks = crc32block.decode(framed)
         inner = offset - (fstart // (crc32block.BLOCK_SIZE + 4)) * crc32block.BLOCK_SIZE
         return blocks[inner : inner + size]
 

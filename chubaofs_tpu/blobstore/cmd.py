@@ -125,13 +125,16 @@ def add_admin_routes(router, cluster, runner: ModuleRunner | None = None):
 
     def stat(req):
         from chubaofs_tpu.ops import device
+        from chubaofs_tpu.utils import crc32block
 
         cm = cluster.cm
         return _json({
             # platform / device_kind / device_count / lowering of the process
-            # doing the EC math, and the engine under clustermgr
+            # doing the EC math, the engine under clustermgr and the one
+            # that frames and verifies the blobnodes' shards
             "device": device.describe(),
             "kv_engine": cm.kv_engine,
+            "frame_engine": crc32block.engine(),
             "disks": len(cm.disks),
             "broken_disks": [d.disk_id for d in cm.broken_disks()],
             "volumes": len(cm.volumes),

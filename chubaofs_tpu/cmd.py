@@ -753,6 +753,7 @@ class BlobstoreDaemon(_Daemon):
         from chubaofs_tpu.blobstore.cmd import ModuleRunner, add_admin_routes
         from chubaofs_tpu.blobstore.gateway import AccessGateway
         from chubaofs_tpu.ops import device
+        from chubaofs_tpu.utils import crc32block
 
         # initialise the backend HERE, at boot: a daemon configured for a
         # platform that is not there dies now, not inside the first PUT
@@ -780,6 +781,7 @@ class BlobstoreDaemon(_Daemon):
         self.runner = runner
         self.addr = runner.handles["gateway"].addr
         self.boot_info["kv_engine"] = runner.handles["cluster"].cm.kv_engine
+        self.boot_info["frame_engine"] = crc32block.engine()
         self._every(1.0, self._bg_tick, "blobstore-bg")
 
     def _bg_tick(self):
@@ -1001,6 +1003,7 @@ def main(argv: list[str] | None = None) -> int:
     addr = getattr(daemon, "addr", "")
     boot = {"role": cfg["role"], "addr": addr}
     # blobstore: platform / device_kind / device_count / lowering / kv_engine
+    # / frame_engine
     boot.update(getattr(daemon, "boot_info", {}))
     stats_addr = getattr(daemon, "stats_addr", "")
     if stats_addr:

@@ -65,12 +65,15 @@ def _build_native() -> bool:
 
 
 def _load_native():
+    """libcfskv: the KV engine and the chunk framing (utils/crc32block). The
+    first call builds and binds it; later ones never touch the lock, so the
+    blobnode's per-shard calls cannot queue on it."""
     global _lib, _lib_failed
+    if _lib is not None or _lib_failed:
+        return _lib
     with _lib_lock:
-        if _lib is not None:
+        if _lib is not None or _lib_failed:
             return _lib
-        if _lib_failed:
-            return None
         if not _build_native():
             _lib_failed = True
             return None
@@ -97,6 +100,12 @@ def _load_native():
         lib.cfskv_count.argtypes = [ctypes.c_void_p]
         lib.cfskv_compact.argtypes = [ctypes.c_void_p]
         lib.cfskv_checkpoint.argtypes = [ctypes.c_void_p, ctypes.c_char_p]
+        lib.cfs_frame.restype = None
+        lib.cfs_frame.argtypes = [ctypes.c_char_p, ctypes.c_long, ctypes.c_long,
+                                  ctypes.c_char_p, ctypes.c_long, ctypes.c_char_p]
+        lib.cfs_unframe.restype = ctypes.c_long
+        lib.cfs_unframe.argtypes = [ctypes.c_char_p, ctypes.c_long, ctypes.c_long,
+                                    ctypes.c_char_p]
         _lib = lib
         return lib
 

@@ -104,7 +104,9 @@ def test_admin_api_and_cli(daemon, rng):
     assert stat["device"] == {"platform": "cpu", "device_kind": "cpu",
                               "device_count": 8, "lowering": "xla-einsum"}
     assert stat["kv_engine"] in ("native", "python")
-    assert daemon.boot_info == {**stat["device"], "kv_engine": stat["kv_engine"]}
+    assert stat["frame_engine"] in ("native", "python")
+    assert daemon.boot_info == {**stat["device"], "kv_engine": stat["kv_engine"],
+                                "frame_engine": stat["frame_engine"]}
     from chubaofs_tpu.utils.exporter import render_all
 
     assert 'cfs_codec_lowering_jobs_total{lowering="xla-einsum"}' in render_all()

@@ -23,7 +23,8 @@ LIVE = ["access.put", "access.get", "access.prepare", "access.alloc",
         "codec.drain", "codec.stack", "codec.expand", "codec.concat", "codec.deliver",
         "hostbatch.group", "hostbatch.launch", "hostbatch.fetch"]
 MARKS = ["access.sem_wait", "blobnode.put_shard", "blobnode.get_shard",
-         "chunk.crc", "chunk.lock_wait", "chunk.write", "chunk.meta"]  # profiler's clock only
+         "chunk.crc", "chunk.lock_wait", "chunk.write", "chunk.meta",
+         "chunk.verify"]  # profiler's clock only
 OBSERVED = ["access.pool_wait", "codec.queue_wait"]  # no thread: counters only
 DISPATCHER = ("codec.drain", "codec.stack", "codec.expand", "codec.concat", "codec.deliver",
               "hostbatch.group", "hostbatch.launch", "hostbatch.fetch")
@@ -144,6 +145,12 @@ def test_storage_stages_nest_inside_put_shard(traced):
     for name in ("chunk.crc", "chunk.lock_wait", "chunk.write", "chunk.meta"):
         for e in (e for e in traced["events"] if e[0] == name):
             assert any(p[3] == e[3] and p[1] <= e[1] and e[2] <= p[2] for p in puts), name
+
+
+def test_verify_nests_inside_get_shard(traced):
+    gets = [e for e in traced["events"] if e[0] == "blobnode.get_shard"]
+    for e in (e for e in traced["events"] if e[0] == "chunk.verify"):
+        assert any(g[3] == e[3] and g[1] <= e[1] and e[2] <= g[2] for g in gets)
 
 
 def test_request_id_joins_annotation_and_rider_record(traced):
