@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
+from chubaofs_tpu.codec.codemode import CodeMode, get_tactic
 from chubaofs_tpu.codec.encoder import lrc_parity_matrix
 from chubaofs_tpu.codec.service import bucket_len
 from chubaofs_tpu.models import EC4P2_1M, EC6P3_4M, EC12P4_8M, EC20P4L2_16M
@@ -29,11 +30,14 @@ def topo():
         pytest.skip(f"cannot describe a v5e topology: {e!r}")
 
 
-def _encode_bits(model) -> np.ndarray:
-    t = model.tactic
+def _tactic_bits(t) -> np.ndarray:
     if t.L:
         return bitmatrix.expand_matrix(lrc_parity_matrix(t)).astype(np.int8)
     return rs.get_kernel(t.N, t.M).parity_bits
+
+
+def _encode_bits(model) -> np.ndarray:
+    return _tactic_bits(model.tactic)
 
 
 def _repair_bits(model, missing) -> np.ndarray:
@@ -80,6 +84,37 @@ def test_fused_kernel_compiles_for_v5e(topo, name, mat_bits, model, batch,
         lambda s: pallas_gf.gf_matmul_bytes_fused(mat_s, s)
     ).lower(data).compile()
     assert "tpu_custom_call" in const.as_text()
+
+
+# the 2-AZ deployment's served shapes (benchmark/configs/az2-ec16p20l2.json):
+# (name, mode, blob bytes, jobs in the drained batch). EC16P20L2's composed
+# generator is 176 x 128 bits, past pick_group's 128-row cap: g = 1 at every
+# batch count, and a second MXU row tile.
+AZ2_SERVED = [
+    ("ec16p20l2-4mib-b1", CodeMode.EC16P20L2, 4 << 20, 1),
+    ("ec16p20l2-4mib-b32", CodeMode.EC16P20L2, 4 << 20, 32),
+    ("ec6p10l2-64kib-b8", CodeMode.EC6P10L2, 64 << 10, 8),
+    ("ec6p10l2-1mib-b8", CodeMode.EC6P10L2, 1 << 20, 8),
+]
+
+
+@pytest.mark.parametrize("name,mode,blob,batch", AZ2_SERVED,
+                         ids=[w[0] for w in AZ2_SERVED])
+def test_az2_served_shapes_compile_for_v5e(topo, name, mode, blob, batch):
+    """The program rs.gf_matmul_hostbatch launches for a drained batch of the
+    2-AZ LRC modes: shards padded to the service's bucket, no group stacking
+    (both matrices pass 128 bit-rows at g = 2), the matrix a runtime argument."""
+    t = get_tactic(mode)
+    mat_bits = _tactic_bits(t)
+    assert mat_bits.shape == (8 * (t.M + t.L), 8 * t.N)
+    kb = bucket_len(t.shard_size(blob))
+    assert pallas_gf.pick_group(batch, *mat_bits.shape) == 1  # no stacking
+    dev = SingleDeviceSharding(topo.devices[0])
+    data = jax.ShapeDtypeStruct((batch, t.N, kb), jnp.uint8, sharding=dev)
+    mat = jax.ShapeDtypeStruct(mat_bits.shape, jnp.int8, sharding=dev)
+    compiled = pallas_gf._fused_core.lower(mat, data, tile_k=None,
+                                           interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_sharded_gf_matmul_compiles_on_a_dp4_mesh(topo):

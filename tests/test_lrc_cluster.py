@@ -9,6 +9,10 @@ Reference semantics under test:
   * AZ-aware code-mode policy puts LRC modes on the live PUT path.
 """
 
+import importlib.util
+import json
+import os
+
 import numpy as np
 import pytest
 
@@ -18,7 +22,38 @@ from chubaofs_tpu.blobstore.access import (
     select_code_mode,
 )
 from chubaofs_tpu.blobstore.cluster import MiniCluster
+from chubaofs_tpu.blobstore.clustermgr import parse_vuid
 from chubaofs_tpu.codec.codemode import CodeMode, get_tactic
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark")
+
+
+def _reference():
+    """benchmark/reference.py, loaded by path: the plain two-stage codec that
+    imports nothing of the program."""
+    spec = importlib.util.spec_from_file_location(
+        "benchmark_reference", os.path.join(BENCH, "reference.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _deployment(name):
+    with open(os.path.join(BENCH, "configs", name + ".json")) as f:
+        return json.load(f)
+
+
+# the LRC deployments of the benchmark at test size: mode, its configuration
+# file, an object size the file's policy table sends to that mode, the cluster.
+# az2 is the file's own layout (24 disks an AZ for EC16P20L2's 19 units); az3
+# is cut to 6 x 2 (EC6P3L3 places 4 units an AZ on 4 disks).
+AZ3 = dict(n_nodes=6, disks_per_node=2, azs=3)
+AZ2 = dict(n_nodes=12, disks_per_node=4, azs=2)
+LRC = {
+    "ec6p3l3": (CodeMode.EC6P3L3, "az3-ec6p3l3", 2_000_000, AZ3),
+    "ec16p20l2": (CodeMode.EC16P20L2, "az2-ec16p20l2", 1_100_000, AZ2),
+    "ec6p10l2": (CodeMode.EC6P10L2, "az2-ec16p20l2", 500_000, AZ2),
+}
 
 
 class DownNode:
@@ -46,11 +81,42 @@ class RecordingNode:
         return getattr(self._inner, name)
 
 
+class DroppingNode:
+    """Pass-through blobnode that refuses the shard writes of some stripe
+    positions (the unit's disk answers, the write fails)."""
+
+    def __init__(self, inner, drop_idx):
+        self._inner = inner
+        self._drop = set(drop_idx)
+
+    def put_shard(self, vuid, bid, payload):
+        if parse_vuid(vuid)[1] in self._drop:
+            raise RuntimeError("shard write dropped")
+        return self._inner.put_shard(vuid, bid, payload)
+
+    def __getattr__(self, name):
+        return getattr(self._inner, name)
+
+
 @pytest.fixture
 def cluster3az(tmp_path):
     # 3 AZs x 2 nodes x 2 disks: EC6P3L3 places 4 units per AZ on 4 disks
-    c = MiniCluster(str(tmp_path), n_nodes=6, disks_per_node=2, azs=3)
+    c = MiniCluster(str(tmp_path), **AZ3)
     yield c
+    c.close()
+
+
+@pytest.fixture(params=list(LRC))
+def lrc(request, tmp_path):
+    """(cluster, mode, configuration, object size) of one LRC deployment."""
+    mode, config_name, size, layout = LRC[request.param]
+    config = _deployment(config_name)
+    assert layout["azs"] == config["layout"]["azs"]
+    c = MiniCluster(str(tmp_path), **layout)
+    real = dict(c.nodes)
+    yield c, mode, config, size
+    c.nodes.clear()  # tests swap in Down / Dropping / Recording nodes
+    c.nodes.update(real)
     c.close()
 
 
@@ -75,18 +141,105 @@ def test_default_policies_put_lrc_on_live_path():
     assert select_code_mode(2_000_000, default_policies(1)) == CodeMode.EC12P4
 
 
-def test_access_selects_lrc_from_cluster_topology(cluster3az, rng):
-    """An Access built on a 3-AZ cluster routes large puts through LRC."""
-    data = blob_bytes(rng, 2_000_000)
-    loc = cluster3az.access.put(data)
-    assert loc.code_mode == int(CodeMode.EC6P3L3)
-    assert cluster3az.access.get(loc) == data
-    # every shard, locals included, landed
-    t = get_tactic(loc.code_mode)
-    vol = cluster3az.cm.get_volume(loc.blobs[0].vid)
-    for unit in vol.units:
-        node = cluster3az.nodes[unit.node_id]
-        assert node.get_shard(unit.vuid, loc.blobs[0].bid)
+def _stored_stripe(c, blob):
+    """Every stripe position's stored bytes, read from the blobnodes."""
+    return [c.nodes[u.node_id].get_shard(u.vuid, blob.bid)
+            for u in c.cm.get_volume(blob.vid).units]
+
+
+def test_access_selects_lrc_from_cluster_topology(lrc, rng):
+    """An Access built on a multi-AZ cluster routes a put of the policy
+    table's size through the LRC mode, and every shard it stores (data,
+    global parity, local parity) is the plain reference's: the program's ONE
+    composed matmul (lrc_parity_matrix) against the reference's two stages."""
+    c, mode, config, size = lrc
+    data = blob_bytes(rng, size)
+    loc = c.access.put(data)
+    assert loc.code_mode == int(mode)
+    assert c.access.get(loc) == data
+    t = get_tactic(mode)
+    geometry = config["modes"][mode.name]
+    assert geometry == {"N": t.N, "M": t.M, "L": t.L, "az_count": t.az_count,
+                        "put_quorum": t.put_quorum}
+    (blob,) = loc.blobs
+    stored = _stored_stripe(c, blob)
+    assert len(stored) == t.total
+    want = _reference().encode(data, geometry, config["code"])
+    assert want.shape == (t.total, t.shard_size(size))
+    for idx, shard in enumerate(stored):
+        assert shard == want[idx].tobytes(), f"{mode.name} shard {idx} differs"
+
+
+def test_units_land_in_their_az_on_distinct_disks(lrc):
+    """createvolume.go: total / az_count units in each AZ (19 for EC16P20L2),
+    the AZ's data + global parity + local parity, no two on one disk."""
+    c, mode, _, _ = lrc
+    t = get_tactic(mode)
+    vol = c.cm.alloc_volume(int(mode))
+    assert len({u.disk_id for u in vol.units}) == t.total
+    for az in range(t.az_count):
+        disks = [c.cm.disks[vol.units[i].disk_id] for i in t.shards_in_az(az)]
+        assert len(disks) == t.total // t.az_count
+        assert {d.az for d in disks} == {az}
+
+
+@pytest.mark.parametrize("down", ["node", "az"])
+def test_degraded_get_is_byte_equal(lrc, rng, down):
+    """GET with the node of data shard 0 down, and with the whole of AZ 0
+    down (EC16P20L2: 19 of 38 shards gone, 8 data + 10 global parities left)."""
+    c, mode, _, size = lrc
+    data = blob_bytes(rng, size)
+    loc = c.access.put(data)
+    assert loc.code_mode == int(mode)
+    vol = c.cm.get_volume(loc.blobs[0].vid)
+    dark = _az_nodes(c, 0) if down == "az" else [vol.units[0].node_id]
+    t = get_tactic(mode)
+    alive = [u.index for u in vol.units
+             if u.index < t.global_count and u.node_id not in dark]
+    assert t.N <= len(alive) < t.global_count
+    for n in dark:
+        c.nodes[n] = DownNode()
+    assert c.access.get(loc) == data
+
+
+@pytest.mark.parametrize("globals_written", ["short", "quorum"])
+def test_put_quorum_counts_global_shards_only(lrc, rng, globals_written):
+    """stream_put.go:226 maxWrittenIndex = N + M: with every local parity
+    written, put_quorum - 1 global shards are refused (EC16P20L2: 33 + 2
+    locals = 35 shards on disk, still short) and put_quorum are accepted."""
+    c, mode, _, size = lrc
+    t = get_tactic(mode)
+    keep = t.put_quorum - (globals_written == "short")
+    drop = range(keep, t.global_count)  # the highest global parities
+    for n, node in list(c.nodes.items()):
+        c.nodes[n] = DroppingNode(node, drop)
+    data = blob_bytes(rng, size)
+    if globals_written == "short":
+        with pytest.raises(QuorumError, match=f"wrote {keep}/{t.global_count}"):
+            c.access.put(data)
+        return
+    loc = c.access.put(data)
+    stored = 0
+    for u in c.cm.get_volume(loc.blobs[0].vid).units:
+        try:
+            stored += bool(c.nodes[u.node_id].get_shard(u.vuid, loc.blobs[0].bid))
+        except Exception:
+            assert u.index in drop
+    assert stored == t.put_quorum + t.L
+    assert c.access.get(loc) == data
+
+
+@pytest.mark.parametrize("lrc", ["ec16p20l2", "ec6p10l2"], indirect=True)
+def test_dark_az_put_is_refused_under_three_azs(lrc, rng):
+    """The dark-AZ tolerance needs >= 3 AZs (stream_put.go:405-437; the 3-AZ
+    side is test_dark_az_put_get_heal): a 2-AZ mode with a whole AZ down has
+    half its globals and fails the quorum."""
+    c, mode, _, size = lrc
+    assert get_tactic(mode).az_count == 2
+    for n in _az_nodes(c, 1):
+        c.nodes[n] = DownNode()
+    with pytest.raises(QuorumError):
+        c.access.put(blob_bytes(rng, size))
 
 
 def test_dark_az_put_get_heal(cluster3az, rng):
@@ -166,47 +319,51 @@ def test_local_parity_does_not_satisfy_quorum(tmp_path, rng):
         c.close()
 
 
-def test_local_stripe_repair_reads_same_az_only(cluster3az, rng):
-    """Losing one shard inside an AZ repairs from that AZ alone
-    (work_shard_recover.go:517)."""
-    c = cluster3az
-    data = blob_bytes(rng, 2_000_000)
-    loc = c.access.put(data, code_mode=CodeMode.EC6P3L3)
-    t = get_tactic(CodeMode.EC6P3L3)
-    vol = c.cm.get_volume(loc.blobs[0].vid)
-    bid = loc.blobs[0].bid
+def _repair_recording_reads(c, vol, bid, lost_idx):
+    """Lose one shard, queue its repair, run one background pass with the
+    volume inspector off (it legitimately sweeps every AZ; only the REPAIR's
+    read set is asserted on) -> node ids the repair read from."""
+    from chubaofs_tpu.blobstore.taskswitch import SWITCH_VOL_INSPECT
 
-    lost_idx = t.shards_in_az(0)[0]  # a data shard in AZ 0
     unit = vol.units[lost_idx]
     c.nodes[unit.node_id].lose_shard(unit.vuid, bid)
     c.proxy.send_shard_repair(vol.vid, bid, [lost_idx], "test")
-
-    # gate off the volume inspector: it legitimately sweeps every AZ, and this
-    # test asserts only on the REPAIR's read set
-    from chubaofs_tpu.blobstore.taskswitch import SWITCH_VOL_INSPECT
-
     c.scheduler.switches.set(SWITCH_VOL_INSPECT, False)
     recorders = {n: RecordingNode(node) for n, node in c.nodes.items()}
     c.nodes.clear()
     c.nodes.update(recorders)
     c.run_background_once()
+    return {n for n, r in recorders.items() if r.reads}
 
-    az0_nodes = set(_az_nodes(c, 0))
-    read_nodes = {n for n, r in recorders.items() if r.reads}
+
+def test_local_stripe_repair_reads_same_az_only(lrc, rng):
+    """Losing one data shard inside an AZ repairs from that AZ's local stripe
+    alone (work_shard_recover.go:517): EC16P20L2 reads AZ 0's 7 data + 10
+    global parities + 1 local, never the other AZ."""
+    c, mode, _, size = lrc
+    data = blob_bytes(rng, size)
+    loc = c.access.put(data, code_mode=mode)
+    t = get_tactic(mode)
+    vol = c.cm.get_volume(loc.blobs[0].vid)
+    bid = loc.blobs[0].bid
+
+    lost_idx = t.shards_in_az(0)[0]  # a data shard in AZ 0
+    unit = vol.units[lost_idx]
+    before = c.nodes[unit.node_id].get_shard(unit.vuid, bid)
+    read_nodes = _repair_recording_reads(c, vol, bid, lost_idx)
+
     assert read_nodes, "repair must have read something"
-    assert read_nodes <= az0_nodes, f"repair read outside AZ 0: {read_nodes}"
-
-    healed = c.nodes[unit.node_id].get_shard(unit.vuid, bid)
-    assert np.frombuffer(healed, np.uint8).size == t.shard_size(loc.blobs[0].size)
+    assert read_nodes <= set(_az_nodes(c, 0)), f"repair read outside AZ 0: {read_nodes}"
+    assert c.nodes[unit.node_id].get_shard(unit.vuid, bid) == before
     assert c.access.get(loc) == data
 
 
-def test_lost_local_parity_recomputed_in_az(cluster3az, rng):
+def test_lost_local_parity_recomputed_in_az(lrc, rng):
     """A lost local parity is regenerated from its AZ's global shards."""
-    c = cluster3az
-    data = blob_bytes(rng, 2_000_000)
-    loc = c.access.put(data, code_mode=CodeMode.EC6P3L3)
-    t = get_tactic(CodeMode.EC6P3L3)
+    c, mode, _, size = lrc
+    data = blob_bytes(rng, size)
+    loc = c.access.put(data, code_mode=mode)
+    t = get_tactic(mode)
     vol = c.cm.get_volume(loc.blobs[0].vid)
     bid = loc.blobs[0].bid
 
@@ -214,20 +371,9 @@ def test_lost_local_parity_recomputed_in_az(cluster3az, rng):
     assert local_idx >= t.global_count
     unit = vol.units[local_idx]
     before = c.nodes[unit.node_id].get_shard(unit.vuid, bid)
-    c.nodes[unit.node_id].lose_shard(unit.vuid, bid)
-    c.proxy.send_shard_repair(vol.vid, bid, [local_idx], "test")
+    read_nodes = _repair_recording_reads(c, vol, bid, local_idx)
 
-    from chubaofs_tpu.blobstore.taskswitch import SWITCH_VOL_INSPECT
-
-    c.scheduler.switches.set(SWITCH_VOL_INSPECT, False)  # see test above
-    recorders = {n: RecordingNode(node) for n, node in c.nodes.items()}
-    c.nodes.clear()
-    c.nodes.update(recorders)
-    c.run_background_once()
-
-    az1_nodes = set(_az_nodes(c, 1))
-    read_nodes = {n for n, r in recorders.items() if r.reads}
-    assert read_nodes <= az1_nodes, f"repair read outside AZ 1: {read_nodes}"
+    assert read_nodes <= set(_az_nodes(c, 1)), f"repair read outside AZ 1: {read_nodes}"
     assert c.nodes[unit.node_id].get_shard(unit.vuid, bid) == before
 
 
