@@ -22,6 +22,14 @@ Layout on disk:
 Shard record in a chunk datafile:
     [32B header: magic, bid, vuid, payload_len, header_crc]
     [crc32block-framed payload]
+
+A chunk holds its datafile as a file DESCRIPTOR and every access is positional
+(utils/crc32block `pwrite` / `pread`: the framing and the I/O of a shard are
+one call, one release of the interpreter lock). No buffered file object ever
+sits on a datafile: there is no file position to share and nothing to flush.
+Durability is what it always was here: when put_shard returns, the record is
+in the OS (written, not fsynced) and its meta in the metadb; only compaction
+syncs, before its commit.
 """
 
 from __future__ import annotations
@@ -113,6 +121,11 @@ class Chunk:
     atomic metadb batch. A crash before the batch leaves gen G valid (the
     orphan G+1 file is swept on open); after it, gen G+1 is valid and stale
     files are swept on open.
+
+    `_lock` guards the offset reservation (`_size`), `shards`, the metadb
+    put, the descriptor and compaction's swap of it. Reads hold it through
+    their one positional call, so `compact` / `delete` / `destroy` / `close`
+    never pull the file (or the descriptor's number) from under a reader.
     """
 
     def __init__(self, path: str, chunk_id: str, max_size: int, metadb):
@@ -129,8 +142,8 @@ class Chunk:
         self._check_committed_gen()
         self._sweep_stale_gens()
         self._load()
-        self._f = open(self._data_path, "r+b")
-        self._size = os.path.getsize(self._data_path)
+        self._fd = os.open(self._data_path, os.O_RDWR | os.O_CREAT, 0o644)
+        self._size = os.fstat(self._fd).st_size
         # garbage metric survives restarts: everything in the file that is not
         # a live record is punched/superseded space (compaction trigger)
         live = sum(HEADER_LEN + crc32block.encoded_len(m.size)
@@ -185,8 +198,6 @@ class Chunk:
         return f"s/{self.chunk_id}/{bid:020d}".encode()
 
     def _load(self):
-        if not os.path.exists(self._data_path):
-            open(self._data_path, "ab").close()
         if os.path.exists(self._idx_path):  # migrate a legacy index WAL
             with open(self._idx_path) as f:
                 for line in f:
@@ -218,39 +229,40 @@ class Chunk:
 
     def put(self, bid: int, vuid: int, payload: bytes) -> ShardMeta:
         head = _HEADER.pack(MAGIC, bid, vuid, len(payload), 0)[:-4]
-        with trace.mark("chunk.crc"):
-            record = crc32block.encode(
-                payload, prefix=head + struct.pack("<I", zlib.crc32(head)))
+        head += struct.pack("<I", zlib.crc32(head))
         with trace.mark("chunk.lock_wait"):
             self._lock.acquire()
         try:
-            return self._put_locked(bid, vuid, len(payload), record)
+            return self._put_locked(bid, vuid, head, payload)
         finally:
             self._lock.release()
 
-    def _put_locked(self, bid: int, vuid: int, size: int,
-                    record: bytes) -> ShardMeta:
-        """`record` is the shard as the file holds it: header, framed payload."""
-        if self._size + len(record) > self.max_size:
+    def _put_locked(self, bid: int, vuid: int, head: bytes,
+                    payload: bytes) -> ShardMeta:
+        length = HEADER_LEN + crc32block.encoded_len(len(payload))
+        if self._size + length > self.max_size:
             raise ChunkFull(self.chunk_id)
         old = self.shards.get(bid)
         offset = self._size
+        # header + framed payload, checksummed and written in one call; on
+        # return the record is in the OS, as after write + flush
         with trace.mark("chunk.write"):
-            self._f.seek(offset)
-            self._f.write(record)
-            self._f.flush()
-        self._size = offset + len(record)
-        meta = ShardMeta(bid=bid, vuid=vuid, offset=offset, size=size)
+            crc32block.pwrite(self._fd, offset, payload, prefix=head)
+        self._size = offset + length
+        meta = ShardMeta(bid=bid, vuid=vuid, offset=offset, size=len(payload))
         self.shards[bid] = meta
         self.tombstones.discard(bid)  # re-put over a tombstone revives it
         with trace.mark("chunk.meta"):
             self._log_idx(meta)
         if old is not None:
             # re-put (e.g. repeated repair): release the superseded record
-            length = HEADER_LEN + crc32block.encoded_len(old.size)
-            _punch_hole(self._f.fileno(), old.offset, length)
-            self.holes += length
+            self._punch_locked(old)
         return meta
+
+    def _punch_locked(self, meta: ShardMeta) -> None:
+        length = HEADER_LEN + crc32block.encoded_len(meta.size)
+        _punch_hole(self._fd, meta.offset, length)
+        self.holes += length
 
     def get(self, bid: int, offset: int = 0, size: int | None = None) -> bytes:
         with self._lock:
@@ -261,12 +273,13 @@ class Chunk:
                 size = meta.size - offset
             if offset < 0 or size < 0 or offset + size > meta.size:
                 raise BlobNodeError(f"range [{offset}, {offset+size}) outside shard of {meta.size}")
+            # only the blocks that cover the range are read; every one of
+            # them is verified in the same call
             fstart, fend = crc32block.block_range(offset, size)
-            self._f.seek(meta.offset + HEADER_LEN + fstart)
-            framed_total = crc32block.encoded_len(meta.size)
-            framed = self._f.read(min(fend, framed_total) - fstart)
-        with trace.mark("chunk.verify"):
-            blocks = crc32block.decode(framed)
+            fend = min(fend, crc32block.encoded_len(meta.size))
+            with trace.mark("chunk.verify"):
+                blocks = crc32block.pread(
+                    self._fd, meta.offset + HEADER_LEN + fstart, fend - fstart)
         inner = offset - (fstart // (crc32block.BLOCK_SIZE + 4)) * crc32block.BLOCK_SIZE
         return blocks[inner : inner + size]
 
@@ -284,9 +297,7 @@ class Chunk:
             meta = self.shards.get(bid)
             if meta is None:
                 raise NoSuchShard(f"chunk {self.chunk_id} bid {bid}")
-            length = HEADER_LEN + crc32block.encoded_len(meta.size)
-            _punch_hole(self._f.fileno(), meta.offset, length)
-            self.holes += length
+            self._punch_locked(meta)
             meta.status = STATUS_DELETED
             self._log_idx(meta)
             self.tombstones.add(meta.bid)
@@ -300,40 +311,44 @@ class Chunk:
             new_gen = self.gen + 1
             new_path = self._gen_path(new_gen)
             new_metas: list[ShardMeta] = []
-            with open(new_path, "wb") as out:
+            new_fd = os.open(new_path, os.O_RDWR | os.O_CREAT | os.O_TRUNC, 0o644)
+            try:
+                new_size = 0
                 for bid, meta in sorted(self.shards.items(),
                                         key=lambda kv: kv[1].offset):
                     length = HEADER_LEN + crc32block.encoded_len(meta.size)
-                    self._f.seek(meta.offset)
-                    record = self._f.read(length)
+                    record = crc32block.pread_exact(self._fd, length, meta.offset)
+                    crc32block.pwrite_all(new_fd, record, new_size)
                     new_metas.append(ShardMeta(bid=bid, vuid=meta.vuid,
-                                               offset=out.tell(),
+                                               offset=new_size,
                                                size=meta.size,
                                                status=meta.status))
-                    out.write(record)
-                out.flush()
-                os.fsync(out.fileno())
-            # the new file's DIRECTORY ENTRY must be durable before the gen
-            # bump commits, or a crash could leave a committed gen with no file
-            dfd = os.open(os.path.dirname(new_path) or ".", os.O_RDONLY)
-            try:
-                os.fsync(dfd)
-            finally:
-                os.close(dfd)
-            # commit point: gen bump + every re-offset meta, atomically.
-            # Tombstones are RETAINED: they are cluster-level delete intent
-            # ("deleted here, not lost"), not file-local garbage — purging them
-            # would let the inspector resurrect a partially-deleted blob
-            puts = [(self._gen_key(), str(new_gen).encode())]
-            puts += [(self._key(m.bid), json.dumps(m.__dict__).encode())
-                     for m in new_metas]
-            self._db.write_batch(puts=puts)
+                    new_size += length
+                os.fsync(new_fd)
+                # the new file's DIRECTORY ENTRY must be durable before the gen
+                # bump commits, or a crash could leave a committed gen with no file
+                dfd = os.open(os.path.dirname(new_path) or ".", os.O_RDONLY)
+                try:
+                    os.fsync(dfd)
+                finally:
+                    os.close(dfd)
+                # commit point: gen bump + every re-offset meta, atomically.
+                # Tombstones are RETAINED: they are cluster-level delete intent
+                # ("deleted here, not lost"), not file-local garbage — purging
+                # them would let the inspector resurrect a partially-deleted blob
+                puts = [(self._gen_key(), str(new_gen).encode())]
+                puts += [(self._key(m.bid), json.dumps(m.__dict__).encode())
+                         for m in new_metas]
+                self._db.write_batch(puts=puts)
+            except BaseException:
+                os.close(new_fd)  # the orphan file is swept on the next open
+                raise
             old_path, old_size = self._data_path, self._size
-            self._f.close()
+            os.close(self._fd)
             self.gen = new_gen
             self._data_path = new_path
-            self._f = open(new_path, "r+b")
-            self._size = os.path.getsize(new_path)
+            self._fd = new_fd
+            self._size = new_size
             self.shards = {m.bid: m for m in new_metas}
             self.holes = 0
             if old_path != new_path:
@@ -359,9 +374,7 @@ class Chunk:
             meta = self.shards.pop(bid, None)
             if meta is None:
                 raise NoSuchShard(f"chunk {self.chunk_id} bid {bid}")
-            length = HEADER_LEN + crc32block.encoded_len(meta.size)
-            _punch_hole(self._f.fileno(), meta.offset, length)
-            self.holes += length
+            self._punch_locked(meta)
             self._db.delete(self._key(bid))
 
     def list_shards(self) -> list[ShardMeta]:
@@ -372,7 +385,7 @@ class Chunk:
         """Delete the chunk outright: datafile, shard metas, tombstones, gen
         marker. Used when a volume unit is re-homed off this disk."""
         with self._lock:
-            self._f.close()
+            self._close_fd_locked()
             keys = [k for k, _ in self._db.scan(
                 prefix=f"s/{self.chunk_id}/".encode())]
             keys.append(self._gen_key())
@@ -384,8 +397,16 @@ class Chunk:
             self.shards.clear()
             self.tombstones.clear()
 
+    def _close_fd_locked(self):
+        """Idempotent, under the lock: a descriptor's number is reused by the
+        next open in the process, so it is closed once and never read after."""
+        fd, self._fd = self._fd, -1
+        if fd >= 0:
+            os.close(fd)
+
     def close(self):
-        self._f.close()
+        with self._lock:
+            self._close_fd_locked()
 
 
 class Disk:

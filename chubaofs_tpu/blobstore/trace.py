@@ -365,7 +365,7 @@ STAGES = frozenset((
 # (cfs_blobnode_shard_put / _get).
 MARKS = frozenset((
     "access.sem_wait", "blobnode.put_shard", "blobnode.get_shard",
-    "chunk.crc", "chunk.lock_wait", "chunk.write", "chunk.meta", "chunk.verify",
+    "chunk.lock_wait", "chunk.write", "chunk.meta", "chunk.verify",
 ))
 
 _stage_summaries: dict[str, object] = {}

@@ -65,7 +65,7 @@ def _build_native() -> bool:
 
 
 def _load_native():
-    """libcfskv: the KV engine and the chunk framing (utils/crc32block). The
+    """libcfskv: the KV engine and the chunk shard I/O (utils/crc32block). The
     first call builds and binds it; later ones never touch the lock, so the
     blobnode's per-shard calls cannot queue on it."""
     global _lib, _lib_failed
@@ -100,12 +100,14 @@ def _load_native():
         lib.cfskv_count.argtypes = [ctypes.c_void_p]
         lib.cfskv_compact.argtypes = [ctypes.c_void_p]
         lib.cfskv_checkpoint.argtypes = [ctypes.c_void_p, ctypes.c_char_p]
-        lib.cfs_frame.restype = None
-        lib.cfs_frame.argtypes = [ctypes.c_char_p, ctypes.c_long, ctypes.c_long,
-                                  ctypes.c_char_p, ctypes.c_long, ctypes.c_char_p]
-        lib.cfs_unframe.restype = ctypes.c_long
-        lib.cfs_unframe.argtypes = [ctypes.c_char_p, ctypes.c_long, ctypes.c_long,
-                                    ctypes.c_char_p]
+        lib.cfs_shard_pwrite.restype = ctypes.c_long
+        lib.cfs_shard_pwrite.argtypes = [
+            ctypes.c_int, ctypes.c_long, ctypes.c_char_p, ctypes.c_long,
+            ctypes.c_char_p, ctypes.c_long, ctypes.c_long]
+        lib.cfs_shard_pread.restype = ctypes.c_long
+        lib.cfs_shard_pread.argtypes = [
+            ctypes.c_int, ctypes.c_long, ctypes.c_long, ctypes.c_long,
+            ctypes.c_char_p, ctypes.POINTER(ctypes.c_long)]
         _lib = lib
         return lib
 

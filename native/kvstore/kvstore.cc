@@ -33,6 +33,7 @@
 #include <sys/file.h>
 #include <sys/stat.h>
 #include <sys/types.h>
+#include <sys/uio.h>
 #include <unistd.h>
 #include <vector>
 
@@ -325,6 +326,36 @@ struct DB {
   }
 };
 
+// -- vectored positional I/O for the shard calls at the end of the file ------
+
+constexpr long kIovBlocks = 256;  // 2 x 256 + 1 entries: under IOV_MAX (1024)
+
+// pwritev/preadv of all of iov[0..cnt) at `pos`, resuming a short transfer
+// where it stopped. Returns the bytes moved (less than asked only at end of
+// file, on a read) or -errno. Consumes iov.
+template <typename Fn>
+long xfer_all(Fn fn, int fd, struct iovec* iov, int cnt, long pos) {
+  long done = 0;
+  while (cnt > 0) {
+    ssize_t r = fn(fd, iov, cnt, pos + done);
+    if (r < 0) {
+      if (errno == EINTR) continue;
+      return -errno;
+    }
+    if (r == 0) break;  // end of file (read); a write of nothing cannot go on
+    done += r;
+    while (cnt > 0 && size_t(r) >= iov->iov_len) {
+      r -= iov->iov_len;
+      iov++, cnt--;
+    }
+    if (cnt > 0) {
+      iov->iov_base = (uint8_t*)iov->iov_base + r;
+      iov->iov_len -= r;
+    }
+  }
+  return done;
+}
+
 }  // namespace
 
 extern "C" {
@@ -439,40 +470,83 @@ int cfskv_checkpoint(void* h, const char* dir) {
   return db->checkpoint(dir) ? 0 : -1;
 }
 
-// -- crc32block framing (blobnode chunk files; utils/crc32block.py) ---------
+// -- crc32block shard I/O (blobnode chunk files; utils/crc32block.py) --------
 //
 // A shard in a chunk datafile is [block][crc32(block)]... in blocks of
-// `block` bytes, the last one short. Both calls walk every block in one pass
-// with no Python between the blocks: under ctypes that is one release of the
-// interpreter lock a shard, whatever its size.
+// `block` bytes, the last one short. Each call below frames (or verifies)
+// every block AND does the file I/O, positional on the chunk's descriptor,
+// with no Python anywhere between: under ctypes that is one release of the
+// interpreter lock a shard, whatever its size, and no framed copy of the
+// shard exists outside the page cache. Durability is what `write` + `flush`
+// of a buffered file gave: the bytes are in the OS when the call returns,
+// nothing here syncs them.
 
-// out <- prefix, then payload framed. `out` holds prefix_len + n + 4 per block.
-void cfs_frame(const uint8_t* payload, long n, long block,
-               const uint8_t* prefix, long prefix_len, uint8_t* out) {
-  memcpy(out, prefix, prefix_len);
-  out += prefix_len;
-  for (long off = 0; off < n; off += block) {
-    long len = std::min(block, n - off);
-    memcpy(out, payload + off, len);
-    uint32_t c = crc32(payload + off, len);
-    out += len;
-    for (int i = 0; i < 4; i++) out[i] = uint8_t(c >> (8 * i));
-    out += 4;
-  }
+// head, then payload framed, written at `pos` of `fd` straight from the
+// caller's buffers: one pwritev of head + (block, crc) pairs for every 16 MiB.
+// Returns the bytes written (head_len + n + 4 a block) or -errno.
+long cfs_shard_pwrite(int fd, long pos, const uint8_t* head, long head_len,
+                      const uint8_t* payload, long n, long block) {
+  struct iovec iov[2 * kIovBlocks + 1];
+  uint8_t crcs[kIovBlocks][4];
+  long total = 0, off = 0, want = head_len;
+  int cnt = 0;
+  if (head_len > 0) iov[cnt++] = {(void*)head, size_t(head_len)};
+  do {
+    for (long b = 0; b < kIovBlocks && off < n; b++, off += block) {
+      long len = std::min(block, n - off);
+      uint32_t c = crc32(payload + off, len);
+      for (int i = 0; i < 4; i++) crcs[b][i] = uint8_t(c >> (8 * i));
+      iov[cnt++] = {(void*)(payload + off), size_t(len)};
+      iov[cnt++] = {crcs[b], 4};
+      want += len + 4;
+    }
+    long got = xfer_all(pwritev, fd, iov, cnt, pos + total);
+    if (got < 0) return got;
+    if (got < want) return -EIO;  // pwritev moved nothing and set no errno
+    total += got;
+    cnt = 0, want = 0;
+  } while (off < n);
+  return total;
 }
 
-// Verifies every block of `framed` and strips the crcs into `out`. Returns -1
-// when all pass, else the framed offset of the first block that does not (a
-// tail too short to hold a block and its crc is such a block).
-long cfs_unframe(const uint8_t* framed, long n, long block, uint8_t* out) {
-  for (long off = 0; off < n; off += block + 4) {
-    long len = std::min(block + 4, n - off) - 4;
-    if (len <= 0 || crc32(framed + off, len) != get_u32(framed + off + len))
-      return off;
-    memcpy(out, framed + off, len);
-    out += len;
+// Reads the `framed_len` framed bytes at `pos` of `fd` with one preadv for
+// every 16 MiB, each block scattered to its place in `out` and its crc aside,
+// and verifies every block where it lies. `*bad` is -1 when all pass, else
+// the framed offset of the first block that does not (a tail too short to
+// hold a block and its crc is such a block); `out` is then not to be used.
+// Returns `framed_len`, fewer bytes where the file ends early, or -errno.
+long cfs_shard_pread(int fd, long pos, long framed_len, long block,
+                     uint8_t* out, long* bad) {
+  struct iovec iov[2 * kIovBlocks];
+  uint8_t crcs[kIovBlocks][4];
+  *bad = -1;
+  long end = framed_len;  // where the whole blocks end
+  if (long tail = framed_len % (block + 4); tail > 0 && tail <= 4)
+    *bad = end = framed_len - tail;
+  for (long first = 0; first < end;) {
+    int cnt = 0;
+    long off = first, want = 0;
+    uint8_t* dst = out;
+    for (long b = 0; b < kIovBlocks && off < end; b++, off += block + 4) {
+      long len = std::min(block + 4, end - off) - 4;
+      iov[cnt++] = {dst, size_t(len)};
+      iov[cnt++] = {crcs[b], 4};
+      dst += len;
+      want += len + 4;
+    }
+    long got = xfer_all(preadv, fd, iov, cnt, pos + first);
+    if (got < 0) return got;
+    if (got < want) return first + got;
+    for (long b = 0; first < off; b++, first += block + 4) {
+      long len = std::min(block + 4, end - first) - 4;
+      if (crc32(out, len) != get_u32(crcs[b])) {
+        *bad = first;
+        return framed_len;
+      }
+      out += len;
+    }
   }
-  return -1;
+  return framed_len;
 }
 
 }  // extern "C"

@@ -1,10 +1,12 @@
-"""crc32block's two engines (ISSUE 26): the native frame/unframe of libcfskv
-against the Python loop, byte for byte; what chooses between them; the counter
-that says which one ran; chunk files that read the same under either."""
+"""crc32block's two engines (ISSUE 26, ISSUE 28): the native shard calls of
+libcfskv (frame + pwritev, preadv + verify, positional on a descriptor) against
+the Python loop, byte for byte; what chooses between them; the counter that
+says which one ran; chunk files that read the same under either."""
 
 import ctypes
 import os
 import sys
+import tempfile
 import threading
 
 import numpy as np
@@ -39,18 +41,46 @@ def payload_of(n: int, seed: int = 0) -> bytes:
     return np.random.default_rng(seed).integers(0, 256, n, dtype=np.uint8).tobytes()
 
 
+AT = 3 * 4096 + 7  # where in the scratch file a record goes: positional, never 0
+
+
+def written(write, size: int) -> bytes:
+    """The `size` bytes that `write(fd)` left at AT of a scratch file."""
+    with tempfile.TemporaryFile() as f:
+        assert write(f.fileno()) == size
+        assert os.fstat(f.fileno()).st_size == (AT + size if size else 0)
+        return os.pread(f.fileno(), size + 1, AT)
+
+
+def frame(payload: bytes, prefix: bytes = b"") -> bytes:
+    """What `crc32block.pwrite` puts in a file, by the engine it chooses."""
+    return written(lambda fd: crc32block.pwrite(fd, AT, payload, prefix=prefix),
+                   len(prefix) + crc32block.encoded_len(len(payload)))
+
+
+def unframe(framed: bytes) -> bytes:
+    """`crc32block.pread` of a file that holds `framed`."""
+    with tempfile.TemporaryFile() as f:
+        os.pwrite(f.fileno(), framed, AT)
+        return crc32block.pread(f.fileno(), AT, len(framed))
+
+
 def native_frame(lib, payload: bytes, prefix: bytes = b"") -> bytes:
-    """cfs_frame itself, also below the size where encode() would call it."""
-    out = ctypes.create_string_buffer(len(prefix) + crc32block.encoded_len(len(payload)))
-    lib.cfs_frame(payload, len(payload), BLOCK_SIZE, prefix, len(prefix), out)
-    return out.raw
+    """cfs_shard_pwrite itself, also below the size where pwrite() would call it."""
+    return written(lambda fd: lib.cfs_shard_pwrite(fd, AT, prefix, len(prefix), payload,
+                                                   len(payload), BLOCK_SIZE),
+                   len(prefix) + crc32block.encoded_len(len(payload)))
 
 
 def native_unframe(lib, framed: bytes) -> tuple[int, bytes]:
-    """cfs_unframe itself -> (first bad framed offset or -1, payload if -1)."""
+    """cfs_shard_pread itself -> (first bad framed offset or -1, payload if -1)."""
     out = ctypes.create_string_buffer(max(1, len(framed)))
-    bad = lib.cfs_unframe(framed, len(framed), BLOCK_SIZE, out)
-    return bad, out.raw[:crc32block.decoded_len(len(framed))] if bad < 0 else b""
+    bad = ctypes.c_long()
+    with tempfile.TemporaryFile() as f:
+        os.pwrite(f.fileno(), framed, AT)
+        got = lib.cfs_shard_pread(f.fileno(), AT, len(framed), BLOCK_SIZE, out, ctypes.byref(bad))
+    assert got == len(framed)
+    return bad.value, out.raw[:crc32block.decoded_len(len(framed))] if bad.value < 0 else b""
 
 
 def counts() -> dict[tuple[str, str], float]:
@@ -67,17 +97,16 @@ def grown(before: dict) -> dict:
 @pytest.mark.parametrize("n", SIZES)
 def test_native_frames_as_python_does(lib, monkeypatch, n, prefix):
     payload = payload_of(n, seed=n)
-    ours = crc32block.encode(payload, prefix=prefix)  # the engine encode() chooses
-    direct = native_frame(lib, payload, prefix)
-    force_python(monkeypatch)
-    reference = crc32block.encode(payload, prefix=prefix)
-    assert ours == reference and direct == reference
+    reference = crc32block.encode(payload, prefix=prefix)  # the loop: the format
+    assert frame(payload, prefix) == reference  # the engine pwrite() chooses
+    assert native_frame(lib, payload, prefix) == reference
     assert len(reference) == len(prefix) + crc32block.encoded_len(n)
     framed = reference[len(prefix):]
-    assert crc32block.decode(framed) == payload  # the Python decoder
-    monkeypatch.undo()
-    assert crc32block.decode(framed) == payload  # the one decode() chooses
+    assert crc32block.decode(framed) == payload
+    assert unframe(framed) == payload  # the engine pread() chooses
     assert native_unframe(lib, framed) == (-1, payload)
+    force_python(monkeypatch)
+    assert frame(payload, prefix) == reference and unframe(framed) == payload
 
 
 def damaged(framed: bytes, what: str, block: int) -> bytes:
@@ -99,12 +128,14 @@ def test_unframe_rejects_what_the_python_decoder_rejects(lib, monkeypatch, n, bl
         block = (n - 1) // BLOCK_SIZE  # only the last block can be the torn one
     bad = damaged(crc32block.encode(payload_of(n, seed=block)), what, block)
     with pytest.raises(CrcError) as native:
-        crc32block.decode(bad)
+        unframe(bad)
     assert native_unframe(lib, bad)[0] == block * STRIDE
+    with pytest.raises(CrcError) as loop:
+        crc32block.decode(bad)
     force_python(monkeypatch)
     with pytest.raises(CrcError) as python:
-        crc32block.decode(bad)
-    assert str(native.value) == str(python.value)
+        unframe(bad)
+    assert str(native.value) == str(python.value) == str(loop.value)
     if what != "cut_to_stub":  # a stub of <= 4 bytes is refused by its length alone
         assert str(python.value).endswith(f"framed offset {block * STRIDE}")
 
@@ -117,9 +148,9 @@ def test_block_range_sub_reads_decode_identically(lib, monkeypatch, offset, size
     fstart, fend = crc32block.block_range(offset, size)
     part = framed[fstart:min(fend, len(framed))]
     inner = offset - fstart // STRIDE * BLOCK_SIZE
-    native = crc32block.decode(part)
+    native = unframe(part)
     force_python(monkeypatch)
-    assert crc32block.decode(part) == native
+    assert unframe(part) == native == crc32block.decode(part)
     assert native[inner:inner + size] == payload[offset:offset + size]
 
 
@@ -143,17 +174,23 @@ def test_one_count_a_shard_under_the_engine_that_ran(lib, tmp_path, n, engine):
 
 
 def test_many_threads_frame_and_verify_at_once(lib):
-    """Write and read workers share the library handle and the counters; with
-    the lock changing hands every 10 us none of them may lose a shard or a count."""
+    """Write and read workers share the library handle, ONE descriptor and the
+    counters; with the lock changing hands every 10 us none of them may lose a
+    shard or a count, or see another's bytes at its own position."""
     payloads = [payload_of(n, seed=n) for n in (AZ1_SHARD, AZ3_SHARD, 70000, 2048)]
     want = [crc32block.encode(p) for p in payloads]
     wrong, rounds, workers = [], 40, 24
     before = counts()
+    scratch = tempfile.TemporaryFile()
+    fd = scratch.fileno()
 
     def work(i):
+        at = i * (1 << 20) + i
         for r in range(rounds):
             k = (i + r) % len(payloads)
-            if crc32block.encode(payloads[k]) != want[k] or crc32block.decode(want[k]) != payloads[k]:
+            if crc32block.pwrite(fd, at, payloads[k]) != len(want[k]) \
+                    or os.pread(fd, len(want[k]), at) != want[k] \
+                    or crc32block.pread(fd, at, len(want[k])) != payloads[k]:
                 wrong.append((i, r))
 
     keep = sys.getswitchinterval()
@@ -167,6 +204,7 @@ def test_many_threads_frame_and_verify_at_once(lib):
     finally:
         sys.setswitchinterval(keep)
     assert not any(t.is_alive() for t in threads) and not wrong
+    scratch.close()
     calls = rounds * workers
     assert grown(before) == {("native", "frame"): calls * 3 / 4, ("native", "verify"): calls * 3 / 4,
                              ("python", "frame"): calls / 4, ("python", "verify"): calls / 4}
@@ -175,8 +213,7 @@ def test_many_threads_frame_and_verify_at_once(lib):
 def test_unloadable_library_means_the_python_engine(monkeypatch, tmp_path):
     force_python(monkeypatch)
     before = counts()
-    framed = crc32block.encode(payload_of(AZ1_SHARD))
-    crc32block.decode(framed)
+    assert unframe(frame(payload_of(AZ1_SHARD))) == payload_of(AZ1_SHARD)
     assert grown(before) == {("python", "frame"): 1, ("python", "verify"): 1}
     kv = kvstore.open_kv(str(tmp_path / "kv"))
     assert kv.engine == "python"

@@ -23,7 +23,7 @@ LIVE = ["access.put", "access.get", "access.prepare", "access.alloc",
         "codec.drain", "codec.stack", "codec.expand", "codec.concat", "codec.deliver",
         "hostbatch.group", "hostbatch.launch", "hostbatch.fetch"]
 MARKS = ["access.sem_wait", "blobnode.put_shard", "blobnode.get_shard",
-         "chunk.crc", "chunk.lock_wait", "chunk.write", "chunk.meta",
+         "chunk.lock_wait", "chunk.write", "chunk.meta",
          "chunk.verify"]  # profiler's clock only
 OBSERVED = ["access.pool_wait", "codec.queue_wait"]  # no thread: counters only
 DISPATCHER = ("codec.drain", "codec.stack", "codec.expand", "codec.concat", "codec.deliver",
@@ -142,7 +142,7 @@ def test_dispatcher_stages_nest_in_time(traced):
 
 def test_storage_stages_nest_inside_put_shard(traced):
     puts = [e for e in traced["events"] if e[0] == "blobnode.put_shard"]
-    for name in ("chunk.crc", "chunk.lock_wait", "chunk.write", "chunk.meta"):
+    for name in ("chunk.lock_wait", "chunk.write", "chunk.meta"):
         for e in (e for e in traced["events"] if e[0] == name):
             assert any(p[3] == e[3] and p[1] <= e[1] and e[2] <= p[2] for p in puts), name
 
@@ -232,7 +232,7 @@ def test_trace_module_runs_a_stage_without_jax():
     code = ("import sys\n"
             "from chubaofs_tpu.blobstore import trace\n"
             "with trace.stage('access.alloc'):\n    pass\n"
-            "with trace.mark('chunk.crc'):\n    pass\n"
+            "with trace.mark('chunk.write'):\n    pass\n"
             "trace.observe_stage('codec.queue_wait', 0.0, 0.001)\n"
             "assert 'jax' not in sys.modules, sorted(m for m in sys.modules if 'jax' in m)\n")
     subprocess.run([sys.executable, "-c", code], check=True, timeout=60)
