@@ -22,7 +22,6 @@ from __future__ import annotations
 import hashlib
 import hmac
 import json
-import os
 import threading
 import time
 import zlib
@@ -221,12 +220,12 @@ class Access:
         self._probe_lock = SanitizedLock(name="access.probe")
         # data-path pipeline: bounded encode->write overlap window for
         # multi-blob PUTs, and blob-level GET readahead depth. 0 = serial.
-        self.pipeline_window = int(os.environ.get("CFS_PIPELINE_WINDOW", "3"))
+        self.pipeline_window = 3
         # how many blobs may be ENCODED ahead of the write window: wide
         # enough that the codec service still forms full device batches
         # (window-sized encode submission would cap batches at 2-4 jobs),
         # bounded so a 1000-blob object doesn't materialize 1000 stripes
-        self.encode_ahead = int(os.environ.get("CFS_PUT_ENCODE_AHEAD", "16"))
+        self.encode_ahead = 16
         self.max_blob_size = MAX_BLOB_SIZE
         # blob-level pipeline stages get their OWN executor: a PUT stage
         # blocks on a codec future plus shard fan-outs running on self._pool

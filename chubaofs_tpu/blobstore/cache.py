@@ -45,9 +45,8 @@ scheduler turns it into a lease-driven promote task.
 
 Knobs: CFS_CACHE_MB (memory-tier budget; 0/unset = cache plane off),
 CFS_CACHE_DISK_MB (disk-tier budget, default 4x memory),
-CFS_CACHE_BLOCK (cache block bytes, default 256 KiB),
-CFS_CACHE_ADMIT ("tinylfu" | "always"), CFS_PROMOTE_HITS (promotion
-threshold, 0 = never signal).
+CFS_CACHE_BLOCK (cache block bytes, default 256 KiB), CFS_PROMOTE_HITS
+(promotion threshold, 0 = never signal). Admission is TinyLFU.
 """
 
 from __future__ import annotations
@@ -82,7 +81,7 @@ class BlobCache:
     (vid, bid, version, block_no)."""
 
     def __init__(self, cache_dir: str, mem_mb: int | None = None,
-                 disk_mb: int | None = None, admit: str | None = None,
+                 disk_mb: int | None = None,
                  promote_hits: int | None = None,
                  block_bytes: int | None = None):
         if mem_mb is None:
@@ -91,8 +90,6 @@ class BlobCache:
             disk_mb = int(os.environ.get("CFS_CACHE_DISK_MB", "") or 0)
             if disk_mb <= 0:
                 disk_mb = 4 * mem_mb
-        if admit is None:
-            admit = os.environ.get("CFS_CACHE_ADMIT", "tinylfu")
         if promote_hits is None:
             promote_hits = int(os.environ.get("CFS_PROMOTE_HITS", "32") or 32)
         if block_bytes is None:
@@ -103,7 +100,7 @@ class BlobCache:
         self.promote_hits = promote_hits
         self.mgr = BcacheManager(cache_dir, capacity_bytes=disk_mb << 20,
                                  mem_capacity_bytes=mem_mb << 20,
-                                 admit=admit)
+                                 admit="tinylfu")
         self._lock = SanitizedLock(name="cache.ver")
         # (vid, bid) -> (version, monotonic stamp of the bump), kept in
         # bump order (move_to_end on re-bump) so pruning pops oldest-first

@@ -138,11 +138,10 @@ class AccessGateway:
 
 
 class AccessClient:
-    """api/access client analog; mirrors the in-process Access surface.
-    `pooled=False` forces connect-per-request (the perfbench A/B control)."""
+    """api/access client analog; mirrors the in-process Access surface."""
 
-    def __init__(self, hosts: list[str], retries: int = 3, pooled: bool = True):
-        self.rpc = RPCClient(hosts, retries=retries, pooled=pooled)
+    def __init__(self, hosts: list[str], retries: int = 3, pool=None):
+        self.rpc = RPCClient(hosts, retries=retries, pool=pool)
 
     def put(self, data: bytes) -> Location:
         status, _, body = self.rpc.do("PUT", "/put", data)

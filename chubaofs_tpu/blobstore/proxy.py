@@ -70,15 +70,13 @@ class Proxy:
     serving a volume that was retired, locked, or filled behind its back."""
 
     def __init__(self, cm: ClusterMgr, data_dir: str | None = None,
-                 alloc_ttl: float = 30.0, active_vols: int | None = None):
+                 alloc_ttl: float = 30.0, active_vols: int = 2):
         self.cm = cm
         self.alloc_ttl = alloc_ttl
         # grants rotate round-robin over a SET of active volumes (the
         # reference allocator keeps several volumes per mode in flight):
         # consecutive blobs of one windowed PUT then land on different
         # chunks/disks instead of serializing on one chunk's append lock
-        if active_vols is None:
-            active_vols = int(os.environ.get("CFS_PROXY_ACTIVE_VOLS", "2"))
         self.active_vols = max(1, active_vols)
         self._lock = SanitizedLock(name="proxy.alloc")
         # code_mode -> (volume grants, monotonic expiry)

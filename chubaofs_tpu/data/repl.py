@@ -11,20 +11,18 @@ goroutine-pair overlap collapsed to one worker task per client connection),
 pooled follower connections, and the RemainingFollowers byte cleared on
 forwarded packets. The operator itself is injected by the datanode.
 
-Serving rides the rpc/evloop.py event-loop core by default (ISSUE 8): loop
-shards own the sockets, the blocking dispatch runs on the bounded worker
-pool, per-connection order is preserved. `CFS_EVLOOP=0` restores the
-thread-per-connection accept loop below for A/B and rollback."""
+Serving rides the rpc/evloop.py event-loop core: loop shards own the
+sockets, the blocking dispatch runs on the bounded worker pool,
+per-connection order is preserved."""
 
 from __future__ import annotations
 
 import socket
-import threading
 
 from chubaofs_tpu.proto.packet import (
     Packet, RES_OK, recv_packet, send_packet,
 )
-from chubaofs_tpu.rpc.evloop import EvloopServer, evloop_enabled
+from chubaofs_tpu.rpc.evloop import EvloopServer
 from chubaofs_tpu.utils.conn_pool import ConnPool
 
 
@@ -54,54 +52,16 @@ class ReplServer:
         self._listener.bind((host, int(port)))
         if int(port) == 0:
             self.addr = f"{host}:{self._listener.getsockname()[1]}"
-        self._stop = threading.Event()
-        self._accept_thread: threading.Thread | None = None
-        self._evloop: EvloopServer | None = None
+        self._evloop = EvloopServer(self._listener, self.dispatch, name="repl")
 
     # -- server side -----------------------------------------------------------
 
     def start(self) -> None:
         self._listener.listen(128)
-        if evloop_enabled():
-            self._evloop = EvloopServer(self._listener, self.dispatch,
-                                        name="repl")
-            self._evloop.start()
-            return
-        self._accept_thread = threading.Thread(
-            target=self._accept_loop, daemon=True, name=f"repl-{self.addr}")
-        self._accept_thread.start()
-
-    def _accept_loop(self) -> None:
-        """CFS_EVLOOP=0 shim: the pre-evloop thread-per-connection path."""
-        while not self._stop.is_set():
-            try:
-                conn, _ = self._listener.accept()
-            except OSError:
-                return
-            conn.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
-            threading.Thread(  # racelint: CFS_EVLOOP=0 rollback shim — evloop is the default serving path
-                target=self._serve_conn, args=(conn,), daemon=True).start()
-
-    def _serve_conn(self, conn: socket.socket) -> None:
-        """ServerConn analog (repl_protocol.go:219): packets in order per conn."""
-        try:
-            while not self._stop.is_set():
-                pkt = recv_packet(conn)
-                reply = self.dispatch(pkt)
-                send_packet(conn, reply)
-        except (ConnectionError, OSError):
-            pass
-        finally:
-            conn.close()
+        self._evloop.start()
 
     def stop(self) -> None:
-        self._stop.set()
-        if self._evloop is not None:
-            self._evloop.stop()
-        try:
-            self._listener.shutdown(socket.SHUT_RDWR)
-        except OSError:
-            pass
+        self._evloop.stop()
         self._listener.close()
         self.pool.close()
 

@@ -41,7 +41,7 @@ from chubaofs_tpu.proto.packet import (
     trace_reply,
 )
 from chubaofs_tpu.raft.server import NotLeaderError
-from chubaofs_tpu.rpc.evloop import EvloopServer, evloop_enabled
+from chubaofs_tpu.rpc.evloop import EvloopServer
 from chubaofs_tpu.utils.auditlog import record_slow_op
 from chubaofs_tpu.utils.exporter import registry
 
@@ -74,42 +74,11 @@ class MetaService:
         self._reg = registry("metanode")  # bound once: _handle is per-packet
         self.listener = socket.create_server((host, port))
         self.addr = f"{host}:{self.listener.getsockname()[1]}"
-        self._stop = threading.Event()
-        self._evloop: EvloopServer | None = None
-        if evloop_enabled():
-            # serving on the shared event-loop core: loop shards own the
-            # sockets, _handle runs on the bounded worker pool (it blocks on
-            # raft commits), per-connection order preserved
-            self._evloop = EvloopServer(self.listener, self._handle,
-                                        name="meta")
-            self._evloop.start()
-        else:
-            self._thread = threading.Thread(target=self._accept, daemon=True)
-            self._thread.start()
-
-    def _accept(self):
-        """CFS_EVLOOP=0 shim: the pre-evloop thread-per-connection path."""
-        while not self._stop.is_set():
-            try:
-                conn, _ = self.listener.accept()
-            except OSError:
-                return
-            conn.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
-            threading.Thread(  # racelint: CFS_EVLOOP=0 rollback shim — evloop is the default serving path
-                target=self._serve, args=(conn,), daemon=True).start()
-
-    def _serve(self, conn: socket.socket):
-        try:
-            while not self._stop.is_set():
-                pkt = recv_packet(conn)
-                send_packet(conn, self._handle(pkt))
-        except (ConnectionError, OSError):
-            pass
-        finally:
-            try:
-                conn.close()
-            except OSError:
-                pass
+        # serving on the shared event-loop core: loop shards own the
+        # sockets, _handle runs on the bounded worker pool (it blocks on
+        # raft commits), per-connection order preserved
+        self._evloop = EvloopServer(self.listener, self._handle, name="meta")
+        self._evloop.start()
 
     def _handle(self, pkt: Packet) -> Packet:
         """Dispatch wrapper: continues the packet's trace (span pushed so the
@@ -187,9 +156,7 @@ class MetaService:
                                            "error": f"{type(e).__name__}: {e}"})
 
     def close(self):
-        self._stop.set()
-        if self._evloop is not None:
-            self._evloop.stop()
+        self._evloop.stop()
         try:
             self.listener.close()
         except OSError:

@@ -31,7 +31,7 @@ import threading
 from chubaofs_tpu import chaos
 from chubaofs_tpu.raft import codec
 from chubaofs_tpu.raft.core import Entry, Msg
-from chubaofs_tpu.rpc.evloop import EvloopServer, evloop_enabled
+from chubaofs_tpu.rpc.evloop import EvloopServer
 
 _LEN = struct.Struct("<I")
 MAX_FRAME = 256 << 20  # a snapshot install rides one frame
@@ -73,21 +73,11 @@ def _pack(secret: bytes, msgs: list[Msg]) -> bytes:
     return _LEN.pack(len(payload)) + mac + payload
 
 
-def _recv_exact(sock: socket.socket, n: int) -> bytes:
-    buf = bytearray()
-    while len(buf) < n:
-        chunk = sock.recv(n - len(buf))
-        if not chunk:
-            raise ConnectionError("peer closed")
-        buf += chunk
-    return bytes(buf)
-
-
 class _FrameFramer:
     """Incremental reader for the [u32 len][32B MAC][payload] raft frame —
-    the evloop per-connection state machine twin of the blocking _serve
-    loop. Yields (mac, payload); oversized lengths raise and drop the
-    connection before a byte of the body is bought."""
+    the evloop's per-connection state machine. Yields (mac, payload);
+    oversized lengths raise and drop the connection before a byte of the
+    body is bought."""
 
     def __init__(self):
         self._stage = "len"
@@ -191,7 +181,6 @@ class TcpNet:
         self.node = None  # the local MultiRaft, set by register()
         self.links: dict[int, _PeerLink] = {}
         self._lock = threading.Lock()
-        self._stop = threading.Event()
 
         host, port = self.peers[node_id].rsplit(":", 1)
         if secret == DEFAULT_SECRET and host not in ("127.0.0.1", "localhost", "::1"):
@@ -202,20 +191,13 @@ class TcpNet:
         self.listener = socket.create_server((host, int(port)))
         self.listen_addr = f"{host}:{self.listener.getsockname()[1]}"
         self.peers[node_id] = self.listen_addr
-        self._evloop: EvloopServer | None = None
-        if evloop_enabled():
-            # inbound raft frames ride the shared event-loop core: verify +
-            # decode + deliver run on its worker pool (deliver takes node
-            # locks), fire-and-forget so encode=None
-            self._evloop = EvloopServer(self.listener, self._on_frame,
-                                        name="raft",
-                                        framer_factory=_FrameFramer,
-                                        encode=None)
-            self._evloop.start()
-        else:
-            self._accept_thread = threading.Thread(target=self._accept,
-                                                   daemon=True)
-            self._accept_thread.start()
+        # inbound raft frames ride the shared event-loop core: verify +
+        # decode + deliver run on its worker pool (deliver takes node
+        # locks), fire-and-forget so encode=None
+        self._evloop = EvloopServer(self.listener, self._on_frame,
+                                    name="raft", framer_factory=_FrameFramer,
+                                    encode=None)
+        self._evloop.start()
 
     # -- InProcNet surface ----------------------------------------------------
 
@@ -262,8 +244,7 @@ class TcpNet:
 
     def _on_frame(self, msg) -> None:
         """Evloop handler: one (mac, payload) frame — authenticate, decode,
-        deliver. Any failure raises, which drops THAT connection (the
-        blocking _serve loop's `return` on the same conditions)."""
+        deliver. Any failure raises, which drops THAT connection."""
         mac, payload = msg
         want = hmac.new(self.secret, payload, hashlib.sha256).digest()
         if not hmac.compare_digest(mac, want):
@@ -272,46 +253,8 @@ class TcpNet:
         if self.node is not None:
             self.node.deliver(msgs)
 
-    def _accept(self):
-        """CFS_EVLOOP=0 shim: the pre-evloop thread-per-connection path."""
-        while not self._stop.is_set():
-            try:
-                conn, _ = self.listener.accept()
-            except OSError:
-                return
-            conn.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
-            threading.Thread(  # racelint: CFS_EVLOOP=0 rollback shim — evloop is the default serving path
-                target=self._serve, args=(conn,), daemon=True).start()
-
-    def _serve(self, conn: socket.socket):
-        try:
-            while not self._stop.is_set():
-                (length,) = _LEN.unpack(_recv_exact(conn, _LEN.size))
-                if length > MAX_FRAME:
-                    return
-                mac = _recv_exact(conn, 32)
-                payload = _recv_exact(conn, length)
-                want = hmac.new(self.secret, payload, hashlib.sha256).digest()
-                if not hmac.compare_digest(mac, want):
-                    return  # unauthenticated frame: drop the connection
-                try:
-                    msgs = _unwire_msgs(codec.loads(payload))
-                except (codec.CodecError, TypeError, ValueError):
-                    return  # malformed frame: hostile or corrupt — drop conn
-                if self.node is not None:
-                    self.node.deliver(msgs)
-        except (ConnectionError, OSError):
-            pass
-        finally:
-            try:
-                conn.close()
-            except OSError:
-                pass
-
     def close(self):
-        self._stop.set()
-        if self._evloop is not None:
-            self._evloop.stop()
+        self._evloop.stop()
         try:
             self.listener.close()
         except OSError:

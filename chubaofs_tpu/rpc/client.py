@@ -29,7 +29,7 @@ _CONN_ERRORS = (ConnectionError, OSError, http.client.HTTPException)
 class RPCClient:
     def __init__(self, hosts: list[str], retries: int = 3, timeout: float = 30.0,
                  auth_secret: bytes | None = None, backoff: float = 0.05,
-                 pool=None, pooled: bool = True):
+                 pool=None):
         self.hosts = list(hosts)
         self.retries = retries
         self.timeout = timeout
@@ -38,10 +38,7 @@ class RPCClient:
         # the client is shared across pool workers: host rotation must not
         # lose/duplicate slots under concurrent do() — count() is atomic
         self._rr = itertools.count()
-        # pool=None -> the process-wide default; pooled=False -> a private
-        # connect-per-request NullPool (A/B control, socket-averse callers)
-        self._pool = pool if pool is not None else (
-            None if pooled else rpc_pool.NullPool(timeout=timeout))
+        self._pool = pool  # None -> the process-wide default
 
     @property
     def pool(self):

@@ -43,9 +43,6 @@ Instrumentation: `cfs_evloop_conns{srv,shard}` live connections per shard,
 shard}` pause events. Chaos: the `evloop.dispatch` failpoint fires before
 every handler call — `delay` injects service latency, `error` (a
 ConnectionError) drops that connection, exactly like a link cut mid-op.
-
-`CFS_EVLOOP=0` restores the threaded accept loops in data/repl.py,
-meta/service.py, and raft/transport.py for A/B and rollback.
 """
 
 from __future__ import annotations
@@ -63,12 +60,6 @@ from chubaofs_tpu import chaos
 from chubaofs_tpu.proto.packet import PacketFramer, advance_iov, packet_iov
 from chubaofs_tpu.utils.exporter import registry
 from chubaofs_tpu.utils.locks import SanitizedLock
-
-
-def evloop_enabled() -> bool:
-    """The CFS_EVLOOP escape hatch: default ON, =0 restores the threaded
-    path (checked at server start, so one process can A/B both)."""
-    return os.environ.get("CFS_EVLOOP", "1").lower() not in ("0", "false", "off")
 
 
 def _env_int(name: str, default: int) -> int:
@@ -149,8 +140,8 @@ class _Workers:
     (MiniCluster's 3 datanodes + 3 metanodes) would otherwise idle at
     n-per-server fixed threads — the very cost the evloop removes. Tasks
     are per-connection drain loops, so the queue never holds more than one
-    entry per live connection; daemon threads match the threaded path's
-    shutdown semantics (a blocked handler cannot hang process exit)."""
+    entry per live connection; daemon threads, so a blocked handler cannot
+    hang process exit."""
 
     _SENTINEL = None
 
@@ -472,8 +463,7 @@ class _LoopShard(threading.Thread):
                     and not conn.paused:
                 # fast sender, slow handler: parsed requests are piling
                 # up — stop READING so the flood stays in the kernel
-                # socket buffer (TCP backpressure to the peer), like the
-                # threaded path's one-recv-per-dispatch loop bounded it.
+                # socket buffer (TCP backpressure to the peer).
                 # paused flips INSIDE the append's critical section: a
                 # worker popping this very message must observe it, or
                 # its low-water resume check can race the pause and
@@ -518,8 +508,7 @@ class _LoopShard(threading.Thread):
                     self.send(conn, self.server.encode(reply),
                               close_after=self.server.close_reply(reply))
             except Exception:
-                # a handler- OR encode-escaping error is conn-fatal (the
-                # threaded path's serve thread died the same way); an error
+                # a handler- OR encode-escaping error is conn-fatal; an error
                 # swallowed with dispatching still True would wedge the conn
                 self.post(lambda c=conn: self._close(c))
                 with self._lock:
@@ -702,8 +691,14 @@ class EvloopServer:
 
     def stop(self) -> None:
         """Stop accepting, close every connection, release the workers. The
-        caller owns (and closes) the listener, same as the threaded path."""
+        caller owns (and closes) the listener."""
         self.stopping.set()
+        try:
+            # pop the acceptor out of accept(): a close alone leaves the
+            # LISTEN socket (and the port) alive until that syscall returns
+            self.listener.shutdown(socket.SHUT_RDWR)
+        except OSError:
+            pass
         for s in self.shards:
             s.wake()  # not post(): post refuses once stopping is set, and a
             # sleeping shard must still see the flag now, not a select

@@ -28,19 +28,14 @@ serving HTTP/1.1 instead of binary packets.
   * `Connection: close` (and HTTP/1.0 without keep-alive) rides the evloop's
     close-after-flush path: the reply fully drains, then the conn tears down.
 
-`CFS_EVLOOP_HTTP=0` restores the threaded ThreadingHTTPServer path in
-rpc/server.py for A/B and rollback — the same escape-hatch contract as
-CFS_EVLOOP on the packet servers.
-
-Not implemented (the daemons' HTTP dialect never uses them, matching the
-threaded path's Content-Length-only body reads): chunked transfer encoding
+Not implemented (the daemons' HTTP dialect never uses them; bodies are
+Content-Length only): chunked transfer encoding
 (501), obs-fold header continuations (400), interim 100-continue responses
 (the body is read and the final status answers; no client of ours waits).
 """
 
 from __future__ import annotations
 
-import os
 import socket
 import threading
 import time
@@ -59,14 +54,6 @@ MAX_HEADER_BYTES = 32 << 10
 MAX_BODY_BYTES = MAX_DATA_LEN
 # scratch recv buffer per connection (the greedy framer's `need()`)
 _SCRATCH = 64 << 10
-
-
-def http_evloop_enabled() -> bool:
-    """The CFS_EVLOOP_HTTP escape hatch: default ON, =0 restores the
-    threaded ThreadingHTTPServer path (checked at server construction, so
-    one process can A/B both)."""
-    return os.environ.get("CFS_EVLOOP_HTTP", "1").lower() \
-        not in ("0", "false", "off")
 
 
 class HttpRequest:
@@ -297,7 +284,7 @@ class HttpEvloopCore:
     """The evloop-backed HTTP server an RPCServer rides: owns the listener
     (SO_REUSEADDR so a restart rebinds the same port immediately — the PR-4
     reload bug class), wraps a `dispatch(Request) -> Response` callable, and
-    carries the threaded path's stop contract: stop accepting, DRAIN
+    carries the stop contract: stop accepting, DRAIN
     in-flight handlers (bounded), let queued replies flush, then hard-close
     every lingering keep-alive socket so a pooled client sees EOF and
     reconnects fresh instead of being served by a stopped stack."""
@@ -384,8 +371,7 @@ class HttpEvloopCore:
                     break  # wedged handler: don't hold the restart hostage
                 self._drain.wait(remaining)
         # in-flight handlers finished; their replies may still sit on write
-        # queues (the threaded path wrote synchronously inside the drained
-        # handler) — give the shards a bounded window to flush before the
+        # queues — give the shards a bounded window to flush before the
         # teardown hard-close discards them
         flush_deadline = time.monotonic() + min(2.0, drain_timeout)
         while self._pending_write_bytes() > 0 \

@@ -15,9 +15,9 @@ policy here for `http.client.HTTPConnection`:
   * thread-safe checkout (the RPCClient is shared across pool workers).
 
 Every `HTTPConnection` in the process is constructed HERE (obslint enforces
-it): the unpooled path is `NullPool`, which mints a fresh connection per
-checkout and closes on check-in — so the pooled/unpooled A/B in perfbench
-flips an object, not a code path.
+it): the unpooled transport is `NullPool`, which mints a fresh connection
+per checkout and closes on check-in — a caller that must not hold sockets
+passes an object, not a flag.
 
 Counters ride `registry("rpc")` (cfs_rpc_pool_*): reuse / miss / evict
 {reason}, so a bench or `cfs-stat` diff shows the realized hit rate. The
@@ -27,7 +27,6 @@ Counters ride `registry("rpc")` (cfs_rpc_pool_*): reuse / miss / evict
 from __future__ import annotations
 
 import http.client
-import os
 import time
 
 from chubaofs_tpu import chaos
@@ -47,12 +46,8 @@ class ConnectionPool:
     counted as evictions, healthy ones are parked for reuse (bounded,
     newest-first)."""
 
-    def __init__(self, max_idle_per_host: int | None = None,
-                 idle_ttl: float | None = None, timeout: float = 30.0):
-        if max_idle_per_host is None:
-            max_idle_per_host = int(os.environ.get("CFS_RPC_POOL_SIZE", "4"))
-        if idle_ttl is None:
-            idle_ttl = float(os.environ.get("CFS_RPC_POOL_TTL", "30"))
+    def __init__(self, max_idle_per_host: int = 4, idle_ttl: float = 30.0,
+                 timeout: float = 30.0):
         self.max_idle_per_host = max(1, max_idle_per_host)
         self.idle_ttl = idle_ttl
         self.timeout = timeout
@@ -148,9 +143,9 @@ class ConnectionPool:
 
 
 class NullPool:
-    """Connect-per-request transport with the pool's interface: the unpooled
-    control in A/B benches, and the opt-out for callers that must not hold
-    sockets (CFS_RPC_POOL=0)."""
+    """Connect-per-request transport with the pool's interface, for callers
+    that must not hold sockets (a one-shot scrape) and as the tests'
+    connect-per-request double."""
 
     def __init__(self, timeout: float = 30.0):
         self.timeout = timeout
@@ -176,20 +171,16 @@ class NullPool:
         pass
 
 
-_default: ConnectionPool | NullPool | None = None
+_default: ConnectionPool | None = None
 _default_lock = SanitizedLock(name="rpc.pool.default")
 
 
-def default_pool() -> ConnectionPool | NullPool:
-    """The process-wide pool every RPCClient rides unless handed its own.
-    CFS_RPC_POOL=0 makes it a NullPool (connect-per-request everywhere)."""
+def default_pool() -> ConnectionPool:
+    """The process-wide pool every RPCClient rides unless handed its own."""
     global _default
     with _default_lock:
         if _default is None:
-            if os.environ.get("CFS_RPC_POOL", "1") == "0":
-                _default = NullPool()
-            else:
-                _default = ConnectionPool()
+            _default = ConnectionPool()
         return _default
 
 

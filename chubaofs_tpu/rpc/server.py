@@ -1,4 +1,4 @@
-"""Threaded HTTP server bound to a Router, plus standard middleware.
+"""HTTP server bound to a Router, plus standard middleware.
 
 Reference counterpart: common/rpc's server glue + middleware stack — auditlog
 middleware (common/rpc/auditlog), shared-secret auth middleware
@@ -12,14 +12,12 @@ from __future__ import annotations
 
 import hashlib
 import hmac
-import threading
 import time
 import zlib
-from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 from chubaofs_tpu import chaos
-from chubaofs_tpu.rpc.httpevloop import HttpEvloopCore, http_evloop_enabled
-from chubaofs_tpu.rpc.router import Request, Response, Router, parse_request
+from chubaofs_tpu.rpc.httpevloop import HttpEvloopCore
+from chubaofs_tpu.rpc.router import Request, Response, Router
 
 AUTH_HEADER = "blob-auth"
 CRC_HEADER = "x-crc-body"
@@ -69,13 +67,10 @@ def audit_middleware(audit):
 
 
 def dispatch_request(router: Router, module: str, req: Request) -> Response:
-    """ONE request through the router with the serving-model-independent
-    plumbing both backends share: the `rpc.server.handle` failpoint (an
-    error here = the handler died before replying, the client sees a
+    """ONE request through the router: the `rpc.server.handle` failpoint
+    (an error here = the handler died before replying, the client sees a
     dropped connection), trace-span continuation, and the Trace-* reply
-    headers for traced callers. The evloop HTTP core and the threaded
-    fallback both call exactly this — the serving model is the ONLY
-    variable between them."""
+    headers for traced callers."""
     chaos.failpoint("rpc.server.handle")
     # continue (or root) the request's trace: handlers see the span via
     # trace.current_span(); its track log rides back on the response
@@ -109,13 +104,10 @@ def dispatch_request(router: Router, module: str, req: Request) -> Response:
 class RPCServer:
     """HTTP server hosting one Router; /metrics mounted by default.
 
-    Serving model (ISSUE 14): by default the evloop HTTP core
-    (rpc/httpevloop.py) — acceptor + loop shards + bounded worker pool, the
-    same machinery the packet servers ride, so thousands of keep-alive
-    connections cost registered sockets instead of parked threads.
-    `CFS_EVLOOP_HTTP=0` restores the ThreadingHTTPServer fallback for A/B
-    and rollback; both backends dispatch through `dispatch_request`, so
-    handlers, middleware, and the side-doors cannot tell them apart.
+    Serving model: the evloop HTTP core (rpc/httpevloop.py) — acceptor +
+    loop shards + bounded worker pool, the same machinery the packet
+    servers ride, so thousands of keep-alive connections cost registered
+    sockets instead of parked threads.
 
     /metrics renders the process's WHOLE registry set (the default registry
     plus every role registry — exporter.render_all), so any daemon role is
@@ -321,80 +313,13 @@ class RPCServer:
             flightrec.activate_from_env()
             _autopilot.activate_from_env()
 
-        outer = self
-        self._inflight = 0
-        self._drain = threading.Condition()
-        self._conns: set = set()  # live connection sockets (keep-alive aware)
-        self.httpd = None
-        self._evcore = None
-        self._thread: threading.Thread | None = None
-
-        if http_evloop_enabled():
-            # the evloop HTTP core: acceptor + loop shards + worker pool
-            # (rpc/httpevloop.py); drain/stop parity is the core's contract
-            self._evcore = HttpEvloopCore(
-                lambda req: dispatch_request(self.router, self.module, req),
-                host=host, port=port, name=module or "rpc")
-            self.addr = self._evcore.addr
-            self.port = self._evcore.port
-        else:
-            # threaded fallback (CFS_EVLOOP_HTTP=0): ThreadingHTTPServer,
-            # one thread per live connection — the pre-ISSUE-14 model, kept
-            # for A/B and rollback
-            class Handler(BaseHTTPRequestHandler):
-                protocol_version = "HTTP/1.1"
-
-                def setup(self):
-                    super().setup()
-                    with outer._drain:
-                        outer._conns.add(self.connection)
-
-                def finish(self):
-                    with outer._drain:
-                        outer._conns.discard(self.connection)
-                    super().finish()
-
-                def log_message(self, *a):  # silence default stderr chatter
-                    pass
-
-                def _serve(self):
-                    with outer._drain:
-                        outer._inflight += 1
-                    try:
-                        self._serve_inner()
-                    finally:
-                        with outer._drain:
-                            outer._inflight -= 1
-                            outer._drain.notify_all()
-
-                def _serve_inner(self):
-                    length = int(self.headers.get("Content-Length") or 0)
-                    body = self.rfile.read(length) if length else b""
-                    req = parse_request(self.command, self.path,
-                                        dict(self.headers.items()), body,
-                                        remote=self.client_address[0])
-                    resp = dispatch_request(outer.router, outer.module, req)
-                    self.send_response(resp.status)
-                    payload = b"" if self.command == "HEAD" else resp.body
-                    for k, v in resp.headers.items():
-                        self.send_header(k, v)
-                    # a handler-set Content-Length wins (HEAD responses
-                    # describe the body they didn't send)
-                    if not any(k.lower() == "content-length"
-                               for k in resp.headers):
-                        self.send_header("Content-Length",
-                                         str(len(resp.body)))
-                    self.end_headers()
-                    if payload:
-                        self.wfile.write(payload)
-
-                do_GET = do_PUT = do_POST = do_DELETE = do_HEAD = _serve
-                do_OPTIONS = _serve
-
-            self.httpd = ThreadingHTTPServer((host, port), Handler)
-            self.httpd.daemon_threads = True
-            self.addr = f"{host}:{self.httpd.server_address[1]}"
-            self.port = self.httpd.server_address[1]
+        # the evloop HTTP core: acceptor + loop shards + worker pool
+        # (rpc/httpevloop.py); drain/stop is the core's contract
+        self._evcore = HttpEvloopCore(
+            lambda req: dispatch_request(self.router, self.module, req),
+            host=host, port=port, name=module or "rpc")
+        self.addr = self._evcore.addr
+        self.port = self._evcore.port
         if metrics:
             # identity + boot stamp (the events satellite): every daemon
             # exports cfs_boot_time_seconds (wall, cross-process protocol —
@@ -416,49 +341,14 @@ class RPCServer:
                                 "version": chubaofs_tpu.__version__})
 
     def start(self):
-        if self._evcore is not None:
-            self._evcore.start()
-            return self
-        self._thread = threading.Thread(target=self.httpd.serve_forever,
-                                        name=f"rpc@{self.addr}", daemon=True)
-        self._thread.start()
+        self._evcore.start()
         return self
 
     def stop(self, drain_timeout: float = 10.0):
         """Stop accepting, then DRAIN: wait for in-flight handlers to finish
         (bounded) before returning — the graceful-restart contract the
         blobstore module reload depends on (blobstore/cmd/cmd.go analog).
-        Both backends then hard-close lingering keep-alive sockets, so a
+        The core then hard-closes lingering keep-alive sockets, so a
         reload can never leave old-stack handlers serving pooled clients
         and the port rebinds immediately."""
-        if self._evcore is not None:
-            self._evcore.stop(drain_timeout)
-            return
-        self.httpd.shutdown()
-        self.httpd.server_close()
-        deadline = time.monotonic() + drain_timeout
-        with self._drain:
-            while self._inflight > 0:
-                remaining = deadline - time.monotonic()
-                if remaining <= 0:
-                    break  # wedged handler: don't hold the restart hostage
-                self._drain.wait(remaining)
-            conns = list(self._conns)
-        # keep-alive connections OUTLIVE shutdown(): their handler threads sit
-        # in readline() waiting for the next request, and a pooled client
-        # would keep being served by THIS stopped stack (a reload would leave
-        # requests landing on closed components). Hard-close them — parked
-        # client conns see EOF and their pool evicts + reconnects fresh.
-        import socket as _socket
-
-        for c in conns:
-            try:
-                c.shutdown(_socket.SHUT_RDWR)
-            except OSError:
-                pass
-            try:
-                c.close()
-            except OSError:
-                pass
-        if self._thread:
-            self._thread.join(timeout=5)
+        self._evcore.stop(drain_timeout)

@@ -464,6 +464,7 @@ def bench_put_pipeline(root: str, blob_kb: int = 64, n_puts: int = 8,
     access registry, and the rpc pool hit rate over the pooled phase."""
     from chubaofs_tpu.blobstore.cluster import MiniCluster
     from chubaofs_tpu.blobstore.gateway import AccessClient, AccessGateway
+    from chubaofs_tpu.rpc.pool import NullPool
     from chubaofs_tpu.utils import exporter
 
     c = MiniCluster(os.path.join(root, "blob"), n_nodes=n_nodes,
@@ -478,8 +479,8 @@ def bench_put_pipeline(root: str, blob_kb: int = 64, n_puts: int = 8,
 
     out: dict = {}
     rng_data = {nb: os.urandom(nb * blob_kb * 1024) for nb in blob_counts}
-    clients = {False: AccessClient([gw.addr], pooled=False),
-               True: AccessClient([gw.addr], pooled=True)}
+    clients = {False: AccessClient([gw.addr], pool=NullPool()),
+               True: AccessClient([gw.addr])}
     variants = [(pooled, window)
                 for pooled in (False, True) for window in (0, 3)]
     pool_hits = pool_misses = 0.0
@@ -773,173 +774,14 @@ def bench_repair_codes(root: str, n_nodes: int = 17, stripes: int = 12,
     return out
 
 
-def _conc_driver(addr: str, n_socks: int, ops: int, payload: int) -> None:
-    """Subprocess body for bench_concurrency's load generator. Runs OUT of
-    the server's process: an in-process driver shares the server's GIL, and
-    at 256+ clients the load generation drowns out the serving-model
-    difference the A/B exists to measure. Protocol with the parent: connect
-    + warm every socket, print READY, block for GO on stdin, run the timed
-    loop, print one JSON line of per-request latencies (ms)."""
-    import socket as _socket
-    import threading
-
-    from chubaofs_tpu.proto.packet import (
-        OP_WRITE, Packet, recv_packet, send_packet)
-
-    host, port = addr.rsplit(":", 1)
-    req = Packet(OP_WRITE, partition_id=1, extent_id=65,
-                 data=b"\xa7" * payload)
-    socks = []
-    for _ in range(n_socks):
-        s = _socket.create_connection((host, int(port)))
-        s.settimeout(60)
-        s.setsockopt(_socket.IPPROTO_TCP, _socket.TCP_NODELAY, 1)
-        send_packet(s, req)  # warm: conn registration, framer state
-        recv_packet(s)
-        socks.append(s)
-    print("READY", flush=True)
-    sys.stdin.readline()  # GO
-    n_threads = max(1, min(8, n_socks))
-    chunks = [socks[t::n_threads] for t in range(n_threads)]
-    lats: list[list[float]] = [[] for _ in range(n_threads)]
-
-    def run(t: int) -> None:
-        mine, out = chunks[t], lats[t]
-        t0s = [0.0] * len(mine)
-        for _ in range(ops):
-            for i, s in enumerate(mine):  # one in-flight request per socket
-                t0s[i] = time.perf_counter()
-                send_packet(s, req)
-            for i, s in enumerate(mine):
-                recv_packet(s)
-                out.append(time.perf_counter() - t0s[i])
-
-    threads = [threading.Thread(target=run, args=(t,), daemon=True)
-               for t in range(n_threads)]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join()
-    for s in socks:
-        s.close()
-    print(json.dumps([round(x * 1000.0, 3) for chunk in lats
-                      for x in chunk]), flush=True)
-
-
-_CONC_DRIVER_CMD = (
-    "import sys\n"
-    "from chubaofs_tpu.tools.perfbench import _conc_driver\n"
-    "_conc_driver(sys.argv[1], int(sys.argv[2]), int(sys.argv[3]),"
-    " int(sys.argv[4]))\n")
-
-
-def bench_concurrency(clients_axis: tuple = (64, 256, 1024),
-                      ops_per_client: int = 20, payload: int = 4096) -> dict:
-    """High fan-in packet-serving A/B (ISSUE 8): ops/s and p99 latency at
-    64/256/1024 concurrent packet connections, event-loop serving vs the
-    CFS_EVLOOP=0 thread-per-connection baseline, against a real ReplServer
-    whose dispatch does representative per-op work (CRC verify + small
-    reply). The client harness is identical in both phases — up to 4
-    subprocess drivers (own GIL each, see _conc_driver) with 8 threads
-    apiece, one in-flight request per socket — so the only variable is the
-    serving model. Per-request latency is measured send→reply per socket;
-    p99 over every request of the phase, so fan-in queueing (the thing
-    thread stacks and GIL churn inflate) lands in the number."""
-    from chubaofs_tpu.data.repl import ReplServer
-    from chubaofs_tpu.proto.packet import Packet, RES_OK
-
-    repo = os.path.dirname(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))))
-
-    def dispatch(pkt: Packet) -> Packet:
-        # representative op cost: payload CRC + a small ack (the datanode
-        # write path's shape without the disk)
-        ok = pkt.verify_crc()
-        return pkt.reply(RES_OK if ok else 1, data=bytes(pkt.data[:32]))
-
-    def phase(mode: str, n_clients: int) -> tuple[float, float]:
-        prev_env = os.environ.get("CFS_EVLOOP")
-        os.environ["CFS_EVLOOP"] = "1" if mode == "evloop" else "0"
-        srv = None
-        procs: list[subprocess.Popen] = []
-        try:
-            srv = ReplServer("127.0.0.1:0", dispatch)
-            srv.start()
-            n_procs = max(1, min(4, n_clients // 16))
-            per = n_clients // n_procs
-            env = dict(os.environ)
-            env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
-            procs = [
-                subprocess.Popen(
-                    [sys.executable, "-c", _CONC_DRIVER_CMD, srv.addr,
-                     str(per), str(ops_per_client), str(payload)],
-                    stdin=subprocess.PIPE, stdout=subprocess.PIPE,
-                    env=env, text=True)
-                for _ in range(n_procs)
-            ]
-            for p in procs:  # all sockets connected + warmed before the clock
-                if p.stdout.readline().strip() != "READY":
-                    raise RuntimeError(
-                        f"concurrency driver died during warm-up "
-                        f"({mode}, {n_clients}c)")
-            t0 = time.perf_counter()
-            for p in procs:
-                p.stdin.write("GO\n")
-                p.stdin.flush()
-            all_lats: list[float] = []
-            for p in procs:
-                line = p.stdout.readline()
-                if not line.strip():
-                    raise RuntimeError(
-                        f"concurrency driver died mid-run "
-                        f"({mode}, {n_clients}c)")
-                all_lats.extend(json.loads(line))
-            dt = time.perf_counter() - t0
-            for p in procs:
-                p.wait(timeout=30)
-            if len(all_lats) != n_procs * per * ops_per_client:
-                raise RuntimeError(
-                    f"concurrency driver dropped requests "
-                    f"({mode}, {n_clients}c): {len(all_lats)}")
-            all_lats.sort()
-            p99 = all_lats[min(len(all_lats) - 1, int(0.99 * len(all_lats)))]
-            return len(all_lats) / dt, p99
-        finally:
-            for p in procs:
-                if p.poll() is None:
-                    p.kill()
-            if srv is not None:
-                srv.stop()
-            if prev_env is None:
-                os.environ.pop("CFS_EVLOOP", None)
-            else:
-                os.environ["CFS_EVLOOP"] = prev_env
-
-    out: dict = {}
-    for n in clients_axis:
-        for mode in ("threads", "evloop"):
-            ops, p99 = phase(mode, n)
-            out[f"conc_ops_{n}c_{mode}"] = round(ops, 1)
-            out[f"conc_p99_ms_{n}c_{mode}"] = round(p99, 2)
-            log(f"  concurrency {n}c {mode}: {out[f'conc_ops_{n}c_{mode}']} "
-                f"ops/s, p99 {out[f'conc_p99_ms_{n}c_{mode}']} ms")
-        out[f"conc_speedup_{n}c"] = round(
-            out[f"conc_ops_{n}c_evloop"]
-            / max(0.001, out[f"conc_ops_{n}c_threads"]), 2)
-        out[f"conc_p99_ratio_{n}c"] = round(
-            out[f"conc_p99_ms_{n}c_evloop"]
-            / max(0.001, out[f"conc_p99_ms_{n}c_threads"]), 2)
-    return out
-
-
 def _gw_driver(addr: str, url: str, n_socks: int, ops: int,
                tolerate: int = 0) -> None:
     """Subprocess body for the gateway benches' load generator: keep-alive
     S3 GETs of one presigned URL over `n_socks` http.client connections,
-    one in-flight request per connection — OUT of the server's process for
-    the same reason as _conc_driver (an in-process driver measures the load
-    generator, not the serving model). Pure stdlib: the URL is presigned by
-    the parent, so the driver needs no signing code. `tolerate=1` accepts
+    one in-flight request per connection — OUT of the server's process (an
+    in-process driver shares the server's GIL and measures the load
+    generator, not the server). Pure stdlib: the URL is presigned by the
+    parent, so the driver needs no signing code. `tolerate=1` accepts
     throttle statuses (429/503) and reports per-status counts (the QoS
     fairness bench's noisy tenant); otherwise any non-200 aborts the run.
     Protocol: connect + warm every socket, print READY, block for GO, run,
@@ -1097,7 +939,7 @@ def _p99(lats: list[float]) -> float:
 class _S3Fixture:
     """One FsCluster + ObjectNode the gateway benches serve: a bucket, a
     small object, presign() for driver URLs, serve()/stop() to bring an
-    RPCServer up under the CURRENT CFS_EVLOOP_HTTP mode."""
+    RPCServer up."""
 
     AK, SK = "benchak", "benchsk"
 
@@ -1158,80 +1000,6 @@ class _S3Fixture:
     def close(self):
         self.stop_server()
         self.cluster.close()
-
-
-def bench_gateway(root: str, clients_axis: tuple = (64, 256, 1024),
-                  ops_per_client: int = 10, payload: int = 2048) -> dict:
-    """Gateway serving-model A/B (ISSUE 14): ops/s and p99 at 64/256/1024
-    keep-alive S3 client connections doing presigned GETs against a REAL
-    ObjectNode over a real FsCluster — evloop HTTP core vs the
-    CFS_EVLOOP_HTTP=0 ThreadingHTTPServer baseline, the bench_concurrency
-    shape ported to the HTTP plane. Drivers are subprocesses (own GIL);
-    the server is rebuilt per phase under the phase's serving mode; every
-    request must be HTTP 200. The headline number is FLATNESS: evloop
-    throughput at 1024c vs its own 64c value, where the threaded control
-    degrades under 1024 parked handler threads."""
-    fix = _S3Fixture(os.path.join(root, "gwbench"), payload=payload)
-    out: dict = {}
-    try:
-        def phase(mode: str, n_clients: int) -> tuple[float, float]:
-            prev = os.environ.get("CFS_EVLOOP_HTTP")
-            os.environ["CFS_EVLOOP_HTTP"] = "1" if mode == "evloop" else "0"
-            procs: list[subprocess.Popen] = []
-            try:
-                addr = fix.serve()
-                if not out:  # first phase creates the bucket + object
-                    fix.put_object()
-                url = fix.presign()
-                n_procs = max(1, min(4, n_clients // 16))
-                per = n_clients // n_procs
-                procs = [_spawn_driver(
-                    _GW_DRIVER_CMD, [addr, url, per, ops_per_client, 0])
-                    for _ in range(n_procs)]
-                t0 = time.perf_counter()
-                outs = _drive(procs, f"gateway {mode} {n_clients}c")
-                dt = time.perf_counter() - t0
-                lats = [x for o in outs for x in o["lats"]]
-                bad = {k: v for o in outs for k, v in o["statuses"].items()
-                       if k != "200"}
-                if bad or len(lats) != n_procs * per * ops_per_client:
-                    raise RuntimeError(
-                        f"gateway driver anomalies ({mode}, {n_clients}c): "
-                        f"bad={bad} n={len(lats)}")
-                return len(lats) / dt, _p99(lats)
-            finally:
-                for p in procs:
-                    if p.poll() is None:
-                        p.kill()
-                fix.stop_server()
-                if prev is None:
-                    os.environ.pop("CFS_EVLOOP_HTTP", None)
-                else:
-                    os.environ["CFS_EVLOOP_HTTP"] = prev
-
-        for n in clients_axis:
-            for mode in ("threads", "evloop"):
-                ops, p99 = phase(mode, n)
-                out[f"gw_ops_{n}c_{mode}"] = round(ops, 1)
-                out[f"gw_p99_ms_{n}c_{mode}"] = round(p99, 2)
-                log(f"  gateway {n}c {mode}: {out[f'gw_ops_{n}c_{mode}']} "
-                    f"ops/s, p99 {out[f'gw_p99_ms_{n}c_{mode}']} ms")
-            out[f"gw_speedup_{n}c"] = round(
-                out[f"gw_ops_{n}c_evloop"]
-                / max(0.001, out[f"gw_ops_{n}c_threads"]), 2)
-        lo, hi = clients_axis[0], clients_axis[-1]
-        out["gw_flatness_evloop"] = round(
-            out[f"gw_ops_{hi}c_evloop"]
-            / max(0.001, out[f"gw_ops_{lo}c_evloop"]), 2)
-        out["gw_flatness_threads"] = round(
-            out[f"gw_ops_{hi}c_threads"]
-            / max(0.001, out[f"gw_ops_{lo}c_threads"]), 2)
-        log(f"  gateway flatness {lo}c->{hi}c: evloop "
-            f"{out['gw_flatness_evloop']}x vs threads "
-            f"{out['gw_flatness_threads']}x")
-    finally:
-        fix.close()
-    return out
 
 
 def bench_qos_fairness(root: str, parent_rps: float = 50.0,
@@ -1357,16 +1125,27 @@ def bench_capacity(root: str, duration: float = 3.5, rate: float = 20.0,
         col.start()
         try:
             ledger = wl.run()
-            time.sleep(2 * interval)  # the tail burn windows land
+            # the tail burn windows land: two more polls, and three in all,
+            # counted and not timed (a poll takes what the host lets it)
+            want = max(3, col.frames + 2)
+            deadline = time.monotonic() + 60.0
+            while col.frames < want and time.monotonic() < deadline:
+                time.sleep(interval)
         finally:
             col.stop()
             wl.close()
         return col.verdict(), ledger
 
-    prev_slo = os.environ.get("CFS_SLO_PUT_P99_MS")
+    prev_slo = {k: os.environ.get(k)
+                for k in ("CFS_SLO_PUT_P99_MS", "CFS_SLO_GET_P99_MS")}
     try:
         c.create_volume("capvol", cold=True)
         c.blobstore.access.put(b"warm" * 256)  # jit outside the window
+        # clean phase: latency objectives no host reaches (an op that cold-
+        # compiles on a loaded host takes seconds, and 2 s is the default),
+        # so only a real fault flips it: errors, backpressure, a dark target
+        for k in prev_slo:
+            os.environ[k] = "600000"
         verdict, ledger = phase(os.path.join(root, "capacity-clean.jsonl"))
         out["cap_frames_clean"] = verdict["frames"]
         out["cap_verdict_clean"] = verdict["verdict"]
@@ -1377,9 +1156,10 @@ def bench_capacity(root: str, duration: float = 3.5, rate: float = 20.0,
         log(f"  capacity clean: verdict={verdict['verdict']} "
             f"frames={verdict['frames']} ops_ok={ledger['ops_ok']}"
             f"/{ledger['ops_planned']}")
-        # chaos phase: sustained shard-write latency + a 20ms objective
+        # chaos phase: a 20 ms objective under a sustained shard-write
+        # delay of ten times that, so the flip never depends on the host
         os.environ["CFS_SLO_PUT_P99_MS"] = "20"
-        chaos.arm("blobnode.put_shard", "delay(0.03)")
+        chaos.arm("blobnode.put_shard", "delay(0.2)")
         try:
             verdict2, _ = phase(os.path.join(root, "capacity-chaos.jsonl"))
         finally:
@@ -1390,10 +1170,11 @@ def bench_capacity(root: str, duration: float = 3.5, rate: float = 20.0,
         log(f"  capacity chaos: verdict={verdict2['verdict']} "
             f"flipped={out['cap_chaos_flipped']}")
     finally:
-        if prev_slo is None:
-            os.environ.pop("CFS_SLO_PUT_P99_MS", None)
-        else:
-            os.environ["CFS_SLO_PUT_P99_MS"] = prev_slo
+        for k, v in prev_slo.items():
+            if v is None:
+                os.environ.pop(k, None)
+            else:
+                os.environ[k] = v
         console.stop()
         srv.stop()
         c.close()
@@ -1829,17 +1610,7 @@ def run(root: str, n_files: int = 600, n_clients: int = 4,
     log("metadata scale-out (1 -> 4 partitions, load splits)...")
     cfg.update(bench_meta_scale(os.path.join(root, "metascale"),
                                 files_per_phase=max(12, n_files // 50)))
-    # the sweep saturates every core for a minute and CPU-throttled hosts
-    # recover slowly, so it must run AFTER the cluster phases or their
-    # throughput floors deflate ~2x; its own A/B is phase-internal, so
-    # position costs it nothing. It also scales with n_files like the other
-    # phases — smoke-size invocations get a smoke-size sweep.
-    log("concurrent-connection sweep (evloop vs threaded A/B)...")
-    if n_files >= 300:
-        cfg.update(bench_concurrency())
-    else:
-        cfg.update(bench_concurrency(clients_axis=(64, 256), ops_per_client=6))
-    # like bench_concurrency, the cache A/B runs AFTER the cluster phases:
+    # the cache A/B runs AFTER the cluster phases:
     # its two MiniClusters + tight GET loops leave a throttle-recovering
     # host deflating the md/stream floors ~2x (measured: create_ops_1c
     # 12 -> 5.5 with this phase ahead of them); both its arms are
@@ -1859,17 +1630,8 @@ def run(root: str, n_files: int = 600, n_clients: int = 4,
     else:  # smoke invocations get a smoke-size range sweep
         cfg.update(bench_ranged(os.path.join(root, "rangedbench"),
                                 blob_mb=2, range_kbs=(16, 256), gets_per=2))
-    # the gateway phases run AFTER the ProcCluster phases for the same
-    # reason as bench_concurrency/bench_cache_zipf (the PR-8/PR-12 floor-
-    # deflation lesson): the 1024-conn sweep saturates every core, and a
-    # throttle-recovering host would deflate the md/stream floors; both
-    # arms of each A/B are phase-internal, so position costs nothing
-    log("gateway serving-model sweep (evloop HTTP vs threaded A/B)...")
-    if n_files >= 300:
-        cfg.update(bench_gateway(os.path.join(root, "gwroot")))
-    else:
-        cfg.update(bench_gateway(os.path.join(root, "gwroot"),
-                                 clients_axis=(32, 128), ops_per_client=6))
+    # the gateway phase runs AFTER the ProcCluster phases for the same
+    # reason as bench_cache_zipf (the PR-8/PR-12 floor-deflation lesson)
     log("gateway QoS fairness (noisy tenant vs victim tenant)...")
     cfg.update(bench_qos_fairness(os.path.join(root, "qosroot")))
     # repair-traffic codes A/B rides the same post-ProcCluster slot (floor-

@@ -41,9 +41,9 @@ mistakes that actually bite that kind of code:
    `threading.Thread(target=..., args=(conn,...))` spawned per accepted
    connection is the scaling wall ISSUE 8 removed: at hundreds of clients
    the thread stacks and GIL churn dominate before the network does.
-   Packet serving rides `rpc/evloop.py` (loop shards + bounded workers);
-   the CFS_EVLOOP=0 rollback shims carry the pragma. `rpc/evloop.py` and
-   `proto/packet.py` are exempt by path (they ARE the sanctioned layer).
+   Packet serving rides `rpc/evloop.py` (loop shards + bounded workers).
+   `rpc/evloop.py` and `proto/packet.py` are exempt by path (they ARE the
+   sanctioned layer).
 
 Exceptions carry a `# racelint: <why>` pragma on the flagged line, or a
 per-file allowlist entry below — both REQUIRE a written reason. Shared
@@ -366,9 +366,8 @@ _CONNISH = ("conn", "sock", "client", "peer")
 
 def _scan_thread_per_conn(tree: ast.AST, relpath: str, flag) -> None:
     """Rule 5: `threading.Thread(target=..., args=(conn,...))` — one thread
-    per accepted connection. The evloop core replaced this; only the
-    CFS_EVLOOP=0 shims (pragma'd) and evloop/packet themselves may spawn
-    per-connection service threads."""
+    per accepted connection. The evloop core replaced this; only
+    evloop/packet themselves may spawn per-connection service threads."""
     if lintcore.path_matches(relpath, _EVLOOP_PATHS):
         return
     for node in ast.walk(tree):
@@ -385,8 +384,7 @@ def _scan_thread_per_conn(tree: ast.AST, relpath: str, flag) -> None:
                      "thread-per-connection serving — a full OS thread per "
                      "accepted conn is the scale wall the evloop removed "
                      "(ISSUE 8); register the socket on rpc/evloop.py's "
-                     "loop shards instead, or pragma the CFS_EVLOOP=0 shim "
-                     "with its reason")
+                     "loop shards instead")
                 break
 
 

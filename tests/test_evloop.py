@@ -1,6 +1,6 @@
 """Event-loop packet data path (ISSUE 8): zero-copy framing invariants,
-evloop-vs-threaded serving matrix, write-queue backpressure fairness, chaos
-failpoints on evloop connections, and restart hygiene."""
+the one serving path of every server, write-queue backpressure fairness,
+chaos failpoints on evloop connections, and restart hygiene."""
 
 from __future__ import annotations
 
@@ -181,21 +181,19 @@ def test_decode_header_bounds_claimed_lengths():
         fr.feed(bytearray(hdr(0xFFFFFFFF, 0)))
 
 
-# -- serving matrix: evloop and threaded shim ----------------------------------
+# -- serving: every server rides the event-loop core ---------------------------
 
 
 def _echo_dispatch(pkt: Packet) -> Packet:
     return pkt.reply(RES_OK, data=bytes(pkt.data))
 
 
-@pytest.fixture(params=["1", "0"], ids=["evloop", "threaded"])
-def repl_server(request, monkeypatch):
+@pytest.fixture
+def repl_server():
     from chubaofs_tpu.data.repl import ReplServer
 
-    monkeypatch.setenv("CFS_EVLOOP", request.param)
     srv = ReplServer("127.0.0.1:0", _echo_dispatch)
     srv.start()
-    assert (srv._evloop is not None) == (request.param == "1")
     yield srv
     srv.stop()
 
@@ -207,7 +205,7 @@ def _connect(addr: str) -> socket.socket:
     return s
 
 
-def test_repl_roundtrip_both_modes(repl_server):
+def test_repl_roundtrip(repl_server):
     s = _connect(repl_server.addr)
     try:
         send_packet(s, Packet(OP_WRITE, partition_id=1, data=PAYLOAD))
@@ -233,42 +231,136 @@ def test_repl_pipelined_burst_stays_in_order(repl_server):
         s.close()
 
 
-def test_meta_service_both_modes(monkeypatch):
+class _StubMeta:
+    partitions: dict = {}
+
+    def read_dir(self, pid, parent):
+        return [{"name": "f", "ino": 2, "pid": pid, "parent": parent}]
+
+
+def test_meta_service_roundtrip():
     from chubaofs_tpu.meta.service import MetaService, RemoteMetaNode
 
-    class _StubMeta:
-        partitions: dict = {}
+    svc = MetaService(_StubMeta())
+    try:
+        rmn = RemoteMetaNode(svc.addr)
+        out = rmn.read_dir(7, 1)
+        assert out[0]["pid"] == 7 and out[0]["parent"] == 1
+        rmn.close()
+    finally:
+        svc.close()
 
-        def read_dir(self, pid, parent):
-            return [{"name": "f", "ino": 2, "pid": pid, "parent": parent}]
 
-    for mode in ("1", "0"):
-        monkeypatch.setenv("CFS_EVLOOP", mode)
-        svc = MetaService(_StubMeta())
+# each rig serves on `addr` and returns (addr, core, ping, stop): the bound
+# address, the server's event-loop core, one round trip, the server's stop
+
+
+def _rig_repl(addr):
+    from chubaofs_tpu.data.repl import ReplServer
+
+    srv = ReplServer(addr, _echo_dispatch)
+    srv.start()
+
+    def ping():
+        s = _connect(srv.addr)
         try:
-            rmn = RemoteMetaNode(svc.addr)
-            out = rmn.read_dir(7, 1)
-            assert out[0]["pid"] == 7 and out[0]["parent"] == 1
-            rmn.close()
+            send_packet(s, Packet(OP_HEARTBEAT))
+            return recv_packet(s).result == RES_OK
         finally:
-            svc.close()
+            s.close()
+
+    return srv.addr, srv._evloop, ping, srv.stop
 
 
-def test_evloop_env_escape_hatch(monkeypatch):
-    from chubaofs_tpu.rpc.evloop import evloop_enabled
+def _rig_meta(addr):
+    from chubaofs_tpu.meta.service import MetaService, RemoteMetaNode
 
-    monkeypatch.delenv("CFS_EVLOOP", raising=False)
-    assert evloop_enabled()  # default ON
+    host, port = addr.rsplit(":", 1)
+    svc = MetaService(_StubMeta(), host=host, port=int(port))
+
+    def ping():
+        rmn = RemoteMetaNode(svc.addr)
+        try:
+            return rmn.read_dir(7, 1)[0]["pid"] == 7
+        finally:
+            rmn.close()
+
+    return svc.addr, svc._evloop, ping, svc.close
+
+
+def _rig_raft(addr):
+    from chubaofs_tpu.raft.core import Msg
+    from chubaofs_tpu.raft.transport import DEFAULT_SECRET, TcpNet, _pack
+
+    net = TcpNet(1, {1: addr})
+    got = threading.Event()
+
+    class _Node:
+        def deliver(self, msgs):
+            if msgs and msgs[0].type == "vote_req" and msgs[0].term == 9:
+                got.set()
+
+    net.register(_Node())
+
+    def ping():
+        got.clear()
+        s = _connect(net.listen_addr)
+        try:
+            s.sendall(_pack(DEFAULT_SECRET, [Msg("vote_req", 7, 2, 1, 9)]))
+            return got.wait(5.0)
+        finally:
+            s.close()
+
+    return net.listen_addr, net._evloop, ping, net.close
+
+
+def _rig_rpc(addr):
+    from chubaofs_tpu.rpc import RPCClient, RPCServer, Response, Router
+    from chubaofs_tpu.rpc.pool import NullPool
+
+    host, port = addr.rsplit(":", 1)
+    router = Router()
+    router.get("/ping", lambda r: Response(200, {}, b"pong"))
+    srv = RPCServer(router, host=host, port=int(port), metrics=False).start()
+
+    def ping():
+        cli = RPCClient([srv.addr], retries=1, pool=NullPool())
+        return cli.do("GET", "/ping")[0] == 200
+
+    return srv.addr, srv._evcore, ping, srv.stop
+
+
+@pytest.mark.parametrize("rig", [_rig_repl, _rig_meta, _rig_raft, _rig_rpc],
+                         ids=["ReplServer", "MetaService", "TcpNet",
+                              "RPCServer"])
+def test_server_has_one_serving_path(rig, monkeypatch):
+    """The names that once selected a thread-per-connection server select
+    nothing: with both set to 0 each server still serves on the event-loop
+    core, stops, and the port rebinds."""
+    from chubaofs_tpu.rpc.evloop import EvloopServer
+    from chubaofs_tpu.rpc.httpevloop import HttpEvloopCore
+
     monkeypatch.setenv("CFS_EVLOOP", "0")
-    assert not evloop_enabled()
+    monkeypatch.setenv("CFS_EVLOOP_HTTP", "0")
+    addr, core, ping, stop = rig("127.0.0.1:0")
+    try:
+        assert isinstance(core, (EvloopServer, HttpEvloopCore))
+        assert ping()
+    finally:
+        stop()
+    addr2, core2, ping2, stop2 = rig(addr)
+    try:
+        assert addr2 == addr and core2 is not core
+        assert ping2()
+    finally:
+        stop2()
 
 
-def test_repl_restart_rebinds_same_port(monkeypatch):
+def test_repl_restart_rebinds_same_port():
     """Crash-restart hygiene: stop tears the loop down completely; a new
     server binds the same port and serves."""
     from chubaofs_tpu.data.repl import ReplServer
 
-    monkeypatch.setenv("CFS_EVLOOP", "1")
     srv = ReplServer("127.0.0.1:0", _echo_dispatch)
     srv.start()
     addr = srv.addr
@@ -438,8 +530,6 @@ def test_chaos_delay_on_evloop_dispatch(repl_server):
 
     s = _connect(repl_server.addr)
     try:
-        if repl_server._evloop is None:
-            pytest.skip("evloop.dispatch failpoint is the evloop's site")
         chaos.arm("evloop.dispatch", "delay(0.2)*1")
         t0 = time.perf_counter()
         send_packet(s, Packet(OP_HEARTBEAT))
@@ -450,14 +540,13 @@ def test_chaos_delay_on_evloop_dispatch(repl_server):
         s.close()
 
 
-def test_chaos_link_drop_kills_one_conn_not_the_server(monkeypatch):
+def test_chaos_link_drop_kills_one_conn_not_the_server():
     """An injected ConnectionError in dispatch drops THAT connection (the
     wire contract for a link cut mid-op); the server and other connections
     keep serving."""
     from chubaofs_tpu import chaos
     from chubaofs_tpu.data.repl import ReplServer
 
-    monkeypatch.setenv("CFS_EVLOOP", "1")
     srv = ReplServer("127.0.0.1:0", _echo_dispatch)
     srv.start()
     try:
@@ -479,11 +568,10 @@ def test_chaos_link_drop_kills_one_conn_not_the_server(monkeypatch):
 # -- conn-pool parity (ISSUE 8 satellite) --------------------------------------
 
 
-def test_conn_pool_counters_and_eviction(monkeypatch):
+def test_conn_pool_counters_and_eviction():
     from chubaofs_tpu.utils import exporter
     from chubaofs_tpu.utils.conn_pool import ConnPool
 
-    monkeypatch.setenv("CFS_EVLOOP", "1")
     from chubaofs_tpu.data.repl import ReplServer
 
     srv = ReplServer("127.0.0.1:0", _echo_dispatch)
@@ -513,11 +601,10 @@ def test_conn_pool_counters_and_eviction(monkeypatch):
 # -- evloop metrics -------------------------------------------------------------
 
 
-def test_evloop_metrics_families(monkeypatch):
+def test_evloop_metrics_families():
     from chubaofs_tpu.data.repl import ReplServer
     from chubaofs_tpu.utils import exporter
 
-    monkeypatch.setenv("CFS_EVLOOP", "1")
     srv = ReplServer("127.0.0.1:0", _echo_dispatch)
     srv.start()
     try:

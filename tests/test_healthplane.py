@@ -113,13 +113,21 @@ def test_capture_attributes_named_threads_with_stacks():
             x += 1
         return x
 
-    t = threading.Thread(target=spin, name="hp-busy-1", daemon=True)
-    t.start()
+    # under xdist the worker's IO thread is started without `threading` and
+    # samples as `?`: beside one named thread it is a fifth of the samples
+    # (coverage 0.8, unless earlier tests of the worker leaked named threads);
+    # a dozen parked named threads make the share this test asserts its own
+    threads = [threading.Thread(target=spin, name="hp-busy-1", daemon=True)]
+    threads += [threading.Thread(target=stop.wait, name=f"hp-idle-{i}",
+                                 daemon=True) for i in range(12)]
+    for t in threads:
+        t.start()
     try:
         prof = profiler.capture(0.3, hz=200)
     finally:
         stop.set()
-        t.join()
+        for t in threads:
+            t.join()
     d = prof.to_dict()
     assert d["sweeps"] >= 10
     assert d["coverage"] >= 0.9, d

@@ -1,5 +1,5 @@
 """HTTP-on-evloop serving core (ISSUE 14): framer edges, pipelining,
-backpressure, stop parity, and the CFS_EVLOOP_HTTP=0 threaded fallback.
+backpressure, and the stop contract.
 
 The framer battery drives HttpFramer directly (hostile inputs must be
 rejected WITHOUT preallocation); the server tests drive a real RPCServer
@@ -14,8 +14,7 @@ import time
 import pytest
 
 from chubaofs_tpu.rpc.httpevloop import (
-    MAX_BODY_BYTES, MAX_HEADER_BYTES, HttpFramer, HttpReply, encode_reply,
-    http_evloop_enabled)
+    MAX_BODY_BYTES, MAX_HEADER_BYTES, HttpFramer, HttpReply, encode_reply)
 from chubaofs_tpu.rpc.router import Response, Router
 from chubaofs_tpu.rpc.server import RPCServer
 
@@ -166,7 +165,6 @@ def _recv_until_closed(sk):
 
 
 def test_evloop_http_is_the_default_and_serves(srv):
-    assert http_evloop_enabled()
     assert srv._evcore is not None  # riding loop shards, not threads
     host, port = srv.addr.rsplit(":", 1)
     c = http.client.HTTPConnection(host, int(port))
@@ -306,30 +304,6 @@ def test_slow_reader_backpressure_pauses_only_that_conn(monkeypatch):
         flood.close()
         assert got.count(b"HTTP/1.1 200") == n_reqs
         assert got.count(body) == n_reqs
-    finally:
-        s.stop()
-
-
-def test_threaded_fallback_mode_matrix(monkeypatch):
-    """CFS_EVLOOP_HTTP=0 restores the ThreadingHTTPServer path; the same
-    requests behave identically (the dispatch_request contract)."""
-    monkeypatch.setenv("CFS_EVLOOP_HTTP", "0")
-    r = Router()
-    r.get("/ping", lambda req: Response(200, {}, b"pong"))
-    r.post("/echo", lambda req: Response(200, {}, req.body))
-    s = RPCServer(r, module="threadedtest").start()
-    try:
-        assert s._evcore is None and s.httpd is not None
-        host, port = s.addr.rsplit(":", 1)
-        c = http.client.HTTPConnection(host, int(port))
-        c.request("GET", "/ping")
-        assert c.getresponse().read() == b"pong"
-        c.request("POST", "/echo", body=b"abc")
-        assert c.getresponse().read() == b"abc"
-        # /metrics side-door mounted identically in both modes
-        c.request("GET", "/metrics")
-        assert b"cfs_" in c.getresponse().read()
-        c.close()
     finally:
         s.stop()
 

@@ -167,36 +167,6 @@ def test_perfbench_tool_runs_and_gates(tmp_path):
     assert cfg["put_pipeline_speedup_wire"] > 0, cfg
 
 
-def test_concurrency_bench_smoke_floor():
-    """Tier-1 evloop gate (ISSUE 8 satellite): the concurrency A/B at smoke
-    size must serve every packet of BOTH modes correctly — the phase
-    asserts reply-count and per-request accounting internally — and report
-    sane rates. Speedup floors live in PERF.md, not CI (co-tenant noise);
-    correctness-at-fan-in is what gates here."""
-    from chubaofs_tpu.tools.perfbench import bench_concurrency
-
-    out = bench_concurrency(clients_axis=(16,), ops_per_client=5)
-    assert out["conc_ops_16c_evloop"] > 0, out
-    assert out["conc_ops_16c_threads"] > 0, out
-    assert out["conc_p99_ms_16c_evloop"] > 0, out
-    assert out["conc_speedup_16c"] > 0, out
-
-
-def test_gateway_bench_smoke_floor(tmp_path):
-    """Tier-1 gateway-serving gate (ISSUE 14 satellite): the HTTP A/B at
-    smoke size must serve every presigned S3 GET of BOTH serving modes
-    with HTTP 200 (the phase raises on any anomaly) and report sane
-    rates. Speedup/flatness floors live in PERF.md, not CI (co-tenant
-    noise) — correctness under keep-alive fan-in is what gates here."""
-    from chubaofs_tpu.tools.perfbench import bench_gateway
-
-    out = bench_gateway(str(tmp_path), clients_axis=(16,), ops_per_client=4)
-    assert out["gw_ops_16c_evloop"] > 0, out
-    assert out["gw_ops_16c_threads"] > 0, out
-    assert out["gw_p99_ms_16c_evloop"] > 0, out
-    assert out["gw_speedup_16c"] > 0, out
-
-
 def test_qos_fairness_bench_smoke_floor(tmp_path):
     """Tier-1 fairness gate (ISSUE 14): with the QoS plane armed, the
     ~10x noisy tenant must be CAPPED (throttle counters nonzero) while

@@ -55,8 +55,10 @@ def test_lease_expiry_reaps_and_requeues_with_backoff(cluster, rng):
     requeued behind a backoff gate, counted by cfs_scheduler_lease_expired,
     and the next acquire hands out a HIGHER lease number."""
     sched = cluster.scheduler
-    sched.lease_ms = 40
-    sched.requeue_backoff_s = 0.05
+    # the "not reached yet" and "backoff must gate" checks each have to run
+    # inside their window on a loaded host; the sleeps are lower bounds
+    sched.lease_ms = 250
+    sched.requeue_backoff_s = 0.25
     cluster.proxy.send_shard_repair(1, 77, [0], "test")
     sched.poll_repair_topic()
     t = sched.acquire_task()
@@ -65,13 +67,13 @@ def test_lease_expiry_reaps_and_requeues_with_backoff(cluster, rng):
     assert lease1 > 0
     assert sched.acquire_task() is None  # never handed out twice
     assert sched.reap_expired() == 0  # deadline not reached yet
-    time.sleep(0.08)
+    time.sleep(0.3)
     before = _counter("lease_expired")
     assert sched.reap_expired() == 1
     assert _counter("lease_expired") == before + 1
     assert t.state == TASK_PREPARED
     assert sched.acquire_task() is None, "requeue backoff must gate re-lease"
-    time.sleep(0.08)
+    time.sleep(0.3)
     t2 = sched.acquire_task()
     assert t2 is not None and t2.task_id == t.task_id
     assert t2.lease == lease1 + 1, "re-lease must advance the lease number"
@@ -84,7 +86,9 @@ def test_lease_renewal_outruns_reaper_and_expiry_cap_fails_terminal(
     (expires max_lease_expiries times) goes terminal FAILED instead of
     re-executing forever."""
     sched = cluster.scheduler
-    sched.lease_ms = 40
+    # a sleep only ever overshoots, so the one bound a loaded host can break
+    # is "still inside the renewed lease": 250 ms of margin under it
+    sched.lease_ms = 400
     sched.requeue_backoff_s = 0.01
     sched.requeue_backoff_cap_s = 0.01
     cluster.proxy.send_shard_repair(3, 99, [2], "test")
@@ -93,9 +97,9 @@ def test_lease_renewal_outruns_reaper_and_expiry_cap_fails_terminal(
     lease = t.lease
     # renewal pushes the deadline out: after the original lease would have
     # expired, the reaper finds nothing
-    time.sleep(0.03)
+    time.sleep(0.3)
     assert sched.renew_lease(t.task_id, lease) is True
-    time.sleep(0.02)  # past the ORIGINAL deadline, inside the renewed one
+    time.sleep(0.15)  # past the ORIGINAL deadline, inside the renewed one
     assert sched.reap_expired() == 0
     # a wrong lease (reaped + re-leased elsewhere) must refuse to renew
     assert sched.renew_lease(t.task_id, lease + 1) is False
@@ -103,6 +107,8 @@ def test_lease_renewal_outruns_reaper_and_expiry_cap_fails_terminal(
     assert sched.report_task(t.task_id, ok=True, lease=lease) is True
 
     # expiry cap: never-reporting executions exhaust into terminal FAILED
+    # (every wait below is a lower bound: a late wake-up only helps)
+    sched.lease_ms = 40
     sched.max_lease_expiries = 3
     cluster.proxy.send_shard_repair(4, 100, [1], "test")
     sched.poll_repair_topic()

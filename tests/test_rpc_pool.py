@@ -177,7 +177,7 @@ def test_no_backoff_sleep_after_terminal_attempt():
 
 
 def test_round_robin_thread_safe():
-    cli = RPCClient(["a:1", "b:1"], pooled=False)
+    cli = RPCClient(["a:1", "b:1"], pool=NullPool())
     seen = []
 
     def spin():
@@ -197,7 +197,7 @@ def test_5xx_response_track_log_merged_before_retry(srv):
     """A >=500 hop's Trace-Tracklog must fold into the caller's span even
     though the attempt is retried — failed hops must not vanish from
     traces."""
-    cli = RPCClient([srv.addr], retries=2, backoff=0.0, pooled=False)
+    cli = RPCClient([srv.addr], retries=2, backoff=0.0, pool=NullPool())
     span = trace.start_span("client-op")
     trace.push_span(span)
     try:
