@@ -74,6 +74,32 @@ class _PipelineAborted(Exception):
     quorum already failed — never user-visible (the first real error wins)."""
 
 
+class _BlobDest:
+    """Where one blob's bytes of a GET land: `view` is the blob's slice of
+    the object's body, the one buffer the request allocated. `put` writes a
+    piece (shard bytes or decoded rows, C-contiguous uint8) at its final
+    offset and clocks it; `secs` is what the blob's one `access.assemble`
+    observation reports. The write is a slice assignment, a memcpy with the
+    interpreter lock HELD: a piece is at most a shard (349 KB in a 4 MiB
+    EC12P4 blob, tens of microseconds), and giving the lock up for it, as an
+    assignment through a numpy view does, costs the thread a wait to win it
+    back that is twenty times the copy (PERF.md, PR 31)."""
+
+    __slots__ = ("view", "start", "secs")
+
+    def __init__(self, view: memoryview):
+        self.view = view
+        self.start = 0.0  # perf_counter at the first put
+        self.secs = 0.0
+
+    def put(self, at: int, piece) -> None:
+        t0 = time.perf_counter()
+        self.view[at:at + len(piece)] = piece
+        self.secs += time.perf_counter() - t0
+        if not self.start:
+            self.start = t0
+
+
 @dataclass(frozen=True)
 class CodeModePolicy:
     """One enabled size band for a code mode (access/codemode.go:24-45 analog)."""
@@ -609,6 +635,16 @@ class Access:
     # -- GET -----------------------------------------------------------------
 
     def get(self, loc: Location | str, offset: int = 0, size: int | None = None) -> bytes:
+        """`get_buffer`'s body as `bytes`, for in-process callers: the one
+        conversion, at this edge. The gateway sends the buffer itself."""
+        return self.get_buffer(loc, offset, size).tobytes()
+
+    def get_buffer(self, loc: Location | str, offset: int = 0,
+                   size: int | None = None) -> memoryview:
+        """The object's bytes [offset, offset + size) in one buffer, allocated
+        once at its final length; every blob read writes at its final offset
+        in it. One allocation a GET and no pool: the buffer lives as long as
+        the caller holds it (the gateway: until the last sendmsg took it)."""
         if isinstance(loc, str):
             loc = Location.from_json(loc)
         if self.qos is not None:
@@ -632,10 +668,8 @@ class Access:
                                time.perf_counter() - span.start, span=span,
                                err=type(err).__name__ if err else "")
 
-    def _get(self, loc: Location | str, offset: int = 0, size: int | None = None) -> bytes:
-        with trace.stage("access.prepare"):  # location parse + sig check + range plan
-            if isinstance(loc, str):
-                loc = Location.from_json(loc)
+    def _get(self, loc: Location, offset: int, size: int | None) -> memoryview:
+        with trace.stage("access.prepare"):  # sig check + range plan + the body
             self._check_sig(loc)
             if size is None:
                 size = loc.size - offset
@@ -647,7 +681,10 @@ class Access:
             registry("access").counter(
                 "read_bytes", {"kind": "requested"}).add(size)
 
-            segs = []  # (blob, intra-blob offset, length) the range touches
+            # allocated, not filled: its pages are first touched by the
+            # writes of the reads below
+            body = memoryview(np.empty(size, np.uint8))
+            segs = []  # (blob, intra-blob offset, length, its slice of body)
             pos = 0
             for blob in loc.blobs:
                 blob_start, blob_end = pos, pos + blob.size
@@ -656,38 +693,36 @@ class Access:
                     continue
                 lo = max(0, offset - blob_start)
                 hi = min(blob.size, offset + size - blob_start)
-                segs.append((blob, lo, hi - lo))
-        window = int(self.pipeline_window)
-        if len(segs) > 1 and window >= 1:
-            return self._get_readahead(loc.code_mode, segs, window)
-        if len(segs) == 1:  # whole-blob/single-blob GET: no reassembly copy
-            blob, lo, n = segs[0]
-            return self._read_blob(loc.code_mode, blob, lo, n)
-        out = bytearray()
-        for blob, lo, n in segs:
-            out += self._read_blob(loc.code_mode, blob, lo, n)
-        return bytes(out)
+                at = blob_start + lo - offset
+                segs.append((blob, lo, hi - lo, body[at: at + hi - lo]))
+        self._get_readahead(loc.code_mode, segs, int(self.pipeline_window))
+        return body
 
-    def _get_readahead(self, mode: int, segs: list, window: int) -> bytes:
-        """Multi-blob ranged GET with readahead: the next blobs' shard
-        gathers are prefetched on the pipe pool (their shard reads still ride
-        the read pool) while the current blob's bytes are consumed, bounded
-        by the same pipeline window as PUT. Byte order is segment order —
-        results are consumed strictly FIFO however the gathers complete."""
+    def _get_readahead(self, mode: int, segs: list, window: int) -> None:
+        """Read every segment into its slice of the body, at most `window`
+        at a time: the next blobs' shard gathers are prefetched on the pipe
+        pool (their shard reads still ride the read pool) while the current
+        blob's is waited for, bounded by the same pipeline window as PUT. A
+        window of 0, or a single segment, is a serial read on the caller's
+        thread. Byte order is segment order by construction: each gather
+        writes at its own offset, however the gathers complete."""
+        if window < 1 or len(segs) == 1:
+            for seg in segs:
+                self._read_blob(mode, *seg)
+            return
         span = trace.current_span()
 
-        def gather(blob, lo, n):
+        def gather(*seg):
             if span is not None:
                 trace.push_span(span)
             try:
-                return self._read_blob(mode, blob, lo, n)
+                self._read_blob(mode, *seg)
             finally:
                 if span is not None:
                     trace.pop_span()
 
         q: deque = deque()
         nxt = 0
-        out = bytearray()
         try:
             while q or nxt < len(segs):
                 while nxt < len(segs) and len(q) < window:
@@ -696,22 +731,23 @@ class Access:
                         registry("access").counter(
                             "get_readahead_prefetch").add()
                     nxt += 1
-                out += q.popleft().result()
+                q.popleft().result()
         except BaseException:
             for f in q:  # queued prefetches must not run for a dead request
                 f.cancel()
             raise
-        return bytes(out)
 
-    def _read_blob(self, mode: int, blob: Blob, offset: int, size: int) -> bytes:
-        """Tiered read: cache -> hot Replica3 copy -> EC cold path. Every
-        lookup feeds the cache's heat accounting; blobs that cross the
-        promote threshold are reported to the hot-blob topic, where the
-        scheduler's tier sweep copies them into the replica engine."""
+    def _read_blob(self, mode: int, blob: Blob, offset: int, size: int,
+                   view: memoryview) -> None:
+        """Tiered read into `view`: cache -> hot Replica3 copy -> EC cold
+        path. Every lookup feeds the cache's heat accounting; blobs that
+        cross the promote threshold are reported to the hot-blob topic, where
+        the scheduler's tier sweep copies them into the replica engine."""
+        dst = _BlobDest(view)
         cache = self.cache
-        fill_ver = None
-        f_lo, f_len = offset, size
-        if cache is not None:
+        if cache is None:
+            self._read_blob_backend(mode, blob, offset, size, dst)
+        else:
             cached = cache.get(blob.vid, blob.bid, offset, size)
             if cache.promote_signal(blob.vid, blob.bid):
                 try:
@@ -719,39 +755,41 @@ class Access:
                 except Exception:
                     pass  # advisory: lost heat re-accumulates next epoch
             if cached is not None and len(cached) == size:
-                return bytes(cached)
-            # version captured BEFORE the backend read: a DELETE racing
-            # this miss invalidates the version and the fill is dropped.
-            # The backend window is rounded OUT to cache-block boundaries
-            # (clipped to the blob) so a ranged miss fills exactly the
-            # blocks it touches — the next overlapping range hits.
-            fill_ver = cache.fill_version(blob.vid, blob.bid)
-            blk = cache.block
-            f_lo = (offset // blk) * blk
-            f_len = min(blob.size,
-                        ((offset + size + blk - 1) // blk) * blk) - f_lo
-        hot = self.cm.hot_location(blob.vid, blob.bid)
-        if hot is not None:
-            data = self._read_blob_hot(hot, f_lo, f_len)
-            if data is not None:
-                if fill_ver is not None:
-                    cache.fill(blob.vid, blob.bid, fill_ver, data,
-                               offset=f_lo, total=blob.size)
-                return (data if f_len == size
-                        else data[offset - f_lo: offset - f_lo + size])
-        data = self._read_blob_ec(mode, blob, f_lo, f_len)
-        if fill_ver is not None:
-            cache.fill(blob.vid, blob.bid, fill_ver, data,
-                       offset=f_lo, total=blob.size)
-        return (data if f_len == size
-                else data[offset - f_lo: offset - f_lo + size])
+                dst.put(0, cached)
+            else:
+                # version captured BEFORE the backend read: a DELETE racing
+                # this miss invalidates the version and the fill is dropped.
+                # The backend window is rounded OUT to cache-block boundaries
+                # (clipped to the blob) so a ranged miss fills exactly the
+                # blocks it touches — the next overlapping range hits. It is
+                # read into a buffer of its own: the cache keeps what the
+                # body does not hold.
+                fill_ver = cache.fill_version(blob.vid, blob.bid)
+                blk = cache.block
+                f_lo = (offset // blk) * blk
+                f_len = min(blob.size,
+                            ((offset + size + blk - 1) // blk) * blk) - f_lo
+                win = dst.view = memoryview(np.empty(f_len, np.uint8))
+                self._read_blob_backend(mode, blob, f_lo, f_len, dst)
+                dst.view = view
+                cache.fill(blob.vid, blob.bid, fill_ver, win.tobytes(),
+                           offset=f_lo, total=blob.size)
+                dst.put(0, win[offset - f_lo: offset - f_lo + size])
+        trace.observe_stage("access.assemble", dst.start, dst.secs,
+                            trace.current_span())
 
-    def _read_blob_hot(self, hot: tuple[int, int], offset: int,
-                       size: int) -> bytes | None:
+    def _read_blob_backend(self, mode: int, blob: Blob, offset: int,
+                           size: int, dst: _BlobDest) -> None:
+        hot = self.cm.hot_location(blob.vid, blob.bid)
+        if hot is None or not self._read_blob_hot(hot, offset, size, dst):
+            self._read_blob_ec(mode, blob, offset, size, dst)
+
+    def _read_blob_hot(self, hot: tuple[int, int], offset: int, size: int,
+                       dst: _BlobDest) -> bool:
         """One direct read of the Replica3 copy's data shard (shard 0 IS the
         blob bytes — systematic RS(1,2), exact-size shards). Any failure
-        falls back to the authoritative EC copy: the hot tier accelerates,
-        it never gates availability."""
+        falls back to the authoritative EC copy (False): the hot tier
+        accelerates, it never gates availability."""
         hot_vid, hot_bid = hot
         reg = registry("cache")
         try:
@@ -766,13 +804,15 @@ class Access:
                 raise AccessError("short hot read")
         except Exception:
             reg.counter("tier_fallbacks").add()
-            return None
+            return False
         reg.counter("tier_hits").add()
         registry("access").counter(
             "read_bytes", {"kind": "shards_read"}).add(size)
-        return bytes(data)
+        dst.put(0, data)
+        return True
 
-    def _read_blob_ec(self, mode: int, blob: Blob, offset: int, size: int) -> bytes:
+    def _read_blob_ec(self, mode: int, blob: Blob, offset: int, size: int,
+                      dst: _BlobDest) -> None:
         t = get_tactic(mode)
         vol = self.cm.get_volume(blob.vid)
         shard_len = t.shard_size(blob.size)
@@ -794,32 +834,35 @@ class Access:
         # degraded path reconstructs around it — the stall is bounded even
         # when the node never errors (stream_get races laggards the same way)
         idxs = list(range(first_shard, last_shard + 1))
-        pieces = []
+        # what the direct phase read: each piece goes to its place in the
+        # body as its read returns, and is kept for the degraded path
+        have: dict[int, bytes] = {}
         slow: set[int] = set()  # timed out, node possibly wedged
-        # fan-out + reassembly (or the failed direct attempt)
+        # fan-out (or the failed direct attempt)
         with trace.stage("access.read", track="blobnode"):
             futs = [self._read_pool.submit(read_one, i) for i in idxs]
             deadline = time.monotonic() + self.read_deadline
             for i, f in zip(idxs, futs):
                 try:
-                    pieces.append(f.result(timeout=max(0.0, deadline - time.monotonic())))
+                    piece = f.result(timeout=max(0.0, deadline - time.monotonic()))
                 except FutureTimeout:
-                    pieces.append(None)
                     slow.add(i)
-            if all(p is not None for p in pieces):
-                return b"".join(pieces)
+                    continue
+                if piece is not None:
+                    have[i] = piece
+                    dst.put(max(offset, i * shard_len) - offset, piece)
+            if len(have) == len(idxs):
+                return
         for f in futs:  # queued laggards must not hold pool workers
             f.cancel()
         # hand the degraded path everything the direct phase learned: the
         # sub-range bytes it DID read (reused verbatim — never refetched),
         # the shards that errored (excluded from the survivor gather), and
         # the ones that hung (deprioritized, probed asynchronously)
-        have = {i: p for i, p in zip(idxs, pieces) if p is not None}
-        failed_direct = {i for i, p in zip(idxs, pieces)
-                         if p is None and i not in slow}
-        return self._read_blob_degraded(t, vol, blob, shard_len, offset, size,
-                                        have=have, failed=failed_direct,
-                                        deprioritize=slow)
+        failed_direct = {i for i in idxs if i not in have and i not in slow}
+        self._read_blob_degraded(t, vol, blob, shard_len, offset, size, dst,
+                                 have=have, failed=failed_direct,
+                                 deprioritize=slow)
 
     def _recover_locals_inplace(self, t, vol, blob, stripe, present: list,
                                 shard_len: int,
@@ -902,9 +945,10 @@ class Access:
             return None
 
     def _read_blob_degraded(self, t, vol, blob, shard_len, offset, size,
+                            dst: _BlobDest,
                             have: dict[int, bytes] | None = None,
                             failed: set[int] | None = None,
-                            deprioritize: set[int] | None = None) -> bytes:
+                            deprioritize: set[int] | None = None) -> None:
         """Degraded read, range-scoped first: reconstruct ONLY the in-window
         shards the direct phase could not serve, from a survivor gather over
         just the window's byte columns (row-sliced decode matrix — decode
@@ -917,19 +961,15 @@ class Access:
         have = dict(have or {})
         slow = set(deprioritize or ())
         failed = set(failed or ())
-        if t.is_regenerating:
-            # PM sub-unit layout: a shard-byte window couples to a column
-            # range in EVERY one of the survivor's alpha sub-units, which
-            # the single-range windowed gather can't express — regenerating
-            # stripes take the full-stripe path (any-N decode) directly
-            out = None
-        else:
-            out = self._degraded_window(t, vol, blob, shard_len, offset,
-                                        size, have, slow, failed)
-        if out is not None:
-            return out
-        return self._degraded_full(t, vol, blob, shard_len, offset, size,
-                                   slow)
+        # PM sub-unit layout: a shard-byte window couples to a column range
+        # in EVERY one of the survivor's alpha sub-units, which the
+        # single-range windowed gather can't express — regenerating stripes
+        # take the full-stripe path (any-N decode) directly
+        if t.is_regenerating or not self._degraded_window(
+                t, vol, blob, shard_len, offset, size, dst, have, slow,
+                failed):
+            self._degraded_full(t, vol, blob, shard_len, offset, size, dst,
+                                slow)
 
     def _gather_survivors(self, vol, bid: int, candidates: list[int],
                           needed: int, lo: int,
@@ -1012,14 +1052,16 @@ class Access:
         return got, failures
 
     def _degraded_window(self, t, vol, blob, shard_len, offset, size,
-                         have: dict[int, bytes], slow: set[int],
-                         failed_direct: set[int]) -> bytes | None:
+                         dst: _BlobDest, have: dict[int, bytes],
+                         slow: set[int], failed_direct: set[int]) -> bool:
         """Range-scoped degraded read: decode ONLY the in-window shards the
         direct phase is missing, over only the window's byte columns. RS is
         column-independent, so t.N survivor rows sliced to the SAME columns
-        decode the missing rows' slice exactly (RSKernel.window_matrix).
-        Returns None when the gather can't reach N global survivors — deep
-        damage, which the full-stripe path (with AZ-local recovery) owns."""
+        decode the missing rows' slice exactly (RSKernel.window_matrix). The
+        direct phase's pieces (`have`) are in the body already; the decoded
+        rows join them. Returns False when the gather can't reach N global
+        survivors — deep damage, which the full-stripe path (with AZ-local
+        recovery) owns."""
         first = offset // shard_len
         last = (offset + size - 1) // shard_len
 
@@ -1054,7 +1096,7 @@ class Access:
                 vol, blob.bid, candidates, t.N - len(reuse), col_lo, width)
         got.update(reuse)
         if len(got) < t.N:
-            return None  # the full path re-proves and reports damage
+            return False  # the full path re-proves and reports damage
         present = sorted(got)[: t.N]
         survivors = np.stack(
             [np.frombuffer(got[i], np.uint8) for i in present])
@@ -1063,17 +1105,12 @@ class Access:
                                           need).result()
         registry("access").counter(
             "read_bytes", {"kind": "decoded"}).add(len(need) * width)
-        # assemble: verbatim direct-phase bytes, decoded rows sliced to each
-        # missing shard's own sub-window
-        rowpos = {i: p for p, i in enumerate(need)}
-        out = bytearray()
-        for i in range(first, last + 1):
-            if i in have:
-                out += have[i]
-            else:
-                lo_i, hi_i = window_of(i)
-                out += rows[rowpos[i],
-                            lo_i - col_lo: hi_i - col_lo].tobytes()
+        # decoded rows, sliced to each missing shard's own sub-window, go
+        # from the result array to their shards' places in the body
+        for p, i in enumerate(need):
+            lo_i, hi_i = window_of(i)
+            dst.put(i * shard_len + lo_i - offset,
+                    rows[p, lo_i - col_lo: hi_i - col_lo])
         # the repair plane must hear what this read PROVED damaged; shards
         # it never touched are probed asynchronously (off the latency path)
         # so ranged reads don't narrow get_miss-driven healing
@@ -1082,10 +1119,10 @@ class Access:
         touched = set(got) | set(have) | set(damaged)
         self._probe_unread(t, vol, blob, shard_len,
                            [i for i in range(t.N + t.M) if i not in touched])
-        return bytes(out)
+        return True
 
     def _degraded_full(self, t, vol, blob, shard_len, offset, size,
-                       slow: set[int]) -> bytes:
+                       dst: _BlobDest, slow: set[int]) -> None:
         """Full-stripe degraded gather (stream_get.go:427 ReconstructData
         fallback) — the deep-damage path: whole shards are read because
         AZ-local stripes repair whole shards. The gather still launches only
@@ -1130,7 +1167,7 @@ class Access:
                            [i for i in range(total)
                             if i not in present and i not in failed])
         data_region = fixed[: t.N].reshape(-1)
-        return data_region[offset : offset + size].tobytes()
+        dst.put(0, data_region[offset : offset + size])
 
     def _probe_unread(self, t, vol, blob, shard_len,
                       unprobed: list[int]) -> None:

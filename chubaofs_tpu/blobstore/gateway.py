@@ -8,7 +8,9 @@ JSON Location bodies, and a client whose put/get/delete signature matches the
 in-process `Access` object so `sdk/data/blobstore`-style consumers are
 transport-blind. Changed: the reference streams multi-blob bodies with
 chunked encoding; blobs here ride whole HTTP bodies (the codec service under
-the gateway already batches stripes for the TPU)."""
+the gateway already batches stripes for the TPU). A GET's body is the one
+buffer `Access.get_buffer` filled, handed to `Response` as it is: the shard
+reads wrote into what `sendmsg` reads from."""
 
 from __future__ import annotations
 
@@ -75,7 +77,7 @@ def build_router(access: Access) -> Router:
                 return Response(416, {"Content-Range": f"bytes */{obj_size}"})
             offset, size = parsed
             try:
-                data = access.get(loc, offset, size)
+                data = access.get_buffer(loc, offset, size)
             except AccessError as e:
                 raise HTTPError(404, msg=str(e), code="AccessError") from None
             return Response(
@@ -87,7 +89,7 @@ def build_router(access: Access) -> Router:
         offset = int(req.q("offset", "0"))
         size = int(req.q("size", "-1"))
         try:
-            data = access.get(loc, offset, None if size < 0 else size)
+            data = access.get_buffer(loc, offset, None if size < 0 else size)
         except AccessError as e:
             raise HTTPError(404, msg=str(e), code="AccessError") from None
         return Response(200, {"Content-Type": "application/octet-stream"}, data)
@@ -107,8 +109,8 @@ def build_router(access: Access) -> Router:
         offset = int(body.get("offset", 0))
         size = int(body.get("size", -1))
         try:
-            data = access.get(body["location"], offset,
-                              None if size < 0 else size)
+            data = access.get_buffer(body["location"], offset,
+                                     None if size < 0 else size)
         except AccessError as e:
             raise HTTPError(404, msg=str(e), code="AccessError") from None
         return Response(200, {"Content-Type": "application/octet-stream"}, data)

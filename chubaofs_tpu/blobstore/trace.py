@@ -349,10 +349,10 @@ def current_span() -> Span | None:
 # so a name also says which thread. The set is closed: it is the declared
 # value set of the counter's `stage` label (exporter._check_bounded).
 STAGES = frozenset((
-    "gateway.recv", "gateway.queue", "gateway.handle",
+    "gateway.recv", "gateway.queue", "gateway.handle", "gateway.send",
     "access.put", "access.get", "access.prepare", "access.alloc",
     "access.encode_wait", "access.decode_wait", "access.write_stripe",
-    "access.pool_wait", "access.read", "access.gather",
+    "access.pool_wait", "access.read", "access.gather", "access.assemble",
     "codec.queue_wait", "codec.drain", "codec.stack", "codec.expand",
     "codec.concat", "codec.deliver",
     "hostbatch.group", "hostbatch.launch", "hostbatch.fetch",

@@ -57,6 +57,7 @@ from collections import deque
 from itertools import count, islice
 
 from chubaofs_tpu import chaos
+from chubaofs_tpu.blobstore import trace
 from chubaofs_tpu.proto.packet import PacketFramer, advance_iov, packet_iov
 from chubaofs_tpu.utils.exporter import registry
 from chubaofs_tpu.utils.locks import SanitizedLock
@@ -102,7 +103,7 @@ class _Conn:
     __slots__ = ("sock", "fd", "framer", "buf", "view", "got", "wq",
                  "wq_bytes", "inbox", "inbox_bytes", "msg_bytes",
                  "dispatching", "paused", "closed", "events", "greedy",
-                 "close_after")
+                 "close_after", "t_send")
 
     def __init__(self, sock: socket.socket, framer):
         self.sock = sock
@@ -126,6 +127,9 @@ class _Conn:
         # a reply asked for connection teardown once it is fully flushed
         # (HTTP Connection: close); reads stop immediately
         self.close_after = False
+        # when the write queue last went from empty to non-empty (servers
+        # that name a send stage; 0.0 = the queue is empty)
+        self.t_send = 0.0
 
     def arm_stage(self) -> None:
         n = self.framer.need()
@@ -529,10 +533,19 @@ class _LoopShard(threading.Thread):
 
         `close_after` tears the connection down once THIS iov is fully on
         the wire (HTTP `Connection: close`): reads stop immediately, the
-        close itself waits for the flush."""
+        close itself waits for the flush.
+
+        A server that names a `send_stage` observes it from here to the
+        queue running empty (the kernel has the last byte): right here when
+        the direct `sendmsg` takes the iov whole, else in `_flush`. An iov
+        that joins a queue already draining shares that queue's
+        observation."""
+        stage = self.server.send_stage
+        t0 = time.perf_counter() if stage else 0.0
         total = sum(len(b) for b in iov)
         views = [memoryview(b) for b in iov]
         action = None
+        whole = False  # the direct sendmsg took all of it
         with self._lock:
             if conn.closed:
                 return
@@ -550,13 +563,20 @@ class _LoopShard(threading.Thread):
                     rest = advance_iov(views, sent)
                     conn.wq.extend(rest)
                     conn.wq_bytes += sum(len(v) for v in rest)
+                    conn.t_send = t0
                     action = action or "flush"
-                elif conn.close_after and action is None:
-                    action = "close"  # reply fully on the wire: tear down now
+                elif action is None:
+                    whole = True
+                    if conn.close_after:
+                        action = "close"  # fully on the wire: tear down now
             else:
+                if not conn.wq:
+                    conn.t_send = t0
                 conn.wq.extend(views)
                 conn.wq_bytes += total
                 action = "flush"
+        if whole and stage:
+            trace.observe_stage(stage, t0, time.perf_counter() - t0)
         # post() takes the shard lock itself, so both follow-ups run after it
         if action == "flush":
             self.post(lambda c=conn: self._after_send(c))
@@ -577,6 +597,7 @@ class _LoopShard(threading.Thread):
             self._set_events(conn, selectors.EVENT_WRITE)
 
     def _flush(self, conn: _Conn) -> None:
+        t_send = 0.0
         try:
             while conn.wq:
                 with self._lock:
@@ -597,9 +618,14 @@ class _LoopShard(threading.Thread):
                     for _ in range(len(batch)):
                         conn.wq.popleft()
                     conn.wq.extendleft(reversed(rest))
+                    if not conn.wq:
+                        t_send, conn.t_send = conn.t_send, 0.0
         except OSError:
             self._close(conn)
             return
+        if t_send:  # the queue a stamped send started ran empty
+            trace.observe_stage(self.server.send_stage, t_send,
+                                time.perf_counter() - t_send)
         if conn.wq:
             self._set_events(conn, conn.events | selectors.EVENT_WRITE)
         else:
@@ -651,7 +677,7 @@ class EvloopServer:
                  name: str = "pkt", framer_factory=PacketFramer,
                  encode=packet_iov, shards: int | None = None,
                  workers: int | None = None, write_hwm: int | None = None,
-                 close_reply=None):
+                 close_reply=None, send_stage: str | None = None):
         self.listener = listener
         self.on_message = on_message
         self.name = name
@@ -660,6 +686,9 @@ class EvloopServer:
         # does THIS reply end its connection? (HTTP Connection: close); the
         # packet protocols never do — every conn outlives every reply
         self.close_reply = close_reply or (lambda reply: False)
+        # the trace stage a reply's send is observed under (`_LoopShard.send`);
+        # the HTTP core names one, the packet servers none
+        self.send_stage = send_stage
         self.reg = registry("evloop")
         self.dispatch_tp = self.reg.summary("dispatch", {"srv": name})
         self.write_hwm = write_hwm if write_hwm is not None \

@@ -87,7 +87,7 @@ class HttpReply:
 
     __slots__ = ("status", "headers", "body", "head_only", "close")
 
-    def __init__(self, status: int, headers: dict, body: bytes,
+    def __init__(self, status: int, headers: dict, body: bytes | memoryview,
                  head_only: bool = False, close: bool = False):
         self.status = status
         self.headers = headers
@@ -307,7 +307,7 @@ class HttpEvloopCore:
         self.core = EvloopServer(
             listener, self._on_message, name=f"http-{name}",
             framer_factory=HttpFramer, encode=encode_reply,
-            close_reply=lambda reply: reply.close)
+            close_reply=lambda reply: reply.close, send_stage="gateway.send")
 
     def start(self) -> "HttpEvloopCore":
         self.core.start()

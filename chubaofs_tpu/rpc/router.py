@@ -52,7 +52,7 @@ class Request:
 class Response:
     status: int = 200
     headers: dict[str, str] = field(default_factory=dict)
-    body: bytes = b""
+    body: bytes | memoryview = b""  # sent as it is: one element of the iovec
 
     @classmethod
     def json(cls, obj, status: int = 200) -> "Response":

@@ -17,6 +17,7 @@ from chubaofs_tpu.rpc.httpevloop import (
     MAX_BODY_BYTES, MAX_HEADER_BYTES, HttpFramer, HttpReply, encode_reply)
 from chubaofs_tpu.rpc.router import Response, Router
 from chubaofs_tpu.rpc.server import RPCServer
+from chubaofs_tpu.utils.exporter import registry
 
 
 def feed_all(framer, raw, step=None):
@@ -317,3 +318,90 @@ def test_sidedoors_served_from_loop_shards(srv):
     c.request("GET", "/health")
     assert c.getresponse().status == 200
     c.close()
+
+
+# -- the send stage (ISSUE 31) -------------------------------------------------
+
+
+def _send_stage():
+    s = registry("trace").summary("stage_seconds", {"stage": "gateway.send"})
+    return s.count, s.sum
+
+
+def _settled(n0: int) -> tuple[int, float]:
+    """The stage's (count, sum) once it has stopped moving past `n0`."""
+    deadline = time.monotonic() + 10
+    while _send_stage()[0] == n0 and time.monotonic() < deadline:
+        time.sleep(0.01)
+    time.sleep(0.1)  # a second observation, were there one, lands here
+    return _send_stage()
+
+
+@pytest.mark.parametrize("path", ["whole", "queued"])
+def test_gateway_send_observed_once_a_reply(path):
+    """`gateway.send` runs from the reply entering the loop shard's `send`
+    to its connection's write queue running empty, one observation a reply:
+    where the first `sendmsg` takes the reply whole, and where a 16 MiB
+    body (a memoryview, sent as it is) waits in the queue for a reader that
+    drains slowly — there the interval covers the reader's delay."""
+    body = bytes(range(256)) * (64 << 10)  # 16 MiB
+    r = Router()
+    r.get("/ping", lambda req: Response(200, {}, b"pong"))
+    r.get("/big", lambda req: Response(200, {}, memoryview(body)))
+    s = RPCServer(r, module="sendstage").start()
+    try:
+        host, port = s.addr.rsplit(":", 1)
+        n0, sum0 = _send_stage()
+        sk = socket.create_connection((host, int(port)))
+        sk.settimeout(15)
+        if path == "whole":
+            sk.sendall(b"GET /ping HTTP/1.1\r\nHost: x\r\n\r\n")
+            got = sk.recv(65536)
+            assert got.endswith(b"pong")
+        else:
+            sk.sendall(b"GET /big HTTP/1.1\r\nHost: x\r\n\r\n")
+            time.sleep(0.3)  # the kernel's buffers hold far less than 16 MiB
+            assert _send_stage()[0] == n0, "observed before the queue ran empty"
+            got = b""
+            while not got.endswith(body[-4096:]) or len(got) < len(body):
+                d = sk.recv(1 << 20)
+                assert d, "closed before the whole reply"
+                got += d
+            assert got.count(b"HTTP/1.1 200") == 1 and got.endswith(body)
+        n1, sum1 = _settled(n0)
+        assert n1 - n0 == 1
+        if path == "queued":
+            assert sum1 - sum0 >= 0.25  # the reader's 0.3 s is inside it
+        sk.close()
+    finally:
+        s.stop()
+
+
+def test_packet_server_names_no_send_stage():
+    """Only a server that names a send stage observes one: a packet server's
+    replies, queued (a reply of twice the high-water mark) or not, add
+    nothing to `gateway.send`."""
+    from chubaofs_tpu.proto.packet import (
+        OP_WRITE, RES_OK, Packet, recv_packet, send_packet)
+    from chubaofs_tpu.rpc.evloop import EvloopServer
+
+    listener = socket.create_server(("127.0.0.1", 0))
+    srv = EvloopServer(listener, lambda pkt: pkt.reply(RES_OK, data=pkt.data),
+                       name="nosend", shards=1, workers=2,
+                       write_hwm=64 * 1024)
+    assert srv.send_stage is None
+    srv.start()
+    try:
+        n0, _ = _send_stage()
+        a = socket.create_connection(listener.getsockname())
+        a.settimeout(15)
+        for size in (8, 8 << 20):
+            blob = bytes(size)
+            send_packet(a, Packet(OP_WRITE, data=blob))
+            assert recv_packet(a).data == blob
+        a.close()
+        time.sleep(0.1)
+        assert _send_stage()[0] == n0
+    finally:
+        srv.stop()
+        listener.close()

@@ -25,7 +25,9 @@ LIVE = ["access.put", "access.get", "access.prepare", "access.alloc",
 MARKS = ["access.sem_wait", "blobnode.put_shard", "blobnode.get_shard",
          "chunk.lock_wait", "chunk.write", "chunk.meta",
          "chunk.verify"]  # profiler's clock only
-OBSERVED = ["access.pool_wait", "codec.queue_wait"]  # no thread: counters only
+# counters only: no thread is inside (a wait), or the time is a sum of
+# moments (a blob's pieces written into the GET's body as they arrive)
+OBSERVED = ["access.pool_wait", "codec.queue_wait", "access.assemble"]
 DISPATCHER = ("codec.drain", "codec.stack", "codec.expand", "codec.concat", "codec.deliver",
               "hostbatch.group", "hostbatch.launch", "hostbatch.fetch")
 
@@ -217,7 +219,7 @@ def served(tmp_path_factory):
 
 @pytest.mark.parametrize("name,least", [
     ("gateway.recv", 2), ("gateway.queue", 2), ("gateway.handle", 2),
-    ("scheduler.scrub", 1), ("scheduler.inspect", 1)])
+    ("gateway.send", 2), ("access.assemble", 1), ("scheduler.scrub", 1), ("scheduler.inspect", 1)])
 def test_gateway_and_background_stages_count(served, name, least):
     assert served.get(name, 0) >= least
 
