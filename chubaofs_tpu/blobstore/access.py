@@ -834,15 +834,18 @@ class Access:
         # degraded path reconstructs around it — the stall is bounded even
         # when the node never errors (stream_get races laggards the same way)
         idxs = list(range(first_shard, last_shard + 1))
+        # a unit whose node is not routed (a dark host, a dark AZ) is a failed
+        # read known without one: it is never handed to the pool
+        live = [i for i in idxs if vol.units[i].node_id in self.nodes]
         # what the direct phase read: each piece goes to its place in the
         # body as its read returns, and is kept for the degraded path
         have: dict[int, bytes] = {}
         slow: set[int] = set()  # timed out, node possibly wedged
         # fan-out (or the failed direct attempt)
         with trace.stage("access.read", track="blobnode"):
-            futs = [self._read_pool.submit(read_one, i) for i in idxs]
+            futs = [self._read_pool.submit(read_one, i) for i in live]
             deadline = time.monotonic() + self.read_deadline
-            for i, f in zip(idxs, futs):
+            for i, f in zip(live, futs):
                 try:
                     piece = f.result(timeout=max(0.0, deadline - time.monotonic()))
                 except FutureTimeout:
@@ -981,11 +984,16 @@ class Access:
         launches a hedge replacement while the original keeps running (slow-
         but-alive may still answer first) — so unselected candidates (the
         parity tail of the list) are never fetched unless a selected read
-        lets the gather down. Returns (idx -> bytes, failed idxs)."""
+        lets the gather down. A candidate whose node is not routed has failed
+        already: it is never launched, and every such candidate is among the
+        failures whether or not the gather would have reached it. Returns
+        (idx -> bytes, failed idxs)."""
         from concurrent.futures import FIRST_COMPLETED, wait
 
         got: dict[int, bytes] = {}
-        failures: list[int] = []
+        failures = [i for i in candidates
+                    if vol.units[i].node_id not in self.nodes]
+        candidates = [i for i in candidates if i not in failures]
         if needed <= 0:
             return got, failures
         pending: dict = {}

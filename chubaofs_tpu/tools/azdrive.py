@@ -9,6 +9,10 @@ The deployment is upstream's two-AZ production table (EC6P10L2 up to
 take EC6P10L2, a 16 MiB object takes EC16P20L2 as four blobs of 38 shards.
 A whole AZ down leaves EC16P20L2 its other AZ's 8 data + 10 global-parity
 shards (>= 16) and EC6P10L2 3 + 5 (>= 6): every object still reads back.
+Layout, the AZ that goes dark with its nodes, and the repair switches held
+are read from the benchmark's configuration of that outage
+(benchmark/configs/az2-ec16p20l2-azdown.json, the cell az2.get16m-azdown), so
+this drive and the timed cell state one deployment.
 
 The blobstore daemon boots in this process exactly as `chubaofs-tpu -c
 blobstore.json` boots it (cmd.start_role), so this process owns the chip;
@@ -25,35 +29,37 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import sys
 import time
 
 import numpy as np
 
-# 24 disks an AZ: the 19 units EC16P20L2 places in an AZ on distinct disks, a
-# whole node to lose, one disk to spare (benchmark/configs/az2-ec16p20l2.json)
-NODES, DISKS_PER_NODE, AZS = 12, 4, 2
+CONFIG = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+                      "benchmark", "configs", "az2-ec16p20l2-azdown.json")
 
 
 def drive(root: str, platform: str | None, sizes: list[int], seed: int) -> dict:
     from chubaofs_tpu import cmd
     from chubaofs_tpu.blobstore.gateway import AccessClient
-    from chubaofs_tpu.blobstore.taskswitch import SWITCH_DISK_REPAIR, SWITCH_SHARD_REPAIR
     from chubaofs_tpu.codec.codemode import CodeMode, get_tactic
     from chubaofs_tpu.ops import device
     from chubaofs_tpu.utils.exporter import registry
 
+    with open(CONFIG) as f:
+        config = json.load(f)
+    lay, failure = config["layout"], config["failure"]
     device.request_platform(platform)
     device.enable_compile_cache()
     cfg = {"role": "blobstore", "root": root, "listen": "127.0.0.1:0",
-           "nodes": NODES, "disksPerNode": DISKS_PER_NODE, "azs": AZS}
+           "nodes": lay["nodes"], "disksPerNode": lay["disks_per_node"], "azs": lay["azs"]}
     if platform:
         cfg["jaxPlatform"] = platform
     daemon = cmd.start_role(cfg)
     out: dict = {"boot": dict(daemon.boot_info), "objects": [], "steps": []}
     try:
         cluster = daemon.runner.handles["cluster"]
-        for name in (SWITCH_SHARD_REPAIR, SWITCH_DISK_REPAIR):
+        for name in config["task_switches_off"]:
             cluster.scheduler.switches.set(name, False)
         client = AccessClient([daemon.addr])
         client.rpc.timeout = 600.0  # a cold daemon compiles inside the first PUTs
@@ -88,9 +94,11 @@ def drive(root: str, platform: str | None, sizes: list[int], seed: int) -> dict:
         for loc, data in objects:  # the node of the object's first data shard
             unit0 = cluster.cm.get_volume(loc.blobs[0].vid).units[0]
             get("node_down", [unit0.node_id], [(loc, data)])
-        az = cluster.cm.disks[unit0.disk_id].az  # the widest object's first AZ, whole
-        get("az_down", sorted({d.node_id for d in cluster.cm.disks.values() if d.az == az}),
-            objects)
+        az_nodes = sorted({d.node_id for d in cluster.cm.disks.values() if d.az == failure["az_down"]})
+        if az_nodes != failure["nodes"]:
+            raise SystemExit(f"{CONFIG}: AZ {failure['az_down']} is nodes {az_nodes} "
+                             f"in this cluster, the file says {failure['nodes']}")
+        get("az_down", failure["nodes"], objects)
     finally:
         daemon.stop()
     out["ok"] = (len(out["steps"]) == len(sizes) + 2

@@ -24,4 +24,7 @@ def test_azdrive_two_az_deployment_reads_back_with_an_az_down(tmp_path):
     assert [s["step"] for s in steps] == ["healthy", "node_down", "node_down", "az_down"]
     assert all(s["differing"] == 0 for s in steps)
     assert steps[0]["decoded_bytes"] == 0 and all(s["decoded_bytes"] > 0 for s in steps[1:])
-    assert len(steps[-1]["nodes_down"]) == 6  # half of the 12 nodes: one whole AZ
+    # one whole AZ, the one the benchmark's AZ-down cell loses: both read it
+    # from the cell's configuration file
+    with open(os.path.join(ROOT, "benchmark", "configs", "az2-ec16p20l2-azdown.json")) as f:
+        assert steps[-1]["nodes_down"] == json.load(f)["failure"]["nodes"] == [1, 3, 5, 7, 9, 11]

@@ -117,6 +117,29 @@ def test_az2_served_shapes_compile_for_v5e(topo, name, mode, blob, batch):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# the AZ-down cell's decode (benchmark/configs/az2-ec16p20l2-azdown.json): 8
+# rows wanted from 16 survivors, 64 x 128 bits a job over 262,144 columns; an
+# even batch count stacks two jobs (128 x 256), an odd one stays at g = 1.
+# The cell's streams keep at most 8 x pipeline_window 3 = 24 jobs queued.
+@pytest.mark.parametrize("batch,g", [(1, 1), (2, 2), (23, 1), (24, 2)])
+def test_azdown_decode_shapes_compile_for_v5e(topo, batch, g):
+    t = get_tactic(CodeMode.EC16P20L2)
+    live = [i for i in range(t.N + t.M) if t.az_of_shard(i) == 1]
+    mat = rs.get_kernel(t.N, t.M).window_matrix(live[: t.N], list(range(t.N // 2)))
+    mat_bits = bitmatrix.expand_matrix(mat).astype(np.int8)
+    assert pallas_gf.pick_group(batch, *mat_bits.shape) == g
+    mat_s = np.kron(np.eye(g, dtype=np.int8), mat_bits)  # what rs.group_stack launches
+    assert mat_s.shape == (g * 64, g * 128)
+    kb = bucket_len(t.shard_size(4 << 20))
+    assert kb == 262144
+    dev = SingleDeviceSharding(topo.devices[0])
+    data = jax.ShapeDtypeStruct((batch // g, g * t.N, kb), jnp.uint8, sharding=dev)
+    mat_arg = jax.ShapeDtypeStruct(mat_s.shape, jnp.int8, sharding=dev)
+    compiled = pallas_gf._fused_core.lower(mat_arg, data, tile_k=None,
+                                           interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_sharded_gf_matmul_compiles_on_a_dp4_mesh(topo):
     """CodecService(mesh=...)'s step under shard_map over the four chips of
     a v5e 2x2 host: EC(12,4) group-stacked, matrix replicated at run time."""
