@@ -42,7 +42,8 @@ from chubaofs_tpu.codec.service import CodecService, default_service
 from chubaofs_tpu.utils.auditlog import record_slow_op
 from chubaofs_tpu.utils.breaker import CircuitBreaker
 from chubaofs_tpu.utils.locks import SanitizedLock
-from chubaofs_tpu.utils.exporter import BATCH_BUCKETS, registry
+from chubaofs_tpu.utils.exporter import (BATCH_BUCKETS, declare_label_values,
+                                         registry)
 
 MAX_BLOB_SIZE = 4 * 1024 * 1024
 
@@ -72,6 +73,15 @@ class DiskPunished(AccessError):
 class _PipelineAborted(Exception):
     """Internal: a later pipeline stage was skipped because an earlier blob's
     quorum already failed — never user-visible (the first real error wins)."""
+
+
+# the plans of an EC blob read (_read_blob_ec): the closed value set of
+# cfs_access_read_plan_total{plan}
+READ_PLANS = ("direct", "one_round", "two_round")
+
+
+def _count_read_plan(plan: str) -> None:
+    registry("access").counter("read_plan_total", {"plan": plan}).add()
 
 
 class _BlobDest:
@@ -259,6 +269,7 @@ class Access:
         # those pools would let W blocked stages starve their own shard IO
         self._pipe_pool = ThreadPoolExecutor(max_workers=8,
                                              thread_name_prefix="access-pipe")
+        declare_label_values("plan", READ_PLANS)
 
     # -- failure containment --------------------------------------------------
 
@@ -813,6 +824,21 @@ class Access:
 
     def _read_blob_ec(self, mode: int, blob: Blob, offset: int, size: int,
                       dst: _BlobDest) -> None:
+        """One blob from the EC tier, by one of three plans, counted once a
+        blob in cfs_access_read_plan_total{plan}:
+
+          direct     every in-window data unit is routed and answers: ranged
+                     sub-shard reads of only the data shards the byte range
+                     touches, issued concurrently.
+          one_round  the routing table shows, before any read, an in-window
+                     data unit that cannot answer (a dark host, a dark AZ):
+                     the blob is degraded by plan, so there is no direct
+                     phase. The degraded read launches its whole survivor set
+                     (the live in-window data shards with them) in ONE
+                     gather and its thread is woken once.
+          two_round  a ROUTED unit errored or hung in the direct phase: what
+                     it read, what failed and what hung go to the degraded
+                     path, which gathers the rest."""
         t = get_tactic(mode)
         vol = self.cm.get_volume(blob.vid)
         shard_len = t.shard_size(blob.size)
@@ -829,23 +855,28 @@ class Access:
             hi = min(offset + size, (idx + 1) * shard_len) - idx * shard_len
             return self._read_shard(vol, idx, blob.bid, lo, hi - lo)
 
-        # every direct read races a deadline: a shard that cannot answer in
-        # read_deadline (wedged node/disk) is treated as missing and the
-        # degraded path reconstructs around it — the stall is bounded even
-        # when the node never errors (stream_get races laggards the same way)
         idxs = list(range(first_shard, last_shard + 1))
         # a unit whose node is not routed (a dark host, a dark AZ) is a failed
         # read known without one: it is never handed to the pool
-        live = [i for i in idxs if vol.units[i].node_id in self.nodes]
+        dark = {i for i in idxs if vol.units[i].node_id not in self.nodes}
+        if dark:
+            _count_read_plan("one_round")
+            self._read_blob_degraded(t, vol, blob, shard_len, offset, size,
+                                     dst, failed=dark)
+            return
         # what the direct phase read: each piece goes to its place in the
         # body as its read returns, and is kept for the degraded path
         have: dict[int, bytes] = {}
         slow: set[int] = set()  # timed out, node possibly wedged
-        # fan-out (or the failed direct attempt)
+        # fan-out (or the failed direct attempt). Every direct read races a
+        # deadline: a shard that cannot answer in read_deadline (wedged
+        # node/disk) is treated as missing and the degraded path reconstructs
+        # around it — the stall is bounded even when the node never errors
+        # (stream_get races laggards the same way)
         with trace.stage("access.read", track="blobnode"):
-            futs = [self._read_pool.submit(read_one, i) for i in live]
+            futs = [self._read_pool.submit(read_one, i) for i in idxs]
             deadline = time.monotonic() + self.read_deadline
-            for i, f in zip(live, futs):
+            for i, f in zip(idxs, futs):
                 try:
                     piece = f.result(timeout=max(0.0, deadline - time.monotonic()))
                 except FutureTimeout:
@@ -855,9 +886,11 @@ class Access:
                     have[i] = piece
                     dst.put(max(offset, i * shard_len) - offset, piece)
             if len(have) == len(idxs):
+                _count_read_plan("direct")
                 return
         for f in futs:  # queued laggards must not hold pool workers
             f.cancel()
+        _count_read_plan("two_round")
         # hand the degraded path everything the direct phase learned: the
         # sub-range bytes it DID read (reused verbatim — never refetched),
         # the shards that errored (excluded from the survivor gather), and
@@ -975,21 +1008,30 @@ class Access:
                                 slow)
 
     def _gather_survivors(self, vol, bid: int, candidates: list[int],
-                          needed: int, lo: int,
-                          n: int) -> tuple[dict[int, bytes], list[int]]:
+                          needed: int, lo: int, n: int,
+                          windows: dict[int, tuple[int, int]] | None = None,
+                          ) -> tuple[dict[int, bytes], list[int]]:
         """Hedged sub-range gather of exactly `needed` shard reads from
-        `candidates` (preference order). Only the reads the selection wants
-        are ever launched — a FAILED read immediately launches the next
-        candidate to keep gather depth, and a read silent past read_deadline
-        launches a hedge replacement while the original keeps running (slow-
-        but-alive may still answer first) — so unselected candidates (the
-        parity tail of the list) are never fetched unless a selected read
-        lets the gather down. A candidate whose node is not routed has failed
-        already: it is never launched, and every such candidate is among the
-        failures whether or not the gather would have reached it. Returns
-        (idx -> bytes, failed idxs)."""
-        from concurrent.futures import FIRST_COMPLETED, wait
+        `candidates` (preference order), each over [lo, lo + n) or its own
+        (lo, n) of `windows`. Only the reads the selection wants are ever
+        launched — a FAILED read immediately launches the next candidate to
+        keep gather depth, and a read silent past read_deadline launches a
+        hedge replacement while the original keeps running (slow-but-alive
+        may still answer first) — so unselected candidates (the parity tail
+        of the list) are never fetched unless a selected read lets the gather
+        down. A candidate whose node is not routed has failed already: it is
+        never launched, and every such candidate is among the failures
+        whether or not the gather would have reached it.
 
+        The caller's thread sleeps on ONE event a round: the reads'
+        done-callbacks set it when a read has failed, or when as many reads
+        have finished as the gather still wants (the in-flight reads, all of
+        them, where they are fewer than that: a gather that can no longer
+        reach `needed` returns when its last read does), and its timeout is
+        the earlier of the moment an un-hedged read crosses read_deadline and
+        the end of the gather's budget. A round in which nothing fails or
+        hangs wakes it once, however many reads it launched. Returns
+        (idx -> bytes, failed idxs)."""
         got: dict[int, bytes] = {}
         failures = [i for i in candidates
                     if vol.units[i].node_id not in self.nodes]
@@ -1000,6 +1042,21 @@ class Access:
         launched: dict = {}  # future -> launch time (hang-hedge input)
         hedged: set = set()  # futures already replaced for being slow
         next_i = 0
+        windows = windows or {}
+        wake = threading.Event()
+        # what the callbacks hand over (list appends are atomic): every
+        # finished read with its bytes (None: it failed). `seen` of them are
+        # drained; the read that makes it `target` long wakes the gather
+        finished: list = []
+        seen, target = 0, min(needed, len(candidates))
+
+        def on_done(f) -> None:
+            if f.cancelled():
+                return  # a straggler this gather abandoned
+            data = f.result() if f.exception() is None else None
+            finished.append((f, data))
+            if data is None or len(finished) >= target:
+                wake.set()
 
         def launch() -> None:
             nonlocal next_i
@@ -1007,9 +1064,11 @@ class Access:
                 return
             idx = candidates[next_i]
             next_i += 1
-            f = self._read_pool.submit(self._read_shard, vol, idx, bid, lo, n)
+            f = self._read_pool.submit(self._read_shard, vol, idx, bid,
+                                       *windows.get(idx, (lo, n)))
             pending[f] = idx
             launched[f] = time.monotonic()
+            f.add_done_callback(on_done)
 
         for _ in range(min(needed, len(candidates))):
             launch()
@@ -1017,7 +1076,7 @@ class Access:
         # is the generous write_deadline, not the per-read read_deadline
         gather_deadline = time.monotonic() + self.write_deadline
         while pending and len(got) < needed:
-            # wake for the earliest of: gather budget, or the moment an
+            # sleep until the callbacks call, the gather budget ends, or an
             # un-hedged in-flight read crosses read_deadline
             now = time.monotonic()
             timeout = gather_deadline - now
@@ -1025,36 +1084,37 @@ class Access:
                             for f in pending if f not in hedged), default=None)
             if nxt_slow is not None:
                 timeout = min(timeout, nxt_slow - now)
-            done, _ = wait(pending, return_when=FIRST_COMPLETED,
-                           timeout=max(0.0, timeout))
-            if not done:
-                now = time.monotonic()
-                if now >= gather_deadline:
-                    break  # budget exhausted: abandon what never answered
-                # an in-flight read exceeded read_deadline without FAILING —
-                # a hung-but-silent replica. Launch a replacement from the
-                # not-yet-tried candidates (the original keeps running), so
-                # gather depth holds against hangs exactly as against
-                # failures.
-                for f in list(pending):
-                    if (f in hedged
-                            or now - launched[f] < self.read_deadline):
-                        continue
-                    hedged.add(f)
-                    launch()
-                continue
-            for fut in done:
+            # nothing to wait for past the reads the gather still wants, or
+            # past the last one in flight where those cannot make `needed`
+            target = seen + min(needed - len(got), len(pending))
+            if len(finished) >= target:
+                wake.set()  # they finished before the target was theirs to see
+            wake.wait(max(0.0, timeout))
+            wake.clear()  # before the drain: a later failure sets it again
+            while seen < len(finished):
+                fut, data = finished[seen]
+                seen += 1
                 idx = pending.pop(fut)
                 launched.pop(fut, None)
                 was_hedged = fut in hedged  # replacement already launched
                 hedged.discard(fut)
-                data = fut.result()
                 if data is not None:
                     got[idx] = data
                 else:
                     failures.append(idx)
                     if not was_hedged:
                         launch()  # keep gather depth
+            now = time.monotonic()
+            if now >= gather_deadline:
+                break  # budget exhausted: abandon what never answered
+            # an in-flight read exceeded read_deadline without FAILING — a
+            # hung-but-silent replica. Launch a replacement from the
+            # not-yet-tried candidates (the original keeps running), so
+            # gather depth holds against hangs exactly as against failures.
+            for f in list(pending):
+                if f not in hedged and now - launched[f] >= self.read_deadline:
+                    hedged.add(f)
+                    launch()
         for fut in pending:  # abandon stragglers (queued ones cancel cleanly)
             fut.cancel()
         return got, failures
@@ -1062,14 +1122,21 @@ class Access:
     def _degraded_window(self, t, vol, blob, shard_len, offset, size,
                          dst: _BlobDest, have: dict[int, bytes],
                          slow: set[int], failed_direct: set[int]) -> bool:
-        """Range-scoped degraded read: decode ONLY the in-window shards the
-        direct phase is missing, over only the window's byte columns. RS is
+        """Range-scoped degraded read: decode ONLY the in-window shards that
+        cannot be read, over only the window's byte columns. RS is
         column-independent, so t.N survivor rows sliced to the SAME columns
-        decode the missing rows' slice exactly (RSKernel.window_matrix). The
-        direct phase's pieces (`have`) are in the body already; the decoded
-        rows join them. Returns False when the gather can't reach N global
-        survivors — deep damage, which the full-stripe path (with AZ-local
-        recovery) owns."""
+        decode the missing rows' slice exactly (RSKernel.window_matrix).
+
+        After a direct phase (`have`: its pieces, in the body already) the
+        in-window shards it is missing are decoded, and the gather fetches
+        the survivors `have` does not cover. With no direct phase (`have`
+        empty: the routing table showed the blob degraded) the ONE gather
+        also reads the live in-window data shards, each once over the hull of
+        its own sub-window and the decode window: the same bytes go to the
+        shard's place in the body and are its survivor row. Returns False
+        when the gather can't reach N global survivors — deep damage, which
+        the full-stripe path (with AZ-local recovery) owns — or lost a live
+        edge shard whose bytes lie outside the columns it read."""
         first = offset // shard_len
         last = (offset + size - 1) // shard_len
 
@@ -1078,7 +1145,12 @@ class Access:
             hi = min(offset + size, (idx + 1) * shard_len) - idx * shard_len
             return lo, hi
 
-        need = [i for i in range(first, last + 1) if i not in have]
+        missing = [i for i in range(first, last + 1) if i not in have]
+        # to decode: what failed (an unrouted unit has) and what hung. To
+        # read in the gather, for the body: the rest (none after a direct
+        # phase)
+        need = [i for i in missing if i in failed_direct or i in slow]
+        body = [i for i in missing if i not in need]
         # the union byte-column window the decode must cover
         col_lo = min(window_of(i)[0] for i in need)
         col_hi = max(window_of(i)[1] for i in need)
@@ -1099,9 +1171,35 @@ class Access:
                       if i not in reuse and i not in failed_direct
                       and i not in need]
         candidates.sort(key=lambda i: (i in slow, i))
+        # a live in-window shard is read once, over the hull of its own
+        # sub-window and the decode's: (lo, n) of each
+        hulls: dict[int, tuple[int, int]] = {}
+        for i in body:
+            lo_i, hi_i = window_of(i)
+            h_lo = min(col_lo, lo_i)
+            hulls[i] = (h_lo, max(col_hi, hi_i) - h_lo)
         with trace.stage("access.gather"):  # windowed sub-reads
             got, gather_failed = self._gather_survivors(
-                vol, blob.bid, candidates, t.N - len(reuse), col_lo, width)
+                vol, blob.bid, candidates, t.N - len(reuse), col_lo, width,
+                hulls)
+        # a ROUTED in-window unit that failed or hung inside the round (the
+        # gather replaced or hedged it) is decoded with the unrouted ones;
+        # one whose sub-window reaches past the columns the survivors were
+        # read over (an edge shard of a ranged read) leaves it to the full path
+        lost = [i for i in body if i not in got]
+        if any(window_of(i)[0] < col_lo or window_of(i)[1] > col_hi
+               for i in lost):
+            return False
+        need = sorted(need + lost)
+        for i in body:  # one read: the body's piece and the survivor's row
+            if i in lost:
+                continue
+            lo_i, hi_i = window_of(i)
+            h_lo = hulls[i][0]
+            data = memoryview(got[i])
+            dst.put(i * shard_len + lo_i - offset,
+                    data[lo_i - h_lo: hi_i - h_lo])
+            got[i] = data[col_lo - h_lo: col_hi - h_lo]
         got.update(reuse)
         if len(got) < t.N:
             return False  # the full path re-proves and reports damage

@@ -14,6 +14,7 @@ failing under a chaos-injected sustained `blobnode.put_shard` delay.
 
 import json
 import os
+from concurrent.futures import ThreadPoolExecutor
 
 import pytest
 
@@ -439,16 +440,20 @@ def test_s3_driver_tenant_mix_over_live_gateway(tmp_path):
         tok0 = driver.blob_put(b"secret", tenant="t0")
         with pytest.raises(RuntimeError):
             driver.blob_get(tok0, tenant="t1")  # t1 creds on t0's bucket
-        # drive t1 past the parent cap: a throttle IS an op error
-        saw_throttle = False
-        for _ in range(120):
-            try:
-                driver.blob_put(b"x" * 64, tenant="t1")
-            except RuntimeError as e:
-                assert "HTTP 4" in str(e) or "HTTP 5" in str(e)
-                saw_throttle = True
-                break
-        assert saw_throttle
+        # drive t1 past the parent cap: a throttle IS an op error. Eight
+        # writers at once: one alone on a loaded host PUTs slower than the
+        # cap's 30 a second and is never throttled
+        def put_until_throttled(_):
+            for _ in range(40):
+                try:
+                    driver.blob_put(b"x" * 64, tenant="t1")
+                except RuntimeError as e:
+                    assert "HTTP 4" in str(e) or "HTTP 5" in str(e)
+                    return True
+            return False
+
+        with ThreadPoolExecutor(max_workers=8) as writers:
+            assert any(list(writers.map(put_until_throttled, range(8))))
     finally:
         srv.stop()
         qos.close()

@@ -6,6 +6,7 @@ once per arm (profiler session on / off); the cases read that recording."""
 import glob
 import subprocess
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -210,7 +211,15 @@ def served(tmp_path_factory):
         data = bytes(range(256)) * 1024
         assert client.get(client.put(data)) == data
         c.run_background_once()
-        after = stage_counts()
+        # the server observes a reply's gateway.send after the client has
+        # read it: on a loaded host give its thread a moment to get there
+        deadline = time.monotonic() + 5.0
+        while True:
+            after = stage_counts()
+            if after.get("gateway.send", 0) - before.get("gateway.send", 0) >= 2 \
+                    or time.monotonic() > deadline:
+                break
+            time.sleep(0.01)
     finally:
         gw.stop()
         c.close()
