@@ -62,7 +62,22 @@ class MiniCluster:
                                    codec=self.codec, cache=self.cache)
         self.worker = RepairWorker(self.scheduler, self.nodes, codec=self.codec)
 
+    def background_tick(self) -> dict:
+        """What the daemon's ticker runs: one tick of every background loop
+        with the tasks HANDED to the repair worker's own thread, never waited
+        for. A disk rebuild outlasts any tick; heartbeats, lease reaping, the
+        deleter and compaction do not queue behind it, and neither does
+        whoever waits for the lock the tick runs under."""
+        return self._tick(wait=False)
+
     def run_background_once(self) -> dict:
+        """One tick driven to quiescence, for in-process callers (tests, the
+        soak, tools): the same steps, with the worker's thread joined before
+        the hygiene steps, so that when it returns every task the tick made
+        has run."""
+        return self._tick(wait=True)
+
+    def _tick(self, wait: bool) -> dict:
         """One tick of every background loop (the 16-ticker scheduleTask analog):
         detection first (heartbeats, heartbeat expiry, lease reaping, the
         budgeted scrub), then the task planes, then host-local hygiene."""
@@ -83,9 +98,11 @@ class MiniCluster:
         tier_msgs = self.scheduler.run_tier()
         disk_tasks = self.scheduler.check_disks()
         balance_task = self.scheduler.check_balance()
-        ran = 0
-        while self.worker.run_once():
-            ran += 1
+        if wait:
+            ran = self.worker.wait_idle()
+        else:
+            ran = 0
+            self.worker.kick()
         deleted = self.scheduler.run_deleter()
         # compaction is host-local work: a dark/dead node skips its own sweep
         # without stalling the cluster's (the daemon analog runs it per host)
@@ -110,10 +127,10 @@ class MiniCluster:
         }
 
     def close(self):
+        self.worker.close()  # first: a migrate in flight stops between stripes
         if self._owns_codec:  # never kill a shared/injected service
             self.codec.close()
         self.access.close()
-        self.worker.close()
         for node in self.nodes.values():
             node.close()
         self.cm.close()

@@ -411,11 +411,13 @@ class ClusterMgr:
         return vol
 
     def get_volume(self, vid: int) -> VolumeInfo:
-        with self._lock:
-            vol = self.volumes.get(vid)
-            if vol is None:
-                raise ClusterError(f"unknown volume {vid}")
-            return vol
+        # one dict read, no lock: every blob of every GET looks its volume up
+        # here, and must not queue behind a write that holds the lock across
+        # its WAL put (which may compact and fsync)
+        vol = self.volumes.get(vid)
+        if vol is None:
+            raise ClusterError(f"unknown volume {vid}")
+        return vol
 
     def alloc_volume(self, code_mode: CodeMode | int, count_hint: int = 1) -> VolumeInfo:
         """Return an active volume of the mode, creating one if none exists."""
@@ -475,11 +477,14 @@ class ClusterMgr:
         if old is not None and old.chunk_count > 0:
             old.chunk_count -= 1  # the chunk moved WITH the unit
         d.chunk_count += 1
-        unit.epoch += 1
-        unit.disk_id = new_disk_id
-        unit.node_id = d.node_id
-        unit.vuid = make_vuid(vid, index, unit.epoch)
-        return unit
+        # a NEW unit swapped in by one list store: a reader that holds the old
+        # object keeps a consistent (vuid, disk, node) of the old home, one
+        # that indexes the list afterwards gets the new home whole, and none
+        # can see a unit half re-homed
+        epoch = unit.epoch + 1
+        vol.units[index] = new = VolumeUnit(
+            make_vuid(vid, index, epoch), index, new_disk_id, d.node_id, epoch)
+        return new
 
     # -- tier residency (hot Replica3 copies of sustained-hot EC blobs) ------
 

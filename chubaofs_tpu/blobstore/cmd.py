@@ -147,6 +147,41 @@ def add_admin_routes(router, cluster, runner: ModuleRunner | None = None):
     def disks(req):
         return _json([d.__dict__ for d in cluster.cm.disks.values()])
 
+    def set_disk(req):
+        """The operator's declaration (clustermgr /disk/set): this disk is
+        BROKEN. A dead node's disks need not wait out the heartbeat timeout:
+        the status is set now, the disk-repair task exists when the call
+        returns, and the repair worker is already on it. Refused for an
+        unknown disk, for any status but `broken` (NORMAL and DROPPED are the
+        repair's to set), and for a disk already DROPPED; declaring a broken
+        disk again changes nothing."""
+        from chubaofs_tpu.blobstore.clustermgr import (
+            DISK_BROKEN,
+            DISK_DROPPED,
+            DISK_NORMAL,
+        )
+
+        try:
+            disk_id = int(req.q("disk_id"))
+        except ValueError:
+            return _json({"error": "disk_id must be an integer"}, 400)
+        status = req.q("status")
+        if status != DISK_BROKEN:
+            return _json({"error": f"status {status!r} cannot be declared: "
+                                   f"only {DISK_BROKEN!r}"}, 400)
+        was = cluster.cm.disk_status(disk_id)
+        if was is None:
+            return _json({"error": f"unknown disk {disk_id}"}, 404)
+        if was == DISK_DROPPED:
+            return _json({"error": f"disk {disk_id} is dropped: its units "
+                                   "were rebuilt elsewhere"}, 409)
+        if was == DISK_NORMAL:
+            cluster.cm.set_disk_status(disk_id, DISK_BROKEN, reason="operator")
+        made = cluster.scheduler.check_disks()
+        cluster.worker.kick()
+        return _json({"disk_id": disk_id, "status": DISK_BROKEN, "was": was,
+                      "tasks": [t.task_id for t in made]})
+
     def volumes(req):
         return _json([
             {"vid": v.vid, "code_mode": v.code_mode, "status": v.status,
@@ -208,6 +243,7 @@ def add_admin_routes(router, cluster, runner: ModuleRunner | None = None):
 
     router.get("/admin/stat", stat)
     router.get("/admin/disks", disks)
+    router.post("/admin/disk/set", set_disk)
     router.get("/admin/volumes", volumes)
     router.get("/admin/volume", volume)
     router.get("/admin/tasks", tasks)

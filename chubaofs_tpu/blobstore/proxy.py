@@ -117,9 +117,17 @@ class Proxy:
     # -- message bus (mq analog) ---------------------------------------------
 
     def send_shard_repair(self, vid: int, bid: int, bad_idx: list[int], reason: str) -> None:
-        self.topics[TOPIC_SHARD_REPAIR].produce(
-            {"vid": vid, "bid": bid, "bad_idx": bad_idx, "reason": reason}
-        )
+        """A report is about the units the volume has NOW: their epochs travel
+        with it, so the scheduler can tell a position that was re-homed while
+        the report waited in the topic (the rebuilt unit is whole; a task for
+        it would gather a stripe to find nothing to do)."""
+        msg = {"vid": vid, "bid": bid, "bad_idx": bad_idx, "reason": reason}
+        try:
+            units = self.cm.get_volume(vid).units
+            msg["epochs"] = [units[i].epoch for i in bad_idx]
+        except Exception:
+            pass  # an unknown volume or index: the task says why
+        self.topics[TOPIC_SHARD_REPAIR].produce(msg)
 
     def send_blob_delete(self, vid: int, bid: int) -> None:
         self.topics[TOPIC_BLOB_DELETE].produce({"vid": vid, "bid": bid})

@@ -9,10 +9,13 @@ via HTTPTaskAcquire, service.go:84, repair tasks served first). Shapes kept:
   * tasks move through PREPARED -> WORKING -> FINISHED and survive restarts by
     reloading from the clustermgr-persisted task table;
   * workers acquire tasks (repair before balance) and report completion;
-  * the repair math itself is a batched TPU reconstruct through CodecService:
-    a disk-repair task covers every (volume, bid) on the dead disk, and the
-    worker stacks thousands of stripes into the same device batches
-    (SURVEY §3.5's 10k-stripe bulk-repair config).
+  * the repair math is the degraded GET's primitive, CodecService.decode_rows:
+    a disk-repair task covers every (volume, bid) on the dead disk, each
+    stripe asks for the ONE row its unit holds, and all stripes of a unit
+    share one matrix, so their jobs batch by content on the device
+    (SURVEY §3.5's bulk-repair config);
+  * tasks run on the worker's own thread (RepairWorker.kick): a rebuild that
+    outlasts a background tick never holds the tick.
 """
 
 from __future__ import annotations
@@ -22,7 +25,7 @@ import os
 import threading
 import time
 from collections import OrderedDict, deque
-from concurrent.futures import ThreadPoolExecutor
+from concurrent.futures import Future, ThreadPoolExecutor
 from concurrent.futures import TimeoutError as FutureTimeout
 from dataclasses import dataclass, field
 
@@ -35,6 +38,7 @@ from chubaofs_tpu.blobstore.clustermgr import (
     DISK_NORMAL,
     ClusterMgr,
     VolumeInfo,
+    make_vuid,
     parse_vuid,
 )
 from chubaofs_tpu.blobstore.proxy import (
@@ -217,13 +221,16 @@ class Scheduler:
 
     def _new_task(self, **kw) -> Task:
         with self._lock:
-            self._seq += 1
-            self.cm.set_config(self._TASK_SEQ_KEY, str(self._seq))
-            t = Task(task_id=f"t{self._seq}", **kw)
-            self._tasks[t.task_id] = t
-            self._persist_task(t)
-            self._update_gauges_locked()
-            return t
+            return self._new_task_locked(**kw)
+
+    def _new_task_locked(self, **kw) -> Task:
+        self._seq += 1
+        self.cm.set_config(self._TASK_SEQ_KEY, str(self._seq))
+        t = Task(task_id=f"t{self._seq}", **kw)
+        self._tasks[t.task_id] = t
+        self._persist_task(t)
+        self._update_gauges_locked()
+        return t
 
     def tasks(self, kind: str | None = None, state: str | None = None) -> list[Task]:
         with self._lock:
@@ -260,14 +267,43 @@ class Scheduler:
             }
         for m in msgs:
             key = (m["vid"], m["bid"])
-            if key in open_keys:
+            bad = self._still_reported(m)
+            if key in open_keys or not bad or self._owned_by_disk_repair(m["vid"], bad):
                 continue
             open_keys.add(key)
             self._new_task(
-                kind=KIND_SHARD_REPAIR, vid=m["vid"], bid=m["bid"], bad_idx=m["bad_idx"]
+                kind=KIND_SHARD_REPAIR, vid=m["vid"], bid=m["bid"], bad_idx=bad
             )
         topic.commit("scheduler", len(msgs))
         return len(msgs)
+
+    def _still_reported(self, msg: dict) -> list[int]:
+        """The reported positions whose unit is still the one the report was
+        made against. A unit re-homed since (its epoch moved on) was rebuilt
+        whole by the migrate that moved it: the degraded GETs of a rebuild
+        report faster than a tick drains the topic, and every report still
+        waiting when a unit is re-homed would otherwise become a task that
+        gathers a whole stripe to find nothing missing."""
+        bad, epochs = msg["bad_idx"], msg.get("epochs")
+        if not epochs:
+            return bad
+        try:
+            units = self.cm.get_volume(msg["vid"]).units
+            return [i for i, e in zip(bad, epochs) if units[i].epoch == e]
+        except Exception:
+            return bad  # an unknown volume or index: the task says why
+
+    def _owned_by_disk_repair(self, vid: int, bad_idx: list[int]) -> bool:
+        """Every reported position lies on a disk that is not NORMAL: the
+        disk-level repair rebuilds and re-homes it (shard_repairer.go leaves a
+        broken disk's shards to the disk repairer the same way), so a degraded
+        GET's report of it is no task."""
+        try:
+            units = self.cm.get_volume(vid).units
+            return all(self.cm.disk_status(units[i].disk_id) != DISK_NORMAL
+                       for i in bad_idx)
+        except Exception:
+            return False  # an unknown volume or index: the task says why
 
     def check_disks(self) -> list[Task]:
         """Turn broken disks into disk-repair tasks (disk_repairer analog).
@@ -279,17 +315,18 @@ class Scheduler:
         if not self.switches.enabled(SWITCH_DISK_REPAIR):
             return []
         out = []
-        for disk in self.cm.broken_disks():
-            # an open (prepared/working) task blocks re-creation; a FAILED one
-            # does not — the disk is still broken and must be retried
-            existing = [
-                t
-                for t in self.tasks(KIND_DISK_REPAIR)
-                if t.disk_id == disk.disk_id and t.state in (TASK_PREPARED, TASK_WORKING)
-            ]
-            if existing:
-                continue
-            out.append(self._new_task(kind=KIND_DISK_REPAIR, disk_id=disk.disk_id))
+        # check and creation under one lock: the ticker and the operator's
+        # declaration (an HTTP thread) may both find the same disk taskless
+        with self._lock:
+            for disk in self.cm.broken_disks():
+                # an open (prepared/working) task blocks re-creation; a FAILED
+                # one does not — the disk is still broken and must be retried
+                if any(t.kind == KIND_DISK_REPAIR and t.disk_id == disk.disk_id
+                       and t.state in (TASK_PREPARED, TASK_WORKING)
+                       for t in self._tasks.values()):
+                    continue
+                out.append(self._new_task_locked(
+                    kind=KIND_DISK_REPAIR, disk_id=disk.disk_id))
         return out
 
     def inspect_volumes(self, max_volumes: int = 4) -> int:
@@ -810,6 +847,27 @@ class Scheduler:
         registry("cache").counter("demotes").add()
 
 
+class _WorkerStopping(Exception):
+    """close() reached a migrate in flight: unwind without a report."""
+
+
+class _PendingRow:
+    """A unit's row still in the codec: the job's future and how to cut the
+    row's bytes out of its result. The cut runs on the thread that lands the
+    row, never in a callback on the codec's dispatcher."""
+
+    __slots__ = ("fut", "cut")
+
+    def __init__(self, fut: Future, cut):
+        self.fut, self.cut = fut, cut
+
+    def done(self) -> bool:
+        return self.fut.done()
+
+    def result(self) -> bytes:
+        return self.cut(self.fut.result())
+
+
 class RepairWorker:
     """Executes repair/migrate tasks with batched TPU reconstructs.
 
@@ -822,7 +880,21 @@ class RepairWorker:
     to repair-GET). Every task runs under a `scheduler.repair` span whose
     `download` stages and the codec's `codec.stack`/`codec.matmul` stages let
     cfs-trace prove the overlap.
+
+    Tasks run on the worker's OWN thread: kick() wakes it, wait_idle() is the
+    in-process driver's join. A disk rebuild outlasts any background tick, so
+    no tick (and no lock a tick holds) ever waits for one; close() stops a
+    migrate between two stripes and leaves its task retryable.
     """
+
+    # decode jobs one unit keeps in the codec's one FIFO before its thread
+    # waits for the oldest: what its own pipeline needs (a job is back within
+    # two gathers: ~1.2 in flight at 25 shards/s on the chip) and no more. The
+    # dispatcher serves readers and rebuild in arrival order at a fixed ~95
+    # jobs/s, so a deeper window is a larger claim on the readers' share
+    # whenever the queue is long: at 8 the rebuild doubled its rate and the
+    # readers lost a third of theirs for 8-14 s at a time (PERF.md, PR 36)
+    DECODE_AHEAD = 2
 
     def __init__(self, sched: Scheduler, nodes: dict[int, BlobNode],
                  codec: CodecService | None = None,
@@ -846,6 +918,17 @@ class RepairWorker:
             thread_name_prefix="repair-stripe")
         self._shard_pool = ThreadPoolExecutor(
             max_workers=16, thread_name_prefix="repair-io")
+        # the worker's own thread (started by the first kick): it drains the
+        # task table whenever a kick is newer than the last drain it finished
+        self._stop = threading.Event()
+        self._state = threading.Condition()
+        self._thread: threading.Thread | None = None
+        self._kicks = 0  # drains asked for
+        self._served = 0  # the newest kick a finished drain had seen
+        self._ran = 0  # tasks the thread has run
+        # (task id, lease, monotonic time of its last renewal) of the migrate
+        # the calling thread runs, for _keepalive
+        self._lease: tuple[str, int, float] | None = None
 
     def set_repair_window(self, window: int) -> None:
         """Change the stripe window AND resize the pool that realizes it —
@@ -861,11 +944,83 @@ class RepairWorker:
         old.shutdown(wait=False)
 
     def close(self) -> None:
-        """Shut down the worker's executors (racelint: unjoined-thread).
-        wait=False mirrors Access.close — a read wedged on a dead node must
-        not stall teardown; it fails on its own deadline."""
+        """Stop the worker's thread and shut down its executors (racelint:
+        unjoined-thread). A migrate in flight stops between two stripes
+        (_keepalive) and reports nothing: its task stays WORKING until the
+        reaper or a restart requeues it, and idempotent write-back makes the
+        re-execution safe. wait=False mirrors Access.close — a read wedged on
+        a dead node must not stall teardown; it fails on its own deadline."""
+        self._stop.set()
+        with self._state:
+            self._state.notify_all()
+            thread = self._thread
+        if thread is not None:
+            thread.join(timeout=5)
         self._stripe_pool.shutdown(wait=False)
         self._shard_pool.shutdown(wait=False)
+
+    # -- the worker's own thread ------------------------------------------------
+
+    def kick(self) -> None:
+        """Wake the worker's thread: it runs tasks until none is left."""
+        with self._state:
+            self._kicks += 1
+            if self._thread is None and not self._stop.is_set():
+                self._thread = threading.Thread(
+                    target=self._loop, daemon=True, name="repair-worker")
+                self._thread.start()
+            self._state.notify_all()
+
+    def wait_idle(self) -> int:
+        """kick(), then wait until a drain that began after this call has run
+        out of tasks; returns how many tasks ran meanwhile. The in-process
+        driver's join (MiniCluster.run_background_once): the daemon's tick
+        never calls it."""
+        with self._state:
+            ran0 = self._ran
+        self.kick()
+        with self._state:
+            want = self._kicks
+            while self._served < want and not self._stop.is_set():
+                self._state.wait(0.5)
+            return self._ran - ran0
+
+    def _loop(self) -> None:
+        while True:
+            with self._state:
+                while self._served == self._kicks and not self._stop.is_set():
+                    self._state.wait()
+                if self._stop.is_set():
+                    return
+                seen = self._kicks
+            ran = 0
+            try:
+                while not self._stop.is_set() and self.run_once():
+                    ran += 1
+            except Exception:
+                # run_once records task failures on the task; what reaches
+                # here is the plane's own (a cm closing under a reload)
+                registry("scheduler").counter("worker_loop_errors").add()
+            with self._state:
+                self._served = seen
+                self._ran += ran
+                self._state.notify_all()
+
+    def _keepalive(self) -> None:
+        """Between two stripes of a migrate: stop if the worker is closing,
+        and renew the task's lease once a third of it has passed (a unit of
+        hundreds of stripes outlasts a lease; the per-unit renewal alone would
+        hand a healthy migrate to the reaper)."""
+        if self._stop.is_set():
+            raise _WorkerStopping()
+        if self._lease is None:
+            return
+        task_id, lease, at = self._lease
+        now = time.monotonic()
+        if now - at >= self.sched.lease_ms / 3e3:
+            if not self.sched.renew_lease(task_id, lease):
+                raise RuntimeError(f"lease {lease} lost mid-migrate ({task_id})")
+            self._lease = (task_id, lease, now)
 
     def run_once(self) -> bool:
         """Process one task; failures are recorded on the task, never raised —
@@ -895,6 +1050,8 @@ class RepairWorker:
                     self._tier_promote(task, lease)
                 elif task.kind == KIND_TIER_DEMOTE:
                     self.sched._drop_hot_copy(task.vid, task.bid)
+            except _WorkerStopping:
+                return False  # no report: the task stays leased, retryable
             except Exception as e:
                 ok, err = False, f"{type(e).__name__}: {e}"
             ratio = stage_overlap_ratio(span.stages)
@@ -1021,10 +1178,23 @@ class RepairWorker:
 
     # -- single-stripe shard repair -------------------------------------------
 
+    def _usable(self, vol: VolumeInfo, idx: int) -> bool:
+        """This stripe position can be read and written now: its node is
+        routed and its disk NORMAL."""
+        u = vol.units[idx]
+        if u.node_id not in self.nodes:
+            return False
+        d = self.cm.disks.get(u.disk_id)
+        return d is None or d.status == DISK_NORMAL
+
     def _repair_shards(self, vid: int, bid: int, bad_idx: list[int]):
         vol = self.cm.get_volume(vid)
         t = vol.tactic()
-        unhandled = sorted(set(bad_idx))
+        # a position on a dark node or a broken disk cannot be written back
+        # where it lives: the disk-level rebuild re-homes it
+        unhandled = sorted(i for i in set(bad_idx) if self._usable(vol, i))
+        if not unhandled:
+            return
         if t.L:
             unhandled = self._repair_local_stripes(vol, t, bid, unhandled)
             if not unhandled:
@@ -1059,16 +1229,16 @@ class RepairWorker:
             if len(az_bad) > local_m:
                 leftover.extend(az_reported)  # beyond local budget
                 continue
-            shard_len = len(next(iter(reads.values())))
-            sub = np.zeros((len(idx), shard_len), np.uint8)
+            # the AZ's local stripe is RS(local_n, local_m) over `idx`: the
+            # lost rows from its first local_n readable ones
             pos = {g: p for p, g in enumerate(idx)}
-            for g, data in reads.items():
-                sub[pos[g]] = np.frombuffer(data, np.uint8)
-            fixed = self.codec.reconstruct(
-                local_n, local_m, sub, [pos[i] for i in az_bad]
-            ).result()
-            for g in az_bad:
-                self._write_back(vol, g, bid, fixed[pos[g]].tobytes())
+            srv = [g for g in idx if g in reads][:local_n]
+            sub = np.stack([np.frombuffer(reads[g], np.uint8) for g in srv])
+            rows = self.codec.decode_rows(
+                local_n, local_m, [pos[g] for g in srv], sub,
+                [pos[g] for g in az_bad]).result()
+            for p, g in enumerate(az_bad):
+                self._write_back(vol, g, bid, rows[p].tobytes())
             # the repair-traffic win the LRC layout buys: these shards were
             # healed reading ONE local group, not the global stripe
             registry("scheduler").counter(
@@ -1081,10 +1251,15 @@ class RepairWorker:
         stripe, present, shard_len = self._gather(vol, t, bid, span=span)
         missing = [i for i in range(t.N + t.M) if i not in present]
         if missing:
-            fixed = self.codec.reconstruct_tactic(t, stripe, missing).result()
+            if t.is_regenerating:  # any-N decode through the PM generator
+                stripe = self.codec.reconstruct_tactic(
+                    t, stripe, missing).result()
+            else:
+                srv = present[: t.N]
+                stripe[missing] = self.codec.decode_rows(
+                    t.N, t.M, srv, stripe[srv], missing).result()
             for idx in missing:
-                self._write_back(vol, idx, bid, fixed[idx].tobytes())
-            stripe = fixed
+                self._write_back(vol, idx, bid, stripe[idx].tobytes())
             registry("scheduler").counter(
                 "repair_global_shards").add(len(missing))
         if t.L:
@@ -1108,7 +1283,11 @@ class RepairWorker:
     def _write_back(self, vol: VolumeInfo, idx: int, bid: int, payload: bytes):
         """Idempotent by construction: put_shard over an existing bid punches
         the superseded record and appends the same bytes, so a re-executed
-        task (lease expiry, crash-restart) can never corrupt the stripe."""
+        task (lease expiry, crash-restart) can never corrupt the stripe. A
+        position that cannot be written where it lives (dark node, broken
+        disk) is the disk-level rebuild's."""
+        if not self._usable(vol, idx):
+            return
         unit = vol.units[idx]
         node = self.nodes[unit.node_id]
         node.create_vuid(unit.vuid, unit.disk_id)
@@ -1214,15 +1393,8 @@ class RepairWorker:
 
         reg = registry("scheduler")
 
-        def usable(i: int) -> bool:
-            u = vol.units[i]
-            if u.node_id not in self.nodes:
-                return False
-            d = self.cm.disks.get(u.disk_id)
-            return d is None or d.status == DISK_NORMAL
-
         alive = [i for i in range(t.global_count)
-                 if i != fail and usable(i)]
+                 if i != fail and self._usable(vol, i)]
         helpers = t.helper_set(fail, alive)
         if not helpers:
             reg.counter("repair_beta_fallback",
@@ -1277,38 +1449,35 @@ class RepairWorker:
     def _migrate_disk(self, task: Task, lease: int | None = None):
         """Move every stripe position off a disk.
 
-        Order matters: GATHER (and copy/reconstruct) the rows through the OLD
-        units first — for a drop of a healthy disk that's a plain read-copy —
-        and only then re-home the units in clustermgr. A crash mid-task
-        leaves every uncommitted unit's old mapping intact and the task
-        retryable. The prepare/commit split is also the cross-unit pipeline:
-        while unit k's reconstructs drain through the device, unit k+1's
-        survivor downloads are already in flight — with few bids per unit,
-        this (not the intra-unit window) is where the overlap comes from."""
+        Order matters, per unit (migrate.go's prepare / work / finish): pick
+        the destination and open the unit's NEW chunk there (the vuid of its
+        next epoch, which no reader knows yet); copy or rebuild every row
+        through the OLD mapping and write it into that chunk; only then
+        re-home the unit in clustermgr, in one step. A reader meets either
+        the old unit (dark: it decodes around it) or the new one with every
+        shard in place, never a unit half written. A crash mid-task leaves
+        every uncommitted unit's old mapping intact and the task retryable."""
         source_broken = self.cm.disks[task.disk_id].status != DISK_NORMAL
-        affected = self.cm.volumes_on_disk(task.disk_id)
-        # bounded prepare-ahead: holding every unit's reconstructed rows at
-        # once would scale memory with the whole disk, not the window.
-        # window <= 1 means the SERIAL control path — depth 1, no cross-unit
-        # overlap either, so the bench A/B measures what it claims to
-        window = self.repair_window or 0
-        depth = max(2, window) if window > 1 else 1
-        pending: deque = deque()
-        for vol, unit in affected:
-            # a disk migrate routinely outlives one lease: renew per unit so
-            # a HEALTHY worker never races the reaper; a lost lease (we were
-            # reaped and possibly re-leased) aborts — the work is someone
-            # else's now, and idempotent write-back keeps the abort safe
-            if lease is not None and \
-                    not self.sched.renew_lease(task.task_id, lease):
-                raise RuntimeError(
-                    f"lease {lease} lost mid-migrate of disk {task.disk_id}")
-            pending.append(
-                self._prepare_unit(vol, unit, task.disk_id, source_broken))
-            if len(pending) >= depth:
-                self._commit_unit(pending.popleft(), task.disk_id)
-        while pending:
-            self._commit_unit(pending.popleft(), task.disk_id)
+        try:
+            for vol, unit in self.cm.volumes_on_disk(task.disk_id):
+                # a disk migrate routinely outlives one lease: renew per unit
+                # (and, inside a long unit, by the clock: _keepalive) so a
+                # HEALTHY worker never races the reaper; a lost lease (we were
+                # reaped and possibly re-leased) aborts — the work is someone
+                # else's now, and idempotent write-back keeps the abort safe
+                if lease is not None:
+                    if not self.sched.renew_lease(task.task_id, lease):
+                        raise RuntimeError(
+                            f"lease {lease} lost mid-migrate of disk {task.disk_id}")
+                    self._lease = (task.task_id, lease, time.monotonic())
+                # re-homed as soon as it is whole: readers stop decoding
+                # around a unit the moment its last row is in place
+                self._commit_unit(
+                    self._prepare_unit(vol, unit, task.disk_id, source_broken),
+                    task.disk_id)
+        finally:
+            self._lease = None
+        # DROPPED only here: every unit the disk held is committed
         self.cm.set_disk_status(task.disk_id, DISK_DROPPED)
 
     def _balance_unit(self, task: Task):
@@ -1323,9 +1492,9 @@ class RepairWorker:
             self._enqueue_missing(vol)
             return
         source_broken = self.cm.disks[task.disk_id].status != DISK_NORMAL
-        prep = self._prepare_unit(vol, unit, task.disk_id, source_broken)
-        self._commit_unit(prep, task.disk_id,
-                          dest_disk_id=task.dest_disk_id)
+        prep = self._prepare_unit(vol, unit, task.disk_id, source_broken,
+                                  dest_disk_id=task.dest_disk_id)
+        self._commit_unit(prep, task.disk_id)
 
     def _enqueue_missing(self, vol: VolumeInfo):
         """Probe every stripe position of every bid in the volume; feed any
@@ -1348,17 +1517,46 @@ class RepairWorker:
                                                    "balance_retry")
 
     def _copy_direct(self, vol: VolumeInfo, unit, bids: list[int],
-                     rows: dict[int, bytes]) -> list[int]:
+                     prep: dict) -> list[int]:
         """Healthy-source fast path: CONCURRENT bounded reads of the unit's
         own rows via _drain_reads (a serial loop here would pay
-        read_deadline per slow bid, not per unit). Returns the bids that
-        still need the gather/reconstruct pipeline."""
+        read_deadline per slow bid, not per unit), written to the new chunk.
+        Returns the bids that still need the gather/reconstruct pipeline."""
         node = self.nodes.get(unit.node_id)
         if node is None:
             return list(bids)
         futs = {bid: self._shard_pool.submit(node.get_shard, unit.vuid, bid)
                 for bid in bids}
-        return self._drain_reads(futs, rows)
+        rows: dict[int, bytes] = {}
+        left = self._drain_reads(futs, rows)
+        for bid, payload in rows.items():
+            self._put_row(prep, bid, payload)
+        return left
+
+    def _gather_rows(self, vol: VolumeInfo, t, unit, bid: int, span=None):
+        """Exactly N survivor rows of a stripe for the rebuild of `unit`:
+        (global positions, (N, k) bytes in that order). Reads the first N
+        positions that can answer (routed node, NORMAL disk) and replaces only
+        what fails, so every stripe of a unit has the same survivor set, and
+        with it the same decode matrix, wherever nothing else is damaged. The
+        rebuild reads N shards a stripe and no more."""
+        with trace.stage("repair.gather"):
+            cands = [i for i in range(t.N + t.M)
+                     if i != unit.index and self._usable(vol, i)]
+            reads: dict[int, bytes] = {}
+            tried = 0
+            while len(reads) < t.N and tried < len(cands):
+                batch = cands[tried: tried + t.N - len(reads)]
+                tried += len(batch)
+                reads.update(self._probe(vol, bid, batch, span=span))
+            if len(reads) < t.N:
+                raise RuntimeError(
+                    f"stripe {vol.vid}/{bid}: {len(reads)} < N={t.N} readable")
+            present = sorted(reads)
+            registry("scheduler").counter("rebuild_bytes", {"kind": "read"}).add(
+                sum(len(reads[i]) for i in present))
+            return present, np.stack(
+                [np.frombuffer(reads[i], np.uint8) for i in present])
 
     def _gather_for_unit(self, vol: VolumeInfo, t, unit, bid: int,
                          span=None):
@@ -1366,89 +1564,127 @@ class RepairWorker:
         regenerating volume first tries the beta-fetch for the migrating
         unit's row (d combined payloads instead of a full-stripe gather —
         the bulk-rebuild path is where nearly all repair bytes move) and
-        falls back to the full gather when helpers can't cover it."""
-        if t.is_regenerating and unit.index < t.global_count:
-            got = self._gather_beta(vol, t, bid, unit.index, span=span)
-            if got is not None:
-                return ("beta",) + got
-        return ("full", self._gather(vol, t, bid, span=span))
+        falls back to the full gather when helpers can't cover it. An RS or
+        LRC global unit gathers N survivors; an LRC local parity gathers its
+        AZ's local stripe, and N survivors only where that has holes."""
+        if t.is_regenerating:
+            if unit.index < t.global_count:
+                got = self._gather_beta(vol, t, bid, unit.index, span=span)
+                if got is not None:
+                    return ("beta",) + got
+            return ("full", self._gather(vol, t, bid, span=span))
+        if unit.index < t.N + t.M:
+            return ("rows",) + self._gather_rows(vol, t, unit, bid, span=span)
+        idx, local_n, _ = next(ls for ls in t.local_stripes() if unit.index in ls[0])
+        have = self._probe(vol, bid, [i for i in idx[:local_n] if self._usable(vol, i)],
+                           span=span)
+        survivors = None
+        if len(have) < local_n:
+            survivors = self._gather_rows(vol, t, unit, bid, span=span)
+        return ("local", idx, have, survivors)
 
-    def _stripe_row(self, vol: VolumeInfo, t, unit, bid: int, gathered,
-                    rows: dict[int, bytes], futures: dict[int, object]):
-        """Turn one gathered stripe into the migrating unit's row: a present
-        survivor copies, a lost global shard becomes a (batchable) device
-        reconstruct future, a lost local parity re-encodes its AZ stripe.
-        A beta-gather (regenerating modes) becomes the (alpha, d) repair
-        matmul — batchable on the device exactly like the RS decodes."""
-        from concurrent.futures import Future
-
-        if gathered[0] == "beta":
+    def _submit_row(self, vol: VolumeInfo, t, unit, bid: int, gathered):
+        """Turn one gathered stripe into the migrating unit's row: its bytes,
+        or a _PendingRow of them. A lost global shard is ONE row of the degraded
+        GET's decode ((1, N) @ (N, k) through CodecService.decode_rows: every
+        stripe of the unit shares the matrix, so the jobs batch by content); a
+        lost local parity decodes what its AZ's local stripe lacks and
+        re-encodes it. A beta-gather (regenerating modes) becomes the
+        (alpha, d) repair matmul — batchable on the device like the decodes."""
+        reg = registry("scheduler")
+        kind = gathered[0]
+        if kind == "beta":
             _, helpers, payloads = gathered
             from chubaofs_tpu.codec import pm
 
             kernel = pm.get_kernel(t.total, t.N)
             mat = kernel.repair_matrix(unit.index, helpers)
-            mm = self.codec.matmul(mat, payloads)
-            # _commit_unit resolves futures as result()[unit.index]: deliver
-            # the single rebuilt row under that key (a dict indexes the same
-            # way a full stripe array does)
-            out: Future = Future()
-            idx = unit.index
-
-            def _fin(f: Future, out=out, idx=idx):
-                if f.exception():
-                    out.set_exception(f.exception())
-                else:
-                    out.set_result({idx: f.result().reshape(-1)})
-
-            mm.add_done_callback(_fin)
-            futures[bid] = out
-            registry("scheduler").counter("repair_beta_shards").add()
-            return
-        stripe, present, _ = gathered[1]
-        missing = [i for i in range(t.N + t.M) if i not in present]
-        if unit.index in present:
-            rows[bid] = stripe[unit.index].tobytes()
-        elif unit.index < t.global_count:
+            reg.counter("repair_beta_shards").add()
+            return _PendingRow(self.codec.matmul(mat, payloads),
+                         lambda out: out.reshape(-1).tobytes())
+        if kind == "full":  # a regenerating stripe the beta-fetch cannot cover
+            stripe, present, _ = gathered[1]
+            if unit.index in present:
+                return stripe[unit.index].tobytes()
             # repair with the FULL missing set: zero-filled absent rows
             # must never be treated as survivors
-            futures[bid] = self.codec.reconstruct_tactic(t, stripe, missing)
-        else:
-            # LRC local parity: complete the globals, then re-encode
-            # this AZ's local stripe to regenerate the lost row
-            if missing:
-                stripe = self.codec.reconstruct(t.N, t.M, stripe, missing).result()
-            local_n = (t.N + t.M) // t.az_count
-            local_m = t.L // t.az_count
-            for idx, _, _ in t.local_stripes():
-                if unit.index in idx:
-                    full = self.codec.encode(
-                        local_n, local_m, stripe[idx[:local_n]]
-                    ).result()
-                    pos = idx[local_n:].index(unit.index)
-                    rows[bid] = full[local_n + pos].tobytes()
-                    break
+            missing = [i for i in range(t.N + t.M) if i not in present]
+            return _PendingRow(self.codec.reconstruct_tactic(t, stripe, missing),
+                         lambda fixed: fixed[unit.index].tobytes())
+        if kind == "rows":
+            _, present, survivors = gathered
+            reg.counter("rebuild_decode_jobs").add()
+            return _PendingRow(self.codec.decode_rows(t.N, t.M, present, survivors,
+                                                [unit.index]),
+                         lambda rows: rows[0].tobytes())
+        # LRC local parity: complete its AZ's local stripe, then re-encode
+        _, idx, have, survivors = gathered
+        local_m = t.L // t.az_count
+        src = idx[: len(idx) - local_m]
+        lost = [i for i in src if i not in have]
+        rows = {i: np.frombuffer(have[i], np.uint8) for i in have}
+        if lost:
+            present, surv = survivors
+            reg.counter("rebuild_decode_jobs").add()
+            got = self.codec.decode_rows(t.N, t.M, present, surv, lost).result()
+            rows.update({i: got[p] for p, i in enumerate(lost)})
+        full = self.codec.encode(
+            len(src), local_m, np.stack([rows[i] for i in src])).result()
+        return full[len(src) + idx[len(src):].index(unit.index)].tobytes()
+
+    def _put_row(self, prep: dict, bid: int, payload: bytes) -> None:
+        """One row into the unit's new chunk (CRC-framed and verified by the
+        blobnode like any shard write)."""
+        with trace.stage("repair.write_back"):
+            prep["node"].put_shard(prep["vuid"], bid, payload)
+        reg = registry("scheduler")
+        reg.counter("repaired_shards").add()
+        reg.counter("rebuild_bytes", {"kind": "written"}).add(len(payload))
+
+    def _land_oldest(self, prep: dict, inflight: deque) -> None:
+        """Wait for the unit's oldest decode and write its row."""
+        self._keepalive()
+        bid, row = inflight.popleft()
+        if isinstance(row, _PendingRow):
+            with trace.stage("repair.decode_wait"):
+                row = row.result()
+        self._put_row(prep, bid, row)
 
     def _rebuild_rows(self, vol: VolumeInfo, t, unit, bids: list[int],
-                      rows: dict[int, bytes], futures: dict[int, object]):
+                      prep: dict):
         """The windowed rebuild pipeline (the _put_pipelined window pattern
         applied to repair-GET): up to repair_window stripes' survivor
-        gathers run on the stripe pool while earlier stripes' reconstructs
-        drain through the codec service's device batches — downloads never
-        idle waiting on decode, decode never starves waiting on the network.
-        Consumption is bid order, so write-back order is deterministic.
+        gathers run on the stripe pool while earlier stripes' decodes drain
+        through the codec service's device batches (at most DECODE_AHEAD of
+        them in flight a unit) and their rows go to the new chunk — downloads
+        never idle waiting on decode, decode never starves waiting on the
+        network. Consumption is bid order, so write-back order is
+        deterministic; every row is in the chunk on return. A stripe that
+        cannot be gathered because its blob was deleted meanwhile is skipped
+        (_commit_unit records the delete); any other one fails the task.
         repair_window <= 1 degenerates to the serial control path."""
         if not bids:
             return
         span = trace.current_span()
         window = self.repair_window
-        if window <= 1:
-            for bid in bids:
-                self._stripe_row(vol, t, unit, bid,
-                                 self._gather_for_unit(vol, t, unit, bid,
-                                                       span=span),
-                                 rows, futures)
-            return
+        inflight: deque = deque()
+
+        def gather(bid: int):
+            try:
+                return self._gather_for_unit(vol, t, unit, bid, span=span)
+            except Exception:
+                if self._deleted(vol, bid):
+                    return None
+                raise
+
+        def decode(bid: int, gathered) -> None:
+            if gathered is None:
+                return
+            inflight.append((bid, self._submit_row(vol, t, unit, bid, gathered)))
+            while inflight and (len(inflight) > self.DECODE_AHEAD
+                                or not isinstance(inflight[0][1], _PendingRow)
+                                or inflight[0][1].done()):
+                self._land_oldest(prep, inflight)
 
         def gather_job(bid: int):
             # the task span follows the gather onto the pool worker so its
@@ -1456,31 +1692,77 @@ class RepairWorker:
             if span is not None:
                 trace.push_span(span)
             try:
-                return self._gather_for_unit(vol, t, unit, bid, span=span)
+                return gather(bid)
             finally:
                 if span is not None:
                     trace.pop_span()
 
-        occ = registry("scheduler").summary("rebuild_window_occupancy",
-                                            buckets=BATCH_BUCKETS)
-        pending: deque = deque()
-        it = iter(bids)
-        nxt = next(it, None)
-        while pending or nxt is not None:
-            while nxt is not None and len(pending) < window:
-                pending.append((nxt, self._stripe_pool.submit(gather_job, nxt)))
-                nxt = next(it, None)
-            occ.observe(len(pending))
-            bid, f = pending.popleft()
-            self._stripe_row(vol, t, unit, bid, f.result(), rows, futures)
+        if window <= 1:
+            for bid in bids:
+                decode(bid, gather(bid))
+        else:
+            occ = registry("scheduler").summary("rebuild_window_occupancy",
+                                                buckets=BATCH_BUCKETS)
+            pending: deque = deque()
+            it = iter(bids)
+            nxt = next(it, None)
+            while pending or nxt is not None:
+                while nxt is not None and len(pending) < window:
+                    pending.append((nxt, self._stripe_pool.submit(gather_job, nxt)))
+                    nxt = next(it, None)
+                occ.observe(len(pending))
+                bid, f = pending.popleft()
+                decode(bid, f.result())
+        while inflight:
+            self._land_oldest(prep, inflight)
+
+    def _deleted(self, vol: VolumeInfo, bid: int) -> bool:
+        """The deleter has this blob: noted by a sweep of this process, or
+        tombstoned on a unit that can say so (a tombstone ANYWHERE means the
+        bid was deleted: inspect_volumes reads them the same way)."""
+        return self.sched._recently_deleted(vol.vid, bid) or any(
+            self.sched._has_tombstone(u.node_id, u.vuid, bid) for u in vol.units)
+
+    def _tombstones(self, vol: VolumeInfo) -> set[int]:
+        """Every bid some reachable unit of the volume holds a tombstone for."""
+        out: set[int] = set()
+        for u in vol.units:
+            node = self.nodes.get(u.node_id)
+            if node is not None:
+                try:
+                    out |= node.tombstones_of(u.vuid)
+                except Exception:
+                    pass  # no chunk there (never written, or dropped)
+        return out
+
+    def _carry_deletes(self, prep: dict) -> None:
+        """Deletes travel with the unit: every bid the volume's reachable
+        units hold a tombstone for, and every row of the new chunk whose blob
+        the deleter has taken since it was written, is deleted in the new
+        chunk too. A rebuild runs beside the deleter: a delete that punched
+        only the old units must not leave its shard in the new one, and one
+        whose only tombstones move must not be resurrected."""
+        vol, node, vuid = prep["vol"], prep["node"], prep["vuid"]
+        gone = self._tombstones(vol)
+        gone.update(b for b in prep["bids"]
+                    if self.sched._recently_deleted(vol.vid, b))
+        gone -= prep["carried"]
+        prep["carried"] |= gone
+        for bid in gone:
+            try:
+                node.mark_delete_shard(vuid, bid)
+                node.delete_shard(vuid, bid)
+            except Exception:
+                node.tombstone_shard(vuid, bid)  # never stored here
 
     def _prepare_unit(self, vol: VolumeInfo, unit, source_disk_id: int,
-                      source_broken: bool) -> dict:
-        """Phase 1 of a unit move: gather/copy every row and SUBMIT the
-        reconstructs (decode futures left in flight — the codec service
-        batches them into shared device calls, and the caller may start the
-        next unit's downloads while they drain). No cluster state changes
-        here: a crash after prepare leaves the old mapping untouched."""
+                      source_broken: bool,
+                      dest_disk_id: int | None = None) -> dict:
+        """Phases 1 and 2 of a unit move: pick the destination, open the
+        unit's NEW chunk there (the vuid of its next epoch), then copy or
+        rebuild every row into it. No cluster state changes here: a crash
+        after prepare leaves the old mapping untouched and a chunk no reader
+        knows."""
         t = vol.tactic()
         # every bid in this volume, seen from any unit (source included when healthy)
         bids: set[int] = set()
@@ -1494,37 +1776,11 @@ class RepairWorker:
                 bids.update(m.bid for m in node.list_shards(u.vuid))
             except Exception:
                 continue
-        # source copies or reconstruct futures. Tombstones TRAVEL with the
-        # unit — enumerated DIRECTLY from the source chunk (they are
-        # invisible to list_shards, so deriving them from live bids would
-        # drop any delete whose bid no reachable unit still serves) — a bid
-        # deleted at the source must stay deleted at the destination.
-        src_node = self.nodes.get(unit.node_id)
-        tombstoned: set[int] = set()
-        if src_node is not None:
-            try:
-                tombstoned = src_node.tombstones_of(unit.vuid)
-            except Exception:
-                pass
-        rows: dict[int, bytes] = {}
-        futures: dict[int, object] = {}
-        work = [b for b in sorted(bids) if b not in tombstoned]
-        if not source_broken:
-            work = self._copy_direct(vol, unit, work, rows)
-        self._rebuild_rows(vol, t, unit, work, rows, futures)
-        return {"vol": vol, "unit": unit, "rows": rows, "futures": futures,
-                "tombstoned": tombstoned}
-
-    def _commit_unit(self, prep: dict, source_disk_id: int,
-                     dest_disk_id: int | None = None):
-        """Phase 2: resolve the in-flight decodes, then re-home the unit in
-        clustermgr and write everything to the new disk. The mapping update
-        stays AFTER all reads/decodes so a failed prepare never half-moves."""
-        vol, unit = prep["vol"], prep["unit"]
-        rows, tombstoned = prep["rows"], prep["tombstoned"]
-        for bid, fut in prep["futures"].items():
-            rows[bid] = fut.result()[unit.index].tobytes()
-
+        # source copies or rebuilt rows. Tombstones TRAVEL with the unit —
+        # enumerated DIRECTLY from the chunks (they are invisible to
+        # list_shards, so deriving them from live bids would drop any delete
+        # whose bid no reachable unit still serves): _commit_unit carries them
+        tombstoned = self._tombstones(vol)
         dest = dest_disk_id
         if dest is not None:
             # a destination pinned at scheduling time may have gone stale
@@ -1534,23 +1790,41 @@ class RepairWorker:
                 dest = None
         if dest is None:
             dest = self._dest_for(vol, source_disk_id)
-        old_vuid, old_node_id = unit.vuid, unit.node_id
-        new_unit = self.cm.update_volume_unit(vol.vid, unit.index, dest)
-        dest_node = self.nodes[new_unit.node_id]
-        dest_node.create_vuid(new_unit.vuid, new_unit.disk_id)
-        for bid, payload in rows.items():
-            dest_node.put_shard(new_unit.vuid, bid, payload)
-        registry("scheduler").counter("repaired_shards").add(len(rows))
-        for bid in tombstoned:
-            dest_node.tombstone_shard(new_unit.vuid, bid)
-        # the move must FREE the source: drop the superseded chunk (best
-        # effort — an unreachable/broken source just leaks until re-imaged)
-        old_node = self.nodes.get(old_node_id)
-        if old_node is not None:
-            try:
-                old_node.drop_vuid(old_vuid)
-            except Exception:
-                pass
+        vuid = make_vuid(vol.vid, unit.index, unit.epoch + 1)
+        node = self.nodes[self.cm.disks[dest].node_id]
+        node.drop_vuid(vuid)  # an aborted attempt's chunk: start it clean
+        node.create_vuid(vuid, dest)
+        work = [b for b in sorted(bids) if b not in tombstoned]
+        prep = {"vol": vol, "unit": unit, "dest": dest, "vuid": vuid,
+                "node": node, "bids": work, "carried": set()}
+        if not source_broken:
+            work = self._copy_direct(vol, unit, work, prep)
+        self._rebuild_rows(vol, t, unit, work, prep)
+        return prep
+
+    def _commit_unit(self, prep: dict, source_disk_id: int):
+        """Phase 3: re-home the unit in clustermgr — one step, after every
+        shard and every delete is in the new chunk, so the next read of the
+        unit finds it whole. The deleter notes a delete before it looks up the
+        units to punch, so one that raced the re-home and punched only the old
+        unit is seen by the second _carry_deletes."""
+        vol, unit = prep["vol"], prep["unit"]
+        with trace.stage("repair.commit"):
+            self._carry_deletes(prep)
+            if vol.units[unit.index].vuid != unit.vuid:
+                raise RuntimeError(
+                    f"unit {vol.vid}/{unit.index} was re-homed under its migrate")
+            self.cm.update_volume_unit(vol.vid, unit.index, prep["dest"])
+            self._carry_deletes(prep)
+            registry("scheduler").counter("rebuild_units_committed").add()
+            # the move must FREE the source: drop the superseded chunk (best
+            # effort — an unreachable/broken source just leaks until re-imaged)
+            old_node = self.nodes.get(unit.node_id)
+            if old_node is not None:
+                try:
+                    old_node.drop_vuid(unit.vuid)
+                except Exception:
+                    pass
 
     def _dest_for(self, vol: VolumeInfo, source_disk_id: int) -> int:
         vol_disks = {u.disk_id for u in vol.units}

@@ -345,7 +345,8 @@ def current_span() -> Span | None:
 # -- stages: profiler's clock + counter + request record -----------------------
 
 # <layer>.<what>; each name is used on one kind of thread only (HTTP worker,
-# access-pipe stage, access write worker, codec dispatcher, background tick),
+# access-pipe stage, access write worker, codec dispatcher, background tick,
+# repair worker and its stripe pool),
 # so a name also says which thread. The set is closed: it is the declared
 # value set of the counter's `stage` label (exporter._check_bounded).
 STAGES = frozenset((
@@ -357,6 +358,7 @@ STAGES = frozenset((
     "codec.concat", "codec.deliver",
     "hostbatch.group", "hostbatch.launch", "hostbatch.fetch",
     "scheduler.tick", "scheduler.scrub", "scheduler.inspect",
+    "repair.gather", "repair.decode_wait", "repair.write_back", "repair.commit",
 ))
 # per-shard steps, on the profiler's clock only (`mark`): six to sixteen of
 # each run per blob, and the background tick reads thousands of shards a

@@ -426,6 +426,7 @@ def run_kill_soak(root: str, seed: int, n_nodes: int = 9,
         t_kill = time.monotonic()
         t_detect = None
         rebuild_busy = 0.0  # wall time the worker actually spent rebuilding
+        resume_tries = 0  # iterations granted to the first PUT after the rebuild
         pending_live = [
             rng.integers(0, 256, rnd.choice(sizes), dtype=np.uint8).tobytes()
             for _ in range(live_puts)]
@@ -470,6 +471,15 @@ def run_kill_soak(root: str, seed: int, n_nodes: int = 9,
                         and not open_tasks
                         and c.proxy.topics[TOPIC_SHARD_REPAIR].lag(
                             "scheduler") == 0):
+                    # the PUT quorum comes back with the last re-homed unit:
+                    # where every PUT of the outage was rejected, the load
+                    # must be seen to resume before the window closes (a
+                    # broken disk's get_miss reports make no straggler tasks
+                    # any more, so the rebuild's last drain ends the window)
+                    if pending_live and not stats["live_puts"] \
+                            and resume_tries < 20:
+                        resume_tries += 1
+                        continue
                     break
                 time.sleep(0.05)  # let the heartbeat-silence clock advance
         finally:

@@ -2,7 +2,7 @@
 
 Reference counterpart: blobstore/cli (the interactive admin shell over
 clustermgr/scheduler/access APIs). Kept: the noun-verb command tree (stat,
-disk ls, vol ls/info, task ls, switch ls/set, reload) plus an interactive
+disk ls/set, vol ls/info, task ls, switch ls/set, reload) plus an interactive
 REPL when no command is given. Changed: one flat HTTP admin surface on the
 access gateway instead of per-service endpoints — the rebuilt blobstore
 composes its services into one daemon.
@@ -57,7 +57,10 @@ class BlobCli:
     def cmd_stat(self, *a) -> str:
         return json.dumps(self._get("/admin/stat"), indent=2)
 
-    def cmd_disk(self, verb: str = "ls", *a) -> str:
+    def cmd_disk(self, verb: str = "ls", disk_id: str = "", status: str = "", *a) -> str:
+        if verb == "set":  # the operator's declaration: only `broken` is accepted
+            return json.dumps(self._post(
+                f"/admin/disk/set?disk_id={int(disk_id)}&status={status}"))
         disks = self._get("/admin/disks")
         return self._table(disks, ["disk_id", "node_id", "az", "status",
                                    "chunk_count"])
@@ -95,7 +98,7 @@ class BlobCli:
         return json.dumps(self._post("/admin/reload"))
 
     def cmd_help(self, *a) -> str:
-        return ("commands: stat | disk ls | vol ls | vol info VID | task ls | "
+        return ("commands: stat | disk ls | disk set ID broken | vol ls | vol info VID | task ls | "
                 "switch ls | switch set NAME on|off | forgive | module ls | "
                 "reload | help | exit")
 

@@ -788,11 +788,18 @@ class BlobstoreDaemon(_Daemon):
         from chubaofs_tpu.blobstore import trace
 
         # under the runner lock, so a tick can never race a concurrent
-        # reload's teardown of the cluster it is sweeping
+        # reload's teardown of the cluster it is sweeping. Repair tasks run on
+        # the worker's own thread (background_tick hands them over): a disk
+        # rebuild never holds this lock
         with trace.stage("scheduler.tick"):
-            self.runner.call_with("cluster", lambda c: c.run_background_once())
+            self.runner.call_with("cluster", lambda c: c.background_tick())
 
     def stop(self):
+        # the worker first, outside the runner lock: a migrate in flight
+        # stops between two stripes and its task stays retryable
+        cluster = self.runner.handles.get("cluster")
+        if cluster is not None:
+            cluster.worker.close()
         super().stop()
         self.runner.stop()
 

@@ -78,6 +78,9 @@ class _Job:
     # as named stages, so a PUT's critical-path report splits encode wait
     span: object | None = None
     t_submit: float = 0.0  # perf_counter at _submit: codec.queue_wait starts
+    # result rows the caller asked for, where decode_rows padded the matrix
+    # past them to ride a resident program; None: all of them
+    rows: int | None = None
 
 
 def _pad_to_bucket(data: np.ndarray, k: int, kb: int) -> np.ndarray:
@@ -122,6 +125,9 @@ class CodecService:
         # get consistent snapshots (stats_snapshot).
         self.stats = {"batches": 0, "jobs": 0, "max_batch": 0}
         self._stats_lock = SanitizedLock(name="codec.stats")
+        # row counts decode_rows has run, by (survivors, bucket): the families
+        # of compiled decode programs this process holds
+        self._decode_rows_run: dict[tuple[int, int], set[int]] = {}
 
     def _ensure_started(self):
         with self._lock:
@@ -332,8 +338,23 @@ class CodecService:
                 f"want ({n}, w) survivors, got {survivors.shape}")
         k = survivors.shape[1]
         kb = bucket_len(k)
+        # a row count that has not run here rides the narrowest wider family
+        # that has (zero rows padded on, the result cut back) rather than
+        # compile its own under a request: a rebuild heals a stripe two rows
+        # short into one a row short under the readers, and their next GET
+        # must not stall ~2 s a batch count on the chip. Where nothing wider
+        # has run (one disk lost: every decode is one row) the count compiles
+        # once and runs exact from then on
+        rows = mat.shape[0]
+        ran = self._decode_rows_run.setdefault((n, kb), set())
+        if rows and rows not in ran:
+            wider = min((r for r in ran if r > rows), default=rows)
+            if wider > rows:
+                mat = np.concatenate([mat, np.zeros((wider - rows, n), np.uint8)])
+            else:
+                ran.add(rows)
         job = _Job("matmul", n, m, _pad_to_bucket(survivors, k, kb),
-                   k, kb, mat=mat)
+                   k, kb, mat=mat, rows=rows)
         self._submit(job)
         return job.future
 
@@ -496,7 +517,7 @@ class CodecService:
                     j.span.add_stage("codec.matmul", start=t_mm,
                                      dur=t_done - t_mm)
             for i, j in enumerate(jobs):
-                j.future.set_result(out[i, :, : j.k])
+                j.future.set_result(out[i, : j.rows, : j.k])
 
 
 _default: CodecService | None = None

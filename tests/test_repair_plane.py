@@ -501,9 +501,12 @@ def test_kill_blobnode_soak_smoke(tmp_path):
     # seed + layout are deterministic, so the victim (and with it the
     # rebuild width that makes overlap observable) is reproducible; the
     # sizes keep EC6P3/EC12P4 stripes in play so the windowed pipeline has
-    # real survivor downloads to hide behind the device decode
+    # real survivor downloads to hide behind the device decode; 16 warm PUTs
+    # give a unit more stripes than the gather window holds (a unit is
+    # re-homed the moment it is whole, so the overlap is the window's own:
+    # stripe k's decode against stripe k+4's download, not the next unit's)
     res = run_kill_soak(str(tmp_path), seed=7, n_nodes=9, disks_per_node=2,
-                        warm_puts=6, live_puts=3, hb_timeout=0.4,
+                        warm_puts=16, live_puts=3, hb_timeout=0.4,
                         wire_ms=2.0, read_deadline=0.4, write_deadline=2.5,
                         max_wait_s=90.0, sizes=[120_000, 700_000])
     assert res["ok"], res
