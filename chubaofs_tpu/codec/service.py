@@ -71,8 +71,12 @@ class _Job:
     k: int  # true shard length (result is sliced back to it)
     kb: int  # bucket_len(k), computed at submission
     future: Future = field(default_factory=Future)
-    # matmul jobs carry their GF matrix (repair rows x survivors)
-    mat: np.ndarray | None = None
+    # the job's matrix as the launch needs it (rs.MatrixPlan): its key groups
+    # the batch, its operand is resident on the device after the first batch
+    plan: rs.MatrixPlan | None = None
+    # the matrix came from a held damage pattern, or with the caller: the
+    # submitting thread computed nothing for it (cfs_codec_plan_total)
+    held: bool = True
     # the SUBMITTER's trace span (if any): the dispatcher attributes the
     # job's queue wait and its batch's stack/matmul intervals back onto it
     # as named stages, so a PUT's critical-path report splits encode wait
@@ -145,7 +149,8 @@ class CodecService:
             raise ValueError(f"want {n} data rows, got {data.shape}")
         k = data.shape[1]
         kb = bucket_len(k)
-        job = _Job("encode", n, m, _pad_to_bucket(data, k, kb), k, kb)
+        job = _Job("encode", n, m, _pad_to_bucket(data, k, kb), k, kb,
+                   plan=rs.get_kernel(n, m).parity_plan)
         self._submit(job)
         return job.future
 
@@ -155,7 +160,7 @@ class CodecService:
         paths ride: PM parity blocks, beta-repair decodes, and any-k
         fallback decodes are all just content-keyed matrices, so they batch
         on the device exactly like RS repairs."""
-        mat = np.ascontiguousarray(mat, np.uint8)
+        mat = np.asarray(mat, np.uint8)
         data = np.asarray(data, np.uint8)
         if data.ndim != 2 or mat.ndim != 2 or data.shape[0] != mat.shape[1]:
             raise ValueError(
@@ -163,7 +168,7 @@ class CodecService:
         k = data.shape[1]
         kb = bucket_len(k)
         job = _Job("matmul", data.shape[0], mat.shape[0],
-                   _pad_to_bucket(data, k, kb), k, kb, mat=mat)
+                   _pad_to_bucket(data, k, kb), k, kb, plan=rs.MatrixPlan(mat))
         self._submit(job)
         return job.future
 
@@ -190,7 +195,7 @@ class CodecService:
         k = data.shape[1]
         kb = bucket_len(k)
         job = _Job("matmul", t.N, t.M + t.L, _pad_to_bucket(data, k, kb),
-                   k, kb, mat=mat)
+                   k, kb, plan=rs.MatrixPlan(mat))
         self._submit(job)
         out = _ChainFuture(job.future)
 
@@ -291,7 +296,7 @@ class CodecService:
     ) -> Future:
         """shards (n+m, k) with garbage rows at bad_idx -> Future[repaired copy]."""
         kernel = rs.get_kernel(n, m)
-        mat, present, missing = kernel.repair_matrix(list(bad_idx), data_only)
+        (plan, present, missing), held = kernel.held_repair(bad_idx, data_only)
         if not missing:
             f: Future = Future()
             f.set_result(np.array(shards, copy=True))
@@ -300,7 +305,7 @@ class CodecService:
         kb = bucket_len(k)
         survivors = _pad_to_bucket(
             np.asarray(shards, np.uint8)[np.asarray(present)], k, kb)
-        job = _Job("matmul", n, m, survivors, k, kb, mat=mat)
+        job = _Job("matmul", n, m, survivors, k, kb, plan=plan, held=held)
         self._submit(job)
 
         out_future: Future = Future()
@@ -328,10 +333,10 @@ class CodecService:
         the wanted rows on the host (RSKernel.window_matrix), so the device
         pass is (len(want), n) @ (n, w) — window-sized both ways. Jobs with
         the identical (present, want) pattern batch on the device exactly
-        like repairs (content-keyed matrix signature).
+        like repairs (content-keyed matrix signature), and the pattern's
+        matrix is computed once (RSKernel.held_window).
         """
-        kernel = rs.get_kernel(n, m)
-        mat = kernel.window_matrix(present, want)
+        plan, held = rs.get_kernel(n, m).held_window(present, want)
         survivors = np.asarray(survivors, np.uint8)
         if survivors.ndim != 2 or survivors.shape[0] != n:
             raise ValueError(
@@ -345,16 +350,19 @@ class CodecService:
         # must not stall ~2 s a batch count on the chip. Where nothing wider
         # has run (one disk lost: every decode is one row) the count compiles
         # once and runs exact from then on
-        rows = mat.shape[0]
+        rows = plan.mat.shape[0]
         ran = self._decode_rows_run.setdefault((n, kb), set())
         if rows and rows not in ran:
             wider = min((r for r in ran if r > rows), default=rows)
             if wider > rows:
-                mat = np.concatenate([mat, np.zeros((wider - rows, n), np.uint8)])
+                # a padded COPY (the held matrix is never written), found by
+                # its content: its operand is as resident as any other
+                plan = rs.MatrixPlan(np.concatenate(
+                    [plan.mat, np.zeros((wider - rows, n), np.uint8)]))
             else:
                 ran.add(rows)
         job = _Job("matmul", n, m, _pad_to_bucket(survivors, k, kb),
-                   k, kb, mat=mat, rows=rows)
+                   k, kb, plan=plan, held=held, rows=rows)
         self._submit(job)
         return job.future
 
@@ -428,16 +436,12 @@ class CodecService:
             if not batch:
                 continue
             # group by compatible shape signature (kb was bucketed at
-            # submission; the drain loop never re-derives shapes)
+            # submission; the drain loop never re-derives shapes). The plan's
+            # key is the matrix's CONTENT, made once with the plan: only jobs
+            # with the identical matrix share a batch
             groups: dict[tuple, list[_Job]] = {}
             for j in batch:
-                if j.kind == "encode":
-                    sig = ("encode", j.n, j.m, j.kb)
-                else:
-                    # matrices are tiny (<= 36x36): key by CONTENT so only jobs
-                    # with the identical repair matrix share a batch
-                    sig = ("matmul", j.mat.tobytes(), j.data.shape[0], j.kb)
-                groups.setdefault(sig, []).append(j)
+                groups.setdefault((j.kind, j.plan.key, j.kb), []).append(j)
             for sig, jobs in groups.items():
                 try:
                     self._run_group(sig, jobs)
@@ -452,7 +456,7 @@ class CodecService:
             return dict(self.stats)
 
     def _record_batch(self, jobs: int, elapsed_s: float,
-                      kind: str = "") -> None:
+                      kind: str = "", plan_hits: int = 0) -> None:
         with self._stats_lock:
             self.stats["batches"] += 1
             self.stats["jobs"] += jobs
@@ -462,6 +466,11 @@ class CodecService:
         reg = registry("codec")
         reg.counter("batches_total").add()
         reg.counter("jobs_total").add(jobs)
+        # one a job: hit = its matrix, bits, group form and device operand
+        # were all found where they are held (RSKernel's patterns, rs's
+        # operands); a miss made at least one of them
+        reg.counter("plan_total", {"result": "hit"}).add(plan_hits)
+        reg.counter("plan_total", {"result": "miss"}).add(jobs - plan_hits)
         if kind:
             # the encode/matmul split: proves repair DECODE really batches
             # on the device (bench_repair and the kill soak read this)
@@ -479,32 +488,44 @@ class CodecService:
         for j in jobs:
             trace.observe_stage("codec.queue_wait", j.t_submit,
                                 t0 - j.t_submit, span=j.span)
-        # jobs arrive pre-padded to the bucket: stacking is the whole job here
+        # jobs arrive pre-padded to the bucket: stacking is the whole job
+        # here, and one job is launched where it lies (a view, no copy)
         with trace.stage("codec.stack"):
-            stack = np.stack([j.data for j in jobs])
+            stack = (jobs[0].data[None] if len(jobs) == 1
+                     else np.stack([j.data for j in jobs]))
         t_mm = time.perf_counter()
         # both paths go through the host-boundary grouped entry: batches of
         # stripes are viewed (free numpy reshape) as MXU-row-filling groups
         # before they ever reach the device (rs.gf_matmul_hostbatch, which
-        # records the hostbatch.* stages) — or, with a mesh, fan out
-        # dp/sp-sharded across every device
-        mm = self._mesh_mm or rs.gf_matmul_hostbatch
+        # records the hostbatch.* stages and launches with the plan's
+        # RESIDENT operand) — or, with a mesh, fan out dp/sp-sharded across
+        # every device from the plan's host bits, as before
+        plan = jobs[0].plan
+        mesh = self._mesh_mm
+        ready = plan.ready(len(jobs)) if mesh is None else plan.expanded
+
+        def mm():
+            if mesh is None:
+                return rs.gf_matmul_hostbatch(plan, stack)
+            return mesh(plan.bits(), stack)
+
         if sig[0] == "encode":
-            kernel = rs.get_kernel(jobs[0].n, jobs[0].m)
-            parity = mm(kernel.parity_bits, stack)
+            parity = mm()
             with trace.stage("codec.concat"):
                 out = np.concatenate([stack, parity], axis=1)  # (B, n+m, kb)
         else:
-            from chubaofs_tpu.ops import bitmatrix
-
-            # a dozen small numpy calls: microseconds of work, but each may
-            # hand the interpreter lock over, so it gets a name of its own
+            # the matrix's bits, where this launch has to make its operand
+            # from them: a dozen small numpy calls, each a chance to hand the
+            # interpreter lock over, so they have a name of their own;
+            # nothing on a hit
             with trace.stage("codec.expand"):
-                bits = bitmatrix.expand_matrix(jobs[0].mat).astype(np.int8)
-            out = mm(bits, stack)
+                if not ready:
+                    plan.bits()
+            out = mm()
         t_done = time.perf_counter()
         with trace.stage("codec.deliver"):  # bookkeeping, then the results
-            self._record_batch(len(jobs), t_done - t0, kind=str(sig[0]))
+            self._record_batch(len(jobs), t_done - t0, kind=str(sig[0]),
+                               plan_hits=sum(ready and j.held for j in jobs))
             for j in jobs:
                 if j.span is not None:
                     # the BATCH's wall intervals, attributed to every rider:

@@ -128,23 +128,36 @@ def gf_matmul_bytes_fused(
     rs.group_stack / pick_group): a (8gr, 8gn) block-diagonal matrix over
     (b/g, g*n, k) host-viewed stripes.
     """
-    r8, n8 = mat_bits.shape
+    if isinstance(mat_bits, np.ndarray):
+        # numpy at trace time: the device never sees the permutation
+        mat_pm = plane_major(mat_bits).astype(np.int8)
+    else:
+        rows, cols = (jnp.asarray(_perm(d // BITS), jnp.int32) for d in mat_bits.shape)
+        mat_pm = mat_bits[rows][:, cols]
+    return gf_matmul_planes(mat_pm, shards, tile_k, interpret)
+
+
+def gf_matmul_planes(
+    mat_pm: jax.Array,
+    shards: jax.Array,
+    tile_k: int | None = None,
+    interpret: bool = False,
+) -> jax.Array:
+    """gf_matmul_bytes_fused for a matrix ALREADY in the kernel's plane-major
+    order (plane_major): what rs.MatrixPlan keeps resident on the device, so a
+    launch neither permutes nor ships it. The one call site of the jitted core:
+    a numpy and a resident operand run the same compiled program."""
+    r8, n8 = mat_pm.shape
     r, n = r8 // BITS, n8 // BITS
     lead = shards.shape[:-2]
     k = shards.shape[-1]
-    assert shards.shape[-2] == n, (shards.shape, mat_bits.shape)
+    assert shards.shape[-2] == n, (shards.shape, mat_pm.shape)
     if r8 == 0 or k == 0:
         return jnp.zeros((*lead, r, k), jnp.uint8)
 
     b = 1
     for d in lead:
         b *= d
-
-    if isinstance(mat_bits, np.ndarray):
-        # numpy at trace time: the device never sees the permutation
-        mat_pm = plane_major(mat_bits).astype(np.int8)
-    else:
-        mat_pm = mat_bits[jnp.asarray(_perm(r))][:, jnp.asarray(_perm(n))]
 
     out = _fused_core(mat_pm, shards.reshape(b, n, k), tile_k=tile_k, interpret=interpret)
     return out.reshape(*lead, r, k)

@@ -29,6 +29,7 @@ import numpy as np
 from chubaofs_tpu import chaos
 from chubaofs_tpu.blobstore import trace
 from chubaofs_tpu.ops import bitmatrix, gf256
+from chubaofs_tpu.utils.locks import SanitizedLock
 
 BITS = 8
 
@@ -105,17 +106,106 @@ def group_stack(mat_bits: np.ndarray, batch: int) -> tuple[np.ndarray, int]:
     indivisible batches.
     """
     mat_bits = np.asarray(mat_bits, np.int8)
-    if not _use_fused() or mat_bits.shape[0] == 0:
-        return mat_bits, 1
-    from chubaofs_tpu.ops import pallas_gf
-
-    g = pallas_gf.pick_group(batch, *mat_bits.shape)
+    g = _group_count(mat_bits.shape, batch)
     if g == 1:
         return mat_bits, 1
     return np.kron(np.eye(g, dtype=np.int8), mat_bits), g
 
 
-def gf_matmul_hostbatch(mat_bits: np.ndarray, shards: np.ndarray) -> np.ndarray:
+def _group_count(bits_shape: tuple[int, int], batch: int) -> int:
+    if not _use_fused() or bits_shape[0] == 0:
+        return 1
+    from chubaofs_tpu.ops import pallas_gf
+
+    return pallas_gf.pick_group(batch, *bits_shape)
+
+
+class _Held:
+    """A bounded map: at most ``bound`` entries, the oldest out. A lookup is
+    one dict read and takes no lock; threads that miss the same key at once
+    each compute, the first put wins and every one of them gets THAT value."""
+
+    def __init__(self, bound: int):
+        self.bound = bound
+        self._d: dict = {}
+        self._lock = SanitizedLock(name="rs.held")
+
+    def get(self, key):
+        return self._d.get(key)
+
+    def put(self, key, value):
+        with self._lock:
+            value = self._d.setdefault(key, value)
+            while len(self._d) > self.bound:
+                del self._d[next(iter(self._d))]
+        return value
+
+    def __len__(self) -> int:
+        return len(self._d)
+
+
+class MatrixPlan:
+    """One GF(2^8) matrix as a launch needs it, each part made once: the
+    content key (what the codec service groups a batch by), an immutable copy
+    of the matrix, and its GF(2) bits on first need. The device operands made
+    from it live in ``_OPERANDS`` by (key, g), so a plan built afresh from an
+    equal matrix finds them without expanding anything."""
+
+    __slots__ = ("key", "mat", "shape", "_bits")
+
+    def __init__(self, mat: np.ndarray | None = None, bits: np.ndarray | None = None):
+        """From the matrix (``bits`` too where the caller has them expanded), or
+        from byte-major GF(2) bits alone (gf_matmul_hostbatch's numpy callers)."""
+        if mat is None:
+            bits = np.ascontiguousarray(bits, np.int8)
+            self.key, self.mat, self.shape = ("bits", bits.shape, bits.tobytes()), None, bits.shape
+        else:
+            mat = np.ascontiguousarray(mat, np.uint8)
+            raw = mat.tobytes()
+            self.key = ("gf", mat.shape, raw)
+            self.mat = np.frombuffer(raw, np.uint8).reshape(mat.shape)  # read-only
+            self.shape = (mat.shape[0] * BITS, mat.shape[1] * BITS)  # of the bits
+        self._bits = bits
+
+    def bits(self) -> np.ndarray:
+        """(8r, 8c) int8, byte-major (bitmatrix.expand_matrix's order): expanded
+        on the first call."""
+        if self._bits is None:
+            self._bits = bitmatrix.expand_matrix(self.mat).astype(np.int8)
+        return self._bits
+
+    @property
+    def expanded(self) -> bool:
+        return self._bits is not None
+
+    def ready(self, batch: int) -> bool:
+        """Is the operand a batch of this many stripes launches with resident?"""
+        return _OPERANDS.get((self.key, _group_count(self.shape, batch))) is not None
+
+    def operand(self, batch: int) -> tuple[jax.Array, int]:
+        """(the resident operand, g) for a batch of this many stripes: the
+        bits, kron(I_g, .) where the lowering group-stacks, the kernel's
+        plane-major order and the placement on the device, done on the first
+        batch that needs them."""
+        g = _group_count(self.shape, batch)
+        op = _OPERANDS.get((self.key, g))
+        if op is None:
+            form, _ = group_stack(self.bits(), batch)
+            if _use_fused():
+                from chubaofs_tpu.ops import pallas_gf
+
+                form = pallas_gf.plane_major(form)
+            op = _OPERANDS.put((self.key, g),
+                               jax.device_put(np.ascontiguousarray(form, np.int8)))
+        return op, g
+
+
+# (plan key, g) -> the matrix resident on the device in the lowering's layout:
+# at most 128 x 512 int8 an entry where g > 1 (pick_group's caps)
+_OPERANDS = _Held(512)
+
+
+def gf_matmul_hostbatch(mat_bits: np.ndarray | MatrixPlan, shards: np.ndarray) -> np.ndarray:
     """Host-boundary batched GF matmul with MXU group-stacking.
 
     shards: host (..., n, k) uint8 -> host (..., r, k). The group view
@@ -124,23 +214,32 @@ def gf_matmul_hostbatch(mat_bits: np.ndarray, shards: np.ndarray) -> np.ndarray:
     131 -> 53 GB/s), which is why stacking lives at the host boundary — where
     this storage system's stripes originate anyway (network buffers, chunk
     files). This is the batch entry the codec service and repair planes use.
-    Three stages split its wall time: hostbatch.group (the kron stack),
-    hostbatch.launch (H2D + enqueue; returns a device array) and
-    hostbatch.fetch (the wait for the kernel + D2H).
+    mat_bits is byte-major GF(2) bits, or the MatrixPlan a caller already
+    holds (the codec service: nothing is serialised to find the operand).
+    Three stages split its wall time: hostbatch.group (the resident operand
+    looked up; made on the first batch of a matrix at a group count),
+    hostbatch.launch (H2D of the shards + enqueue; returns a device array)
+    and hostbatch.fetch (the wait for the kernel + D2H).
     """
     shards = np.asarray(shards, np.uint8)
-    mat_bits = np.asarray(mat_bits, np.int8)
+    plan = mat_bits if isinstance(mat_bits, MatrixPlan) else MatrixPlan(bits=mat_bits)
     lead, n, k = shards.shape[:-2], shards.shape[-2], shards.shape[-1]
-    r = mat_bits.shape[0] // BITS
+    r = plan.shape[0] // BITS
     b = 1
     for d in lead:
         b *= d
     if b == 0 or r == 0 or k == 0:
         return np.zeros((*lead, r, k), np.uint8)
     with trace.stage("hostbatch.group"):
-        mat_s, g = group_stack(mat_bits, b)
+        operand, g = plan.operand(b)
     with trace.stage("hostbatch.launch"):
-        out = gf_matmul_dispatch(mat_s, shards.reshape(b // g, g * n, k))
+        grouped = shards.reshape(b // g, g * n, k)
+        if _use_fused():
+            from chubaofs_tpu.ops import pallas_gf
+
+            out = pallas_gf.gf_matmul_planes(operand, grouped)
+        else:
+            out = gf_matmul_bytes(operand, grouped)
     with trace.stage("hostbatch.fetch"):
         return np.asarray(out).reshape(*lead, r, k)
 
@@ -173,6 +272,11 @@ class RSKernel:
         # default backend); inside jit a numpy constant is embedded and placed
         # by XLA wherever the computation runs.
         self.parity_bits = bitmatrix.expand_matrix(self.gen[n:, :]).astype(np.int8)
+        self.parity_plan = MatrixPlan(self.gen[n:, :], self.parity_bits)
+        # damage pattern -> its matrix: a pure function of the pattern for
+        # this (n, m), so the GF inverse runs once a pattern, not once a blob
+        self._window = _Held(256)
+        self._repair = _Held(256)
 
     # -- encode ------------------------------------------------------------
     #
@@ -202,9 +306,20 @@ class RSKernel:
         """Host-side: (matrix mapping survivors->missing, survivor rows, missing rows).
 
         survivor rows are the first n present indices; matrix is GF(2^8) of shape
-        (len(missing), n), already verified invertible via decode_matrix.
+        (len(missing), n), already verified invertible via decode_matrix. The
+        matrix is the held one, read-only: pad or edit a copy.
         """
-        bad = sorted(set(int(i) for i in bad_idx))
+        (plan, present, missing), _ = self.held_repair(bad_idx, data_only)
+        return plan.mat, list(present), list(missing)
+
+    def held_repair(self, bad_idx: list[int], data_only: bool = False):
+        """((MatrixPlan, survivor rows, missing rows), was it held) for
+        repair_matrix's arguments: computed once a pattern."""
+        key = (tuple(sorted(set(int(i) for i in bad_idx))), bool(data_only))
+        held = self._repair.get(key)
+        if held is not None:
+            return held, True
+        bad = list(key[0])
         for i in bad:
             if not 0 <= i < self.total:
                 raise ValueError(f"bad shard index {i}")
@@ -214,7 +329,7 @@ class RSKernel:
         dec = gf256.decode_matrix(self.gen, present)  # (n, n)
         missing = [i for i in bad if i < self.n] if data_only else bad
         mat = gf256.gf_matmul(self.gen[np.asarray(missing), :], dec) if missing else np.zeros((0, self.n), np.uint8)
-        return mat, present, missing
+        return self._repair.put(key, (MatrixPlan(mat), tuple(present), tuple(missing))), False
 
     def window_matrix(self, present: list[int], want: list[int]) -> np.ndarray:
         """Row-sliced decode matrix for ranged reads: the GF(2^8) map from
@@ -226,10 +341,19 @@ class RSKernel:
         fetch) and computes only the rows the byte window needs, so degraded
         decode cost scales with the window, not the stripe. RS is column-
         independent, so the same matrix applied to column-sliced survivors
-        yields the identical column slice of the wanted shards.
+        yields the identical column slice of the wanted shards. The matrix
+        is the held one, read-only: pad or edit a copy.
         """
-        present = [int(i) for i in present]
-        want = [int(i) for i in want]
+        return self.held_window(present, want)[0].mat
+
+    def held_window(self, present: list[int], want: list[int]) -> tuple[MatrixPlan, bool]:
+        """(window_matrix's matrix as a MatrixPlan, was it held): the inverse
+        and the product run once a (present, want) pattern."""
+        key = (tuple(int(i) for i in present), tuple(int(i) for i in want))
+        plan = self._window.get(key)
+        if plan is not None:
+            return plan, True
+        present, want = key
         if len(present) != self.n:
             raise ValueError(
                 f"window decode needs exactly n={self.n} survivors, "
@@ -238,9 +362,11 @@ class RSKernel:
             if not 0 <= i < self.total:
                 raise ValueError(f"bad shard index {i}")
         if not want:
-            return np.zeros((0, self.n), np.uint8)
-        dec = gf256.decode_matrix(self.gen, present)  # (n, n)
-        return gf256.gf_matmul(self.gen[np.asarray(want), :], dec)
+            mat = np.zeros((0, self.n), np.uint8)
+        else:
+            dec = gf256.decode_matrix(self.gen, list(present))  # (n, n)
+            mat = gf256.gf_matmul(self.gen[np.asarray(want), :], dec)
+        return self._window.put(key, MatrixPlan(mat)), False
 
     def repair_plan(self, bad_idx: list[int], data_only: bool = False):
         """Device-ready repair plan: (repair_bits, present, missing) numpy arrays.
@@ -249,8 +375,8 @@ class RSKernel:
         bit-matrix repair lowering lives in exactly one place. Kept as numpy so
         closing over a plan inside jit never commits to the default device.
         """
-        mat, present, missing = self.repair_matrix(bad_idx, data_only)
-        return self._device_plan(mat, present, missing)
+        (plan, present, missing), _ = self.held_repair(bad_idx, data_only)
+        return plan.bits(), np.asarray(present, np.int32), np.asarray(missing, np.int32)
 
     @staticmethod
     def _device_plan(mat, present, missing):

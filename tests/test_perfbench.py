@@ -78,7 +78,11 @@ def test_repair_codes_bench_smoke_floor(tmp_path):
     floors stay in PERF.md — CI co-tenant noise."""
     from chubaofs_tpu.tools.perfbench import bench_repair_codes
 
-    out = bench_repair_codes(str(tmp_path), stripes=4, blob_kb=60,
+    # eight stripes through a window of four: the later stripes' downloads run
+    # while the earlier ones decode, so the overlap asserted below is the
+    # pipeline's (at four every download is in flight at once, and whether a
+    # decode met one was the luck of a decode slow enough to)
+    out = bench_repair_codes(str(tmp_path), stripes=8, blob_kb=60,
                              wire_ms=2.0, window=4)
     assert out["repair_codes_rows_rg"] > 0, out
     assert out["repair_codes_rows_rs"] == out["repair_codes_rows_rg"], out

@@ -280,3 +280,152 @@ def test_dispatcher_stages_sum_to_dispatch_seconds():
     whole = b["whole"] - a["whole"]
     split = sum(b[p] - a[p] for p in parts)
     assert abs(split - whole) <= 0.05 * whole, (split, whole)
+
+
+# -- a batch of one job is launched where it lies (ISSUE 37) --------------------
+
+
+def _one_and_two(submit):
+    """The result of ``submit(svc)`` alone in its batch, and twice in a batch of two."""
+    from chubaofs_tpu.codec.service import CodecService
+
+    out = []
+    for count in (1, 2):
+        svc = CodecService(max_batch=count, max_wait_ms=5000.0)
+        try:
+            before = svc.stats_snapshot()
+            futures = [submit(svc) for _ in range(count)]
+            out.append([np.array(f.result(60)) for f in futures])
+            after = svc.stats_snapshot()
+        finally:
+            svc.close()
+        assert (after["batches"] - before["batches"], after["jobs"] - before["jobs"]) == (1, count)
+    return out
+
+
+def _jobs():
+    from chubaofs_tpu.codec.codemode import get_tactic
+
+    rng = np.random.default_rng(37)
+    exact = rng.integers(0, 256, (12, 16 * 1024), dtype=np.uint8)  # the bucket itself
+    short = rng.integers(0, 256, (12, 5000), dtype=np.uint8)  # padded by the submitter
+    lrc = rng.integers(0, 256, (6, 16 * 1024), dtype=np.uint8)
+    present = [2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12, 13]
+    return {
+        "encode": lambda svc: svc.encode(12, 4, exact),
+        "encode-padded": lambda svc: svc.encode(12, 4, short),
+        "decode_rows": lambda svc: svc.decode_rows(12, 4, present, exact, [0, 1]),
+        "decode_rows-padded": lambda svc: svc.decode_rows(12, 4, present, short, [0, 1]),
+        "encode_tactic-lrc": lambda svc: svc.encode_tactic(get_tactic("EC6P3L3"), lrc),
+        "reconstruct": lambda svc: svc.reconstruct(12, 4, np.concatenate([exact, exact[:4]]), [0, 13]),
+    }
+
+
+@pytest.mark.parametrize("name", sorted(_jobs()))
+def test_one_job_batch_gives_the_bytes_of_a_batch_of_two(name):
+    (alone,), (first, second) = _one_and_two(_jobs()[name])
+    assert alone.dtype == np.uint8 and alone.shape == first.shape
+    assert np.array_equal(alone, first) and np.array_equal(alone, second)
+
+
+@pytest.mark.parametrize("kind", ["encode", "decode_rows"])
+def test_input_may_be_overwritten_once_the_future_is_done(kind):
+    """The one-job view reads the caller's rows during the launch, inside the
+    call: a result already delivered does not change when they do."""
+    from chubaofs_tpu.codec.service import CodecService
+    from chubaofs_tpu.ops import gf256, rs
+
+    rng = np.random.default_rng(5)
+    data = rng.integers(0, 256, (12, 16 * 1024), dtype=np.uint8)
+    kernel = rs.get_kernel(12, 4)
+    present = [1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12]
+    svc = CodecService(max_batch=1)
+    try:
+        for _ in range(3):
+            keep = data.copy()
+            if kind == "encode":
+                got = svc.encode(12, 4, data).result(60)
+                expect = np.concatenate([keep, gf256.gf_matmul(kernel.gen[12:], keep)])
+            else:
+                got = svc.decode_rows(12, 4, present, data, [0]).result(60)
+                expect = gf256.gf_matmul(kernel.window_matrix(present, [0]), keep)
+            data[:] = rng.integers(0, 256, data.shape, dtype=np.uint8)
+            assert np.array_equal(got, expect)
+    finally:
+        svc.close()
+
+
+def test_cancelled_one_job_batch_does_no_device_work(monkeypatch):
+    from chubaofs_tpu.codec.service import CodecService
+    from chubaofs_tpu.ops import rs
+
+    import threading
+
+    sound, calls, entered, release = rs.gf_matmul_hostbatch, [], threading.Event(), threading.Event()
+
+    def counting(mat_bits, shards):
+        calls.append(shards.shape)
+        entered.set()
+        release.wait(30)
+        return sound(mat_bits, shards)
+
+    monkeypatch.setattr(rs, "gf_matmul_hostbatch", counting)
+    data = np.random.default_rng(6).integers(0, 256, (12, 16 * 1024), dtype=np.uint8)
+    svc = CodecService(max_batch=1)
+    try:
+        first = svc.encode(12, 4, data)
+        assert entered.wait(30)  # the dispatcher is inside the first batch
+        dropped = svc.decode_rows(12, 4, list(range(1, 13)), data, [0])
+        assert dropped.cancel()
+        last = svc.encode(12, 4, data)
+        release.set()
+        assert first.result(60).shape == last.result(60).shape == (16, 16 * 1024)
+    finally:
+        release.set()
+        svc.close()
+    assert dropped.cancelled() and calls == [(1, 12, 16 * 1024)] * 2
+
+
+@pytest.mark.parametrize("kind,expect", [
+    ("encode", ["codec.stack", "hostbatch.group", "hostbatch.launch", "hostbatch.fetch",
+                "codec.concat", "codec.deliver"]),
+    ("decode_rows", ["codec.stack", "codec.expand", "hostbatch.group", "hostbatch.launch",
+                     "hostbatch.fetch", "codec.deliver"]),
+])
+def test_dispatcher_stage_list_and_order_are_the_same_on_a_hit(monkeypatch, kind, expect):
+    """A miss (the first batch of a matrix) and a hit enter the same stages in
+    the same order, once a batch each: the per-job metrics keep their meaning."""
+    from chubaofs_tpu.codec.service import CodecService
+    from chubaofs_tpu.utils.exporter import registry
+
+    entered: list[str] = []
+    sound = trace.stage
+
+    def recording(name, *a, **kw):
+        if name != "codec.drain":
+            entered.append(name)
+        return sound(name, *a, **kw)
+
+    monkeypatch.setattr(trace, "stage", recording)
+    data = np.random.default_rng(7).integers(0, 256, (12, 32 * 1024), dtype=np.uint8)
+    # a pattern no other test of this process uses: the first batch is a miss
+    present = [0, 1, 2, 4, 5, 6, 7, 8, 10, 11, 14, 15]
+    reg = registry("codec")
+    hit, miss = (reg.counter("plan_total", {"result": r}) for r in ("hit", "miss"))
+    svc = CodecService(max_batch=1)
+    try:
+        seen = []
+        for _ in range(3):
+            del entered[:]
+            before = (hit.value, miss.value)
+            if kind == "encode":
+                svc.encode(12, 4, data).result(60)
+            else:
+                svc.decode_rows(12, 4, present, data, [3, 9]).result(60)
+            seen.append((list(entered), hit.value - before[0], miss.value - before[1]))
+    finally:
+        svc.close()
+    assert [s[0] for s in seen] == [expect] * 3
+    if kind == "decode_rows":
+        assert [s[1:] for s in seen] == [(0, 1), (1, 0), (1, 0)]
+    assert seen[-1][1:] == (1, 0)
