@@ -35,6 +35,15 @@ the per-shard steps whose always-on cost would show (MARKS; ~1 us with no
 session, against ~3 for a stage). STAGES is the closed set of counted names.
 This module never imports jax: a process that has not imported it has no
 profiler to share a clock with.
+
+Wall time says how long a stage LASTED; two readings of the kernel's
+per-thread CPU clock say how long its thread RAN. Under a profiler session,
+and at no other time, a `stage` also adds its thread's CPU seconds to
+cfs_trace_stage_cpu_seconds{stage=<name>} (the series stands still outside a
+session; `observe_stage` and `mark` have no thread to read). And whenever
+/metrics is rendered, `collect_cpu` reads every live thread's CPU clock and
+sums it by thread ROLE into cfs_proc_cpu_seconds{role=<role>}: who the
+process's cores were spent on, at no cost between two scrapes.
 """
 
 from __future__ import annotations
@@ -43,6 +52,8 @@ import sys
 import threading
 import time
 import uuid
+
+from chubaofs_tpu.utils import exporter
 
 TRACE_ID_KEY = "Trace-Id"
 TRACK_LOG_KEY = "Trace-Tracklog"
@@ -139,7 +150,6 @@ class Span:
         # stay on the monotonic clock
         self.start_wall = time.time()
         self.tags: dict[str, object] = {}
-        self.logs: list[tuple[float, str]] = []
         self.track: list[str] = []  # track-log entries, e.g. "blobnode:12"
         self.track_dropped = 0  # entries the TRACK_MAX cap swallowed
         # named in-span attributions: (name, offset_s from start, dur_s)
@@ -168,9 +178,6 @@ class Span:
     def set_tag(self, k: str, v) -> "Span":
         self.tags[k] = v
         return self
-
-    def log(self, msg: str):
-        self.logs.append((time.perf_counter() - self.start, msg))
 
     def _push_track(self, entry: str):
         if len(self.track) >= TRACK_MAX:
@@ -374,8 +381,6 @@ _stage_summaries: dict[str, object] = {}
 
 
 def _stage_summary(name: str):
-    from chubaofs_tpu.utils import exporter
-
     exporter.declare_label_values("stage", STAGES)
     s = _stage_summaries[name] = exporter.registry("trace").summary(
         "stage_seconds", {"stage": name})
@@ -392,16 +397,29 @@ def observe_stage(name: str, start: float, dur: float,
         span.add_stage(name, start, dur)
 
 
-def _annotate(name: str, span: Span | None):
+_stage_cpu_counters: dict[str, object] = {}
+
+
+def _stage_cpu_counter(name: str):
+    exporter.declare_label_values("stage", STAGES)
+    c = _stage_cpu_counters[name] = exporter.registry("trace").counter(
+        "stage_cpu_seconds", {"stage": name})
+    return c
+
+
+def _annotate(name: str, span: Span | None, stage: "stage | None" = None):
     """The entered profiler annotation of a stage, or None with no session
     on (or no jax in this process); req= joins one request's stages across
-    threads."""
+    threads. Under a session a `stage` is also handed its thread's CPU
+    clock as the block begins (a `mark` asks for none)."""
     prof = sys.modules.get("jax.profiler")
     if prof is None or not prof.TraceAnnotation.is_enabled():
         return None
     kw = {"req": span.trace_id} if span is not None else {}
     ann = prof.TraceAnnotation("cfs:" + name, **kw)
     ann.__enter__()
+    if stage is not None:
+        stage._cpu = time.thread_time()
     return ann
 
 
@@ -429,7 +447,7 @@ class stage:
     of a track-log entry the same interval appends to it when the block
     did not raise (stream_put.go's per-hop `module:ms`)."""
 
-    __slots__ = ("name", "track", "span", "start", "_ann")
+    __slots__ = ("name", "track", "span", "start", "_ann", "_cpu")
 
     def __init__(self, name: str, track: str | None = None):
         self.name = name
@@ -437,16 +455,97 @@ class stage:
 
     def __enter__(self) -> "stage":
         self.span = span = current_span()
-        self._ann = _annotate(self.name, span)
+        self._ann = _annotate(self.name, span, self)
         self.start = time.perf_counter()
         return self
 
     def __exit__(self, et, ev, tb):
         dur = time.perf_counter() - self.start
         if self._ann is not None:
+            ran = time.thread_time() - self._cpu
             self._ann.__exit__(et, ev, tb)
+            (_stage_cpu_counters.get(self.name) or _stage_cpu_counter(self.name)).add(ran)
         span = self.span
         observe_stage(self.name, self.start, dur, span)
         if span is not None and self.track is not None and et is None:
             span.append_track_log(self.track, start=self.start)
         return False
+
+
+# -- CPU by thread role: who ran, read where /metrics is rendered -----------------
+
+# The closed value set of cfs_proc_cpu_seconds' `role` label. The first six
+# are the daemon's hot threads by the names the code gives them; `other` is
+# every other Python thread (in a benchmark cell the harness's main thread,
+# a reload, the sampling profiler's); `native` is the process's CPU clock
+# minus all of those: threads Python does not own (PJRT and the TPU runtime,
+# the jax profiler's collectors).
+ROLES = ("loop", "request", "io", "codec", "tick", "repair", "other", "native")
+# thread-name prefix -> role, first match: the one mapping the counter's label
+# and the sampling profiler's role totals (utils/profiler.py) both read
+_ROLE_OF_PREFIX = (
+    ("evloop-", "loop"),  # acceptor and loop shards: receive, reply flush
+    ("evw-", "request"),  # HTTP workers: gateway.handle, access.put / get
+    ("access-pipe", "request"),  # the blob stages of every request
+    ("access-read", "io"), ("access-probe", "io"),
+    ("access_", "io"),  # the shard write pool
+    ("codec-svc", "codec"),  # the one dispatcher
+    ("blobstore-bg", "tick"),
+    ("repair-", "repair"),  # repair-worker, repair-stripe*, repair-io*
+)
+
+
+def thread_role(name: str) -> str:
+    """The role of a thread by its name (or by its profiler bucket: a
+    prefix holds no digit run); `other` where the name is nobody's."""
+    for prefix, role in _ROLE_OF_PREFIX:
+        if name.startswith(prefix):
+            return role
+    return "other"
+
+
+_cpu_lock = threading.Lock()
+_cpu_last: dict = {}  # live Thread -> (role, CPU seconds at the last scrape)
+_cpu_retired = dict.fromkeys(ROLES[:-1], 0.0)  # of threads that have ended
+_cpu_series: dict[str, object] = {}
+
+
+def collect_cpu() -> None:
+    """Refresh cfs_proc_cpu_seconds{role}: every live Python thread's CPU
+    clock, summed by role, and the process's clock less their sum as
+    `native`. Run by exporter.render_all() and by nothing else; monotone (a
+    thread that ended keeps its last reading in its role's retired sum; what
+    it ran after that reading shows as `native`). A platform without
+    per-thread CPU clocks renders no such series."""
+    clock_of = getattr(time, "pthread_getcpuclockid", None)
+    if clock_of is None:
+        return
+    with _cpu_lock:
+        if not _cpu_series:
+            reg = exporter.registry("proc")
+            _cpu_series.update((r, reg.counter("cpu_seconds", {"role": r})) for r in ROLES)
+        live = {}
+        for t in threading.enumerate():
+            try:
+                live[t] = (thread_role(t.name), time.clock_gettime(clock_of(t.ident)))
+            except (OSError, TypeError):
+                continue  # it ended between the two calls: its last reading retires below
+        total = time.process_time()
+        retired = dict(_cpu_retired)
+        for t, (role, ran) in _cpu_last.items():
+            if t not in live:
+                retired[role] += ran
+        by_role = dict(retired)
+        for role, ran in live.values():
+            by_role[role] += ran  # KeyError: the mapping names a role ROLES does not
+        by_role["native"] = total - sum(by_role.values())
+        _cpu_retired.update(retired)
+        _cpu_last.clear()
+        _cpu_last.update(live)
+        for role, ran in by_role.items():
+            series = _cpu_series[role]
+            if ran > series.value:
+                series.add(ran - series.value)
+
+
+exporter.add_collector(collect_cpu)

@@ -303,9 +303,22 @@ def registry(module: str) -> Registry:
         return r
 
 
+# series that are READ rather than counted (a thread's CPU clock) are
+# refreshed by a collector just before a scrape renders them
+_collectors: list = []
+
+
+def add_collector(fn) -> None:
+    """Have render_all() call `fn()` before it renders. `fn` must not raise."""
+    if fn not in _collectors:
+        _collectors.append(fn)
+
+
 def render_all() -> str:
     """Every registry in the process: the default one plus each module's —
     what a daemon's /metrics endpoint serves."""
+    for collect in list(_collectors):
+        collect()
     with _reg_lock:
         regs = [_default] + [_registries[m] for m in sorted(_registries)]
     return "".join(r.render() for r in regs)

@@ -1,12 +1,17 @@
-"""Sampling wall-clock profiler — stack-based "where does daemon CPU go".
+"""Sampling wall-clock profiler — stack-based "where do a daemon's threads STAND".
 
 The third leg of the observability plane: metrics say *how much*, traces say
 *which request*, but neither says where a daemon's threads actually SPEND
 wall time (cfs-trace flamegraphs are span-based — they only see what was
 instrumented). This is the pprof-style answer: a timer thread samples
-`sys._current_frames()` at `CFS_PROF_HZ` and aggregates whole stacks, so the
-ROADMAP item-4 question — "is PUT bottlenecked on Python glue or device
-encode?" — reads off a profile instead of being guessed.
+`sys._current_frames()` at `CFS_PROF_HZ` on WALL time and aggregates whole
+stacks, so a thread parked in a wait is sampled as often as one that runs.
+It does not say who RUNS: a thread waiting for the interpreter lock and the
+one holding it both show the frame they stand in. For that read the kernel's
+per-thread CPU clocks, which cost the process nothing between two scrapes:
+`cfs_proc_cpu_seconds{role}` (blobstore/trace.py `collect_cpu`) by the same
+thread roles this profile totals, and `cfs_trace_stage_cpu_seconds{stage}`
+under a jax profiler session.
 
 Discipline (mirrors utils/locks.py's sanitizer):
 
@@ -18,20 +23,23 @@ Discipline (mirrors utils/locks.py's sanitizer):
     rolling aggregate; `/debug/prof` (rpc/server.py mounts it next to
     /metrics) serves it. With `?seconds=N` the endpoint runs a fresh scoped
     capture instead — on-demand profiling works on ANY daemon, armed or
-    not, because the cost is explicit and bounded by the request.
+    not, because the cost is explicit and bounded by the request. Every
+    sweep takes the interpreter lock, so an armed profiler slows what it
+    watches (PERF.md, PR 36 call r7).
 
 Aggregation is per THREAD-NAME bucket (digit runs collapsed, so
 `evloop-pkt-0`/`evloop-pkt-1` fold into one `evloop-pkt-N` bucket while
 staying distinct from `codec-svc`, `raft-tick`, `access-pipe_N`, ...): the
-repo names every hot thread, which makes "which subsystem burns the CPU"
-the profile's FIRST axis, before any stack is read. Output is collapsed-
-stack text (`bucket;frame;frame count` — the flamegraph.pl/speedscope
-format `cfs-trace --flame` also emits), root frame first.
+repo names every hot thread, which makes "which subsystem's threads stand
+where" the profile's FIRST axis, before any stack is read; `roles` folds the
+buckets once more by `trace.thread_role`, the mapping the CPU counter's
+label uses. Output is collapsed-stack text (`bucket;frame;frame count` — the
+flamegraph.pl/speedscope format `cfs-trace --flame` also emits), root frame
+first.
 
 Sampling bias note: `sys._current_frames()` needs the GIL, so samples land
 at bytecode boundaries — C-extension/IO waits attribute to the Python frame
-that entered them, which is exactly the "glue vs device dispatch" split the
-codec roofline work needs.
+that entered them.
 """
 
 from __future__ import annotations
@@ -41,6 +49,8 @@ import re
 import sys
 import threading
 import time
+
+from chubaofs_tpu.blobstore.trace import thread_role
 
 _ENV = "CFS_PROF_HZ"
 
@@ -123,6 +133,16 @@ class Profile:
                 out[bucket] = out.get(bucket, 0) + n
             return dict(sorted(out.items(), key=lambda kv: -kv[1]))
 
+    def role_totals(self) -> dict[str, int]:
+        """Samples by thread role: the buckets folded by the mapping that
+        labels cfs_proc_cpu_seconds{role}, so the two speak of the same
+        threads (this one of where they stood, that one of how long they ran)."""
+        out: dict[str, int] = {}
+        for bucket, n in self.thread_totals().items():
+            role = thread_role(bucket)
+            out[role] = out.get(role, 0) + n
+        return out
+
     def collapsed(self) -> str:
         """Collapsed-stack lines, root frame first — what flamegraph.pl /
         speedscope ingest, and the same shape `cfs-trace --flame` emits for
@@ -151,6 +171,7 @@ class Profile:
             "coverage": round(attributed / samples, 4) if samples else 0.0,
             "stacks": stacks,
             "threads": self.thread_totals(),
+            "roles": self.role_totals(),
             "collapsed": self.collapsed(),
         }
 
