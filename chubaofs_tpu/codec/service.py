@@ -18,9 +18,14 @@ service is the TPU-native replacement:
     nothing falls back. cfs_codec_lowering_jobs_total{lowering=...} says which
     one did the math.
 
-Batching trades a bounded latency (max_wait_ms) for throughput, exactly like the
-reference's proxy-side volume-allocation batching — but for math instead of
-metadata.
+Batching is group commit, like the reference's proxy-side volume-allocation
+batching but for math instead of metadata: the one dispatcher starts the moment
+it has a job and takes with it everything that queued while the last batch ran,
+so batches grow with load by themselves and an idle service adds no wait. It
+never sleeps on a timer with a job in hand, unless a caller sets a hold
+(`max_wait` > 0: wait up to that long for `max_batch` jobs), the instrument of
+warm-ups and tests that need a batch of an exact count.
+cfs_codec_batch_close_total{close="empty"|"full"|"held"} says how each batch closed.
 """
 
 from __future__ import annotations
@@ -35,6 +40,7 @@ import numpy as np
 
 from chubaofs_tpu.blobstore import trace
 from chubaofs_tpu.ops import rs
+from chubaofs_tpu.utils.exporter import BATCH_BUCKETS, registry
 from chubaofs_tpu.utils.locks import SanitizedLock
 
 MIN_BUCKET = 16 * 1024
@@ -101,7 +107,7 @@ def _pad_to_bucket(data: np.ndarray, k: int, kb: int) -> np.ndarray:
 class CodecService:
     """Queue -> padded device batches -> futures. Thread-safe, one device stream."""
 
-    def __init__(self, max_batch: int = 32, max_wait_ms: float = 2.0,
+    def __init__(self, max_batch: int = 32, max_wait_ms: float = 0.0,
                  mesh=None, mesh_interpret: bool = False):
         """mesh: optional jax.sharding.Mesh (dp, sp) — drained batches then
         run through parallel.mesh.sharded_gf_matmul instead of the single-
@@ -394,21 +400,34 @@ class CodecService:
         if first is None:
             raise StopIteration
         batch = [first]
-        deadline = self.max_wait
-        # the max_wait hold, from the first job taken to the batch closing
-        # (the empty poll above is not the dispatcher's work)
+        held = False
+        # from the first job taken to the batch closing (the empty poll above
+        # is not the dispatcher's work): sweep what is ALREADY queued and
+        # launch. Only a caller's hold (max_wait > 0) is ever slept on
         with trace.stage("codec.drain"):
-            t0 = time.monotonic()
+            deadline = time.monotonic() + self.max_wait
             while len(batch) < self.max_batch:
-                remaining = deadline - (time.monotonic() - t0)
                 try:
-                    job = self._q.get(timeout=max(0.0, remaining))
+                    job = self._q.get_nowait()
                 except queue.Empty:
-                    break
+                    remaining = deadline - time.monotonic()
+                    if remaining <= 0:
+                        break
+                    held = True
+                    try:
+                        job = self._q.get(timeout=remaining)
+                    except queue.Empty:
+                        break
                 if job is None:
                     self._q.put(None)  # re-post sentinel for the outer loop
                     break
                 batch.append(job)
+        # how the batch closed, one a batch: "held" = a hold was waited on,
+        # "full" = max_batch reached by the sweep alone, "empty" = the sweep
+        # ran the queue dry and the batch was launched at once
+        close = ("held" if held
+                 else "full" if len(batch) >= self.max_batch else "empty")
+        registry("codec").counter("batch_close_total", {"close": close}).add()
         return batch
 
     def _run(self):
@@ -461,8 +480,6 @@ class CodecService:
             self.stats["batches"] += 1
             self.stats["jobs"] += jobs
             self.stats["max_batch"] = max(self.stats["max_batch"], jobs)
-        from chubaofs_tpu.utils.exporter import BATCH_BUCKETS, registry
-
         reg = registry("codec")
         reg.counter("batches_total").add()
         reg.counter("jobs_total").add(jobs)

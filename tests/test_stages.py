@@ -429,3 +429,264 @@ def test_dispatcher_stage_list_and_order_are_the_same_on_a_hit(monkeypatch, kind
     if kind == "decode_rows":
         assert [s[1:] for s in seen] == [(0, 1), (1, 0), (1, 0)]
     assert seen[-1][1:] == (1, 0)
+
+
+# -- the drain is work-conserving: no timer with a job in hand (ISSUE 40) --------
+
+
+def _closes() -> dict[str, float]:
+    return {c: exporter.registry("codec").counter("batch_close_total", {"close": c}).value
+            for c in ("empty", "full", "held")}
+
+
+def _drain_reading() -> dict[str, float]:
+    m = scrape()
+    out = {k: m.get('cfs_trace_stage_seconds_%s{stage="codec.drain"}' % k, 0.0)
+           for k in ("sum", "count")}
+    out.update(_closes())
+    return out
+
+
+def _grew(before: dict, after: dict) -> dict:
+    return {k: after[k] - before[k] for k in after}
+
+
+def _until(cond, timeout: float = 10.0) -> bool:
+    deadline = time.monotonic() + timeout
+    while not cond() and time.monotonic() < deadline:
+        time.sleep(0.001)
+    return cond()
+
+
+def _parked(svc, monkeypatch):
+    """Park the dispatcher inside its next batch: -> (sizes, entered, release).
+    ``sizes`` takes the job count of every group launched from then on."""
+    import threading
+
+    sound, sizes = svc._run_group, []
+    entered, release = threading.Event(), threading.Event()
+
+    def gated(sig, jobs):
+        sizes.append(len(jobs))
+        entered.set()
+        release.wait(30)
+        return sound(sig, jobs)
+
+    monkeypatch.setattr(svc, "_run_group", gated)
+    return sizes, entered, release
+
+
+@pytest.mark.parametrize("name", ["encode", "decode_rows", "encode_tactic-lrc"])
+def test_a_lone_job_is_launched_without_a_wait(name):
+    """A default service with nothing queued behind the job closes the batch
+    on the spot: codec.drain is a sweep of an empty queue, not a 2 ms hold."""
+    from chubaofs_tpu.codec.service import CodecService
+
+    submit, n = _jobs()[name], 40
+    svc = CodecService()
+    try:
+        submit(svc).result(60)  # compile outside the reading
+        before = _drain_reading()
+        for _ in range(n):
+            submit(svc).result(60)
+        grew = _grew(before, _drain_reading())
+    finally:
+        svc.close()
+    assert (grew["count"], grew["empty"], grew["full"], grew["held"]) == (n, n, 0, 0)
+    assert grew["sum"] / n < 0.5e-3, grew
+
+
+@pytest.mark.parametrize("k", [2, 5, 32, 40])
+def test_what_queued_behind_a_running_batch_is_one_batch(monkeypatch, k):
+    """Batches form by themselves: everything that arrived while the
+    dispatcher was busy is taken by the next sweep, up to max_batch."""
+    from chubaofs_tpu.codec.service import CodecService
+
+    submit = _jobs()["encode"]
+    svc = CodecService()
+    sizes, entered, release = _parked(svc, monkeypatch)
+    try:
+        before = _closes()
+        first = submit(svc)
+        assert entered.wait(30)  # the dispatcher is inside the first batch
+        queued = [submit(svc) for _ in range(k)]
+        release.set()
+        for f in [first] + queued:
+            assert f.result(60).shape == (16, 16 * 1024)
+        grew = _grew(before, _closes())
+    finally:
+        release.set()
+        svc.close()
+    want = [1, min(k, svc.max_batch)] + ([k - svc.max_batch] if k > svc.max_batch else [])
+    assert sizes == want
+    assert grew == {"empty": len(want) - (k >= svc.max_batch),
+                    "full": int(k >= svc.max_batch), "held": 0}
+
+
+@pytest.mark.parametrize("b", [1, 2, 7, 32])
+def test_a_hold_set_on_a_live_service_drains_an_exact_count(b):
+    """The instrument benchmark/deploy.py's warm-up plays: max_wait and
+    max_batch set on a running default service make b jobs submitted one by
+    one ride ONE batch; restoring both restores the unheld drain."""
+    from chubaofs_tpu.codec.service import CodecService
+
+    submit = _jobs()["encode"]
+    svc = CodecService()
+    try:
+        submit(svc).result(60)  # live: the dispatcher waits for its next job
+        keep = (svc.max_batch, svc.max_wait)
+        assert keep == (32, 0.0)
+        svc.max_wait = 5.0
+        try:
+            svc.max_batch = b
+            stats, closes = svc.stats_snapshot(), _closes()
+            for f in [submit(svc) for _ in range(b)]:
+                f.result(60)
+            after = svc.stats_snapshot()
+            grew = _grew(closes, _closes())
+        finally:
+            svc.max_batch, svc.max_wait = keep
+        assert (after["batches"] - stats["batches"], after["jobs"] - stats["jobs"]) == (1, b)
+        # the b-th job closes the batch, out of the sweep or out of the hold
+        assert grew["empty"] == 0 and grew["full"] + grew["held"] == 1
+        assert b > 1 or grew["full"] == 1
+        before = _drain_reading()
+        t0 = time.monotonic()
+        submit(svc).result(60)
+        assert time.monotonic() - t0 < 2.0
+        grew = _grew(before, _drain_reading())
+        assert (grew["count"], grew["empty"], grew["held"]) == (1, 1, 0)
+    finally:
+        svc.close()
+
+
+@pytest.mark.parametrize("hold_ms", [0.0, 5000.0])
+def test_the_sentinel_ends_a_batch_and_is_posted_again(monkeypatch, hold_ms):
+    """close() behind queued jobs: the sweep (unheld: the dispatcher was busy
+    while they queued) or the hold (it sat waiting for more) stops at the
+    sentinel, the jobs in hand are served, the loop then meets it and ends."""
+    import threading
+
+    from chubaofs_tpu.codec.service import CodecService
+
+    submit = _jobs()["encode"]
+    svc = CodecService(max_wait_ms=hold_ms)
+    sizes, entered, release = _parked(svc, monkeypatch)
+    closer = threading.Thread(target=svc.close)
+    try:
+        before = _closes()
+        futures = [submit(svc)]
+        if hold_ms:
+            assert _until(lambda: not svc._q.qsize())
+            time.sleep(0.05)  # the first job is taken: the dispatcher sits in the hold
+        else:
+            assert entered.wait(30)  # inside the first batch; the rest queue up
+        futures += [submit(svc), submit(svc)]
+        t0 = time.monotonic()
+        closer.start()
+        if not hold_ms:
+            assert _until(lambda: svc._q.qsize() == 3)  # two jobs, then the sentinel
+        release.set()
+        for f in futures:
+            assert f.result(60).shape == (16, 16 * 1024)
+        closer.join(10)
+        svc._thread.join(10)
+        assert not closer.is_alive() and not svc._thread.is_alive()
+        assert time.monotonic() - t0 < 4.0  # a hold does not outlast the sentinel
+    finally:
+        release.set()
+        closer.join(10)
+    grew = _grew(before, _closes())
+    if hold_ms:
+        assert sizes == [3] and grew == {"empty": 0, "full": 0, "held": 1}
+    else:
+        assert sizes == [1, 2] and grew == {"empty": 2, "full": 0, "held": 0}
+    with pytest.raises(RuntimeError):
+        submit(svc)
+
+
+@pytest.mark.parametrize("cancelled", [[0], [1], [0, 2], [0, 1, 2]])
+def test_a_cancelled_job_in_a_swept_batch_is_skipped(monkeypatch, cancelled):
+    from chubaofs_tpu.codec.service import CodecService
+
+    submit = _jobs()["encode"]
+    svc = CodecService()
+    sizes, entered, release = _parked(svc, monkeypatch)
+    try:
+        before = _closes()
+        first = submit(svc)
+        assert entered.wait(30)
+        queued = [submit(svc) for _ in range(3)]
+        for i in cancelled:
+            assert queued[i].cancel()
+        release.set()
+        assert first.result(60).shape == (16, 16 * 1024)
+        for i, f in enumerate(queued):
+            assert f.cancelled() if i in cancelled else f.result(60).shape == (16, 16 * 1024)
+        assert _until(lambda: not svc._q.qsize())  # all three cancelled: wait for the sweep itself
+        assert submit(svc).result(60).shape == (16, 16 * 1024)  # the service goes on
+    finally:
+        release.set()
+        svc.close()
+    # the swept batch launches its live jobs only; one that is all cancelled launches nothing
+    assert sizes == [1] + [3 - len(cancelled)] * (len(cancelled) < 3) + [1]
+    assert _grew(before, _closes()) == {"empty": 3, "full": 0, "held": 0}
+
+
+# -- the per-layer metrics that read codec.drain ---------------------------------
+
+DRAIN_LAYERS = [
+    ("small_codec_drain_ms", ["az1.small-open"], "op_p50_ms"),
+    ("get_codec_drain_ms", ["az1.get16m-nodedown", "az2.get16m-azdown", "az1.get16m-rebuild"],
+     "get_MBps"),
+    ("codec_drain_ms", ["az1.put16m", "az3.put16m", "az2.put16m"], "put_MBps"),
+]
+
+
+@pytest.mark.parametrize("name,cells,moves", DRAIN_LAYERS)
+def test_drain_layer_is_the_benchmarks_entry_and_reads_a_live_service(name, cells, moves):
+    import importlib.util
+    import json
+    import os
+
+    from chubaofs_tpu.codec.service import CodecService
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    bench_dir = os.path.join(root, "benchmark")
+    with open(os.path.join(bench_dir, "layers", name + ".json")) as f:
+        spec = json.load(f)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    entry = next(e for e in bench["per_layer"] if e["name"] == name)
+    assert entry == {k: spec[k] for k in entry}
+    assert (entry["workloads"], entry["moves"], entry["layer"]) == (cells, moves, "codec service")
+    assert bench["per_layer"].index(entry) >= len(bench["per_layer"]) - len(DRAIN_LAYERS), "appended"
+    assert set(cells) <= set(next(m for m in bench["end_to_end"] if m["name"] == moves)["workloads"])
+    assert spec["params"] == {"stages": ["codec.drain"]} and "codec.drain" in trace.STAGES
+    modspec = importlib.util.spec_from_file_location(
+        "reducer_" + spec["reducer"], os.path.join(bench_dir, "reducers", spec["reducer"] + ".py"))
+    mod = importlib.util.module_from_spec(modspec)
+    sys.path.insert(0, bench_dir)
+    try:
+        modspec.loader.exec_module(mod)
+    finally:
+        sys.path.remove(bench_dir)
+
+    def reduce(before, after):
+        return mod.reduce({"snap0": {"counters": before}, "snap1": {"counters": after}},
+                          spec["params"])
+
+    total, count = ('cfs_trace_stage_seconds_%s{stage="codec.drain"}' % k for k in ("sum", "count"))
+    assert reduce({total: 1.0, count: 100.0}, {total: 1.5, count: 350.0}) == 2.0
+    assert reduce({total: 1.0, count: 100.0}, {total: 1.0, count: 100.0}) is None  # no batch: nothing
+    submit = _jobs()["encode"]
+    svc = CodecService()
+    try:
+        submit(svc).result(60)
+        before = scrape()
+        for _ in range(20):
+            submit(svc).result(60)
+        read = reduce(before, scrape())
+    finally:
+        svc.close()
+    assert 0.0 < read < 0.5, read
