@@ -113,64 +113,97 @@ class Deployment:
             codec.max_batch, codec.max_wait = keep
         return missed
 
-    def warm_encode(self, object_sizes: list[int], max_count: int) -> dict:
+    def _warm_shapes(self, shapes: dict, max_count: int, named: dict) -> dict:
+        """Drive every shape ``label -> (call, rows, k)`` with (rows, k) seeded
+        bytes (any will do: only the shape compiles) at its batch counts: an
+        inferred shape at 1..``max_count``, a shape a traffic file names at
+        1..its own (said under ``named_counts``, only where a file names one:
+        the line of a cell that names none is what it always was), both capped
+        by the service's max_batch."""
+        def upto(n: int) -> range:
+            return range(1, min(n, self.cluster.codec.max_batch) + 1)
+
+        missed = {}
+        for label, (call, rows, k) in shapes.items():
+            data = np.random.default_rng(k).integers(0, 256, (rows, k), dtype=np.uint8)
+            m = self._exact_batches(lambda: call(data), upto(named.get(label, max_count)))
+            if m:
+                missed[label] = m
+        out = {"shapes": list(shapes), "counts": len(upto(max_count)), "missed": missed}
+        if named:
+            out["named_counts"] = {label: len(upto(n)) for label, n in named.items()}
+        return out
+
+    def warm_encode(self, object_sizes: list[int], max_count: int, named_shapes=()) -> dict:
         """Encode, through CodecService.encode_tactic, every (mode, shard
         bucket) a PUT of these object sizes produces, at batch counts
-        1..min(max_count, the service's max_batch)."""
+        1..min(max_count, the service's max_batch); and, through
+        CodecService.encode, every shape the traffic file names
+        (``warm.encode_shapes``: {n, m, shard_bytes, max_count}) that the
+        harness cannot infer from a PUT, at 1..its max_count."""
         from chubaofs_tpu.blobstore.access import select_code_mode
         from chubaofs_tpu.codec.codemode import get_tactic
         from chubaofs_tpu.codec.service import bucket_len
 
         access, codec = self.cluster.access, self.cluster.codec
-        shapes: dict[tuple, tuple] = {}
+        shapes: dict[str, tuple] = {}
         for size in sorted(set(object_sizes)):
             mode = select_code_mode(size, access.policies)
             t = get_tactic(mode)
             for blob in {min(access.max_blob_size, size - off)
                          for off in range(0, size, access.max_blob_size)}:
                 k = t.shard_size(blob)
-                shapes[(mode.name, bucket_len(k))] = (t, k)
-        counts = range(1, min(max_count, codec.max_batch) + 1)
-        missed = {}
-        for (name, kb), (t, k) in shapes.items():
-            data = np.random.default_rng(kb).integers(0, 256, (t.N, k), dtype=np.uint8)
-            m = self._exact_batches(lambda: codec.encode_tactic(t, data), counts)
-            if m:
-                missed[f"{name}/{kb}"] = m
-        return {"shapes": [f"{n}/{kb}" for n, kb in shapes], "counts": len(counts),
-                "missed": missed}
+                shapes[f"{mode.name}/{bucket_len(k)}"] = (lambda data, t=t: codec.encode_tactic(t, data), t.N, k)
+        named = {}
+        for s in named_shapes:
+            n, m, k = s["n"], s["m"], s["shard_bytes"]
+            label = f"{n}+{m}/{bucket_len(k)}"
+            shapes.setdefault(label, (lambda data, n=n, m=m: codec.encode(n, m, data), n, k))
+            named[label] = s["max_count"]
+        return self._warm_shapes(shapes, max_count, named)
 
-    def warm_decode(self, locations: list[str], max_count: int) -> dict:
+    def warm_decode(self, locations: list[str], max_count: int, named_shapes=()) -> dict:
         """Decode, through CodecService.decode_rows, every (rows wanted, shard
-        bucket) a whole-blob GET of these objects needs with the nodes that
-        are down, at batch counts 1..min(max_count, max_batch)."""
+        bucket) a whole-blob GET of these objects needs with what is down, at
+        batch counts 1..min(max_count, max_batch). A unit is down where its
+        node is not routed OR the cluster manager holds its disk other than
+        NORMAL (one disk of a routed node declared broken). And every shape
+        the traffic file names (``warm.decode_shapes``: {n, m, rows,
+        shard_bytes, max_count}) that no GET shows, such as a repair's decode
+        by a local stripe, at 1..its max_count."""
+        from chubaofs_tpu.blobstore.clustermgr import DISK_NORMAL
         from chubaofs_tpu.codec.codemode import get_tactic
         from chubaofs_tpu.codec.service import bucket_len
 
         codec, cm, nodes = self.cluster.codec, self.cluster.cm, self.cluster.nodes
-        shapes: dict[tuple, tuple] = {}
+
+        def up(u) -> bool:
+            return u.node_id in nodes and cm.disk_status(u.disk_id) == DISK_NORMAL
+
+        def decode(n, m, present, want):
+            return lambda surv: codec.decode_rows(n, m, present, surv, want)
+
+        shapes: dict[str, tuple] = {}
         for token in locations:
             loc = json.loads(token)
             t = get_tactic(loc["code_mode"])
             for b in loc["blobs"]:
                 units = cm.get_volume(b["vid"]).units
-                want = [u.index for u in units if u.index < t.N and u.node_id not in nodes]
+                want = [u.index for u in units if u.index < t.N and not up(u)]
                 if not want:
                     continue
-                present = [u.index for u in units
-                           if u.index < t.N + t.M and u.node_id in nodes][: t.N]
+                present = [u.index for u in units if u.index < t.N + t.M and up(u)][: t.N]
                 k = t.shard_size(b["size"])
-                shapes.setdefault((t.N, t.M, len(want), bucket_len(k)), (present, want, k))
-        counts = range(1, min(max_count, codec.max_batch) + 1)
-        missed = {}
-        for (n, m, r, kb), (present, want, k) in shapes.items():
-            surv = np.random.default_rng(kb + r).integers(0, 256, (n, k), dtype=np.uint8)
-            miss = self._exact_batches(
-                lambda: codec.decode_rows(n, m, present, surv, want), counts)
-            if miss:
-                missed[f"{n}+{m}/want{r}/{kb}"] = miss
-        return {"shapes": [f"{n}+{m}/want{r}/{kb}" for n, m, r, kb in shapes],
-                "counts": len(counts), "missed": missed}
+                shapes.setdefault(f"{t.N}+{t.M}/want{len(want)}/{bucket_len(k)}",
+                                  (decode(t.N, t.M, present, want), t.N, k))
+        named = {}
+        for s in named_shapes:
+            n, m, r, k = s["n"], s["m"], s["rows"], s["shard_bytes"]
+            label = f"{n}+{m}/want{r}/{bucket_len(k)}"
+            # the first r positions lost, the next n answer: some r-row pattern of the code
+            shapes.setdefault(label, (decode(n, m, list(range(r, r + n)), list(range(r))), n, k))
+            named[label] = s["max_count"]
+        return self._warm_shapes(shapes, max_count, named)
 
     def gather_window(self) -> int:
         """Blob gathers one GET keeps in flight (the access layer's pipeline

@@ -1,6 +1,10 @@
 """Closed loop of GETs over a data set PUT in set-up: each stream reads a
 seeded-uniform object when its last read has returned, and compares every body
 with the bytes that were put (a memcmp against the pool, no hash, no copy).
+It also says what it holds its own interpreter lock for: `compare_ms`, a
+stream's time inside payloads.matches a GET, and `turnaround_ms`, from a body's
+last byte to the next request's first (the compare, the op record, the draw),
+each as mean and max over every GET of the run.
 Parameters: streams, object_bytes, objects, load_streams, stagger_s."""
 
 from __future__ import annotations
@@ -9,7 +13,7 @@ import numpy as np
 
 import payloads
 import wire
-from genlib import LOAD_A, bulk_put, now, run_threads, sleep_until
+from genlib import LOAD_A, bulk_put, mean_max_ms, now, run_threads, sleep_until
 
 
 class Generator:
@@ -29,6 +33,8 @@ class Generator:
         p, seed = self.p, self.spec["seed"]
         tokens, size = self.loaded["locations"], p["object_bytes"]
         ops: list[list[dict]] = [[] for _ in range(p["streams"])]
+        compare_s: list[float] = []  # every stream appends: one list each, for the whole run
+        turnaround_s: list[float] = []
 
         def stream(s: int) -> None:
             c = wire.Client(self.spec["addr"])
@@ -40,10 +46,13 @@ class Generator:
                 rec = {"stream": s, "kind": "get", "bytes": size, "a": LOAD_A, "b": i,
                        "seq": q, "ok": False, "t_due": now()}
                 rec["t_start"] = rec["t_due"]
+                if ops[s]:
+                    turnaround_s.append(rec["t_due"] - ops[s][-1]["t_end"])
                 try:
                     body = c.get(tokens[i])
                     rec["t_end"] = now()
                     rec["ok"] = payloads.matches(body, self.pool, seed, LOAD_A, i, size)
+                    compare_s.append(now() - rec["t_end"])
                     if not rec["ok"]:
                         rec["err"] = "body differs from the bytes put"
                     del body
@@ -55,4 +64,5 @@ class Generator:
             c.close()
 
         run_threads(p["streams"], stream, "get")
-        return {"ops": [o for s in ops for o in s], "pool_bytes": p["object_bytes"]}
+        return {"ops": [o for s in ops for o in s], "pool_bytes": p["object_bytes"],
+                "compare_ms": mean_max_ms(compare_s), "turnaround_ms": mean_max_ms(turnaround_s)}

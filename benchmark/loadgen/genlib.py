@@ -23,6 +23,13 @@ def sleep_until(t: float) -> None:
         time.sleep(d)
 
 
+def mean_max_ms(seconds: list[float]) -> dict:
+    """{"mean", "max", "count"} of a generator's own intervals, in ms."""
+    if not seconds:
+        return {}
+    return {"mean": sum(seconds) / len(seconds) * 1e3, "max": max(seconds) * 1e3, "count": len(seconds)}
+
+
 def run_threads(n: int, target, name: str) -> None:
     """Run target(0..n-1) on n threads and wait for all of them."""
     threads = [threading.Thread(target=target, args=(i,), name=f"{name}{i}") for i in range(n)]

@@ -42,11 +42,19 @@ def payload(pool: list[bytes], seed: int, a: int, b: int, size: int) -> bytes:
 
 
 def matches(body, pool: list[bytes], seed: int, a: int, b: int, size: int) -> bool:
-    """memcmp of ``body`` against object (a, b) without building a copy."""
-    if len(body) != size:
+    """Is ``body`` exactly object (a, b)? Its length, its 16-byte stamp, then
+    EVERY remaining byte against the pool slice, in one memcmp: bytes.startswith
+    over [start, end) of the base takes any buffer as its prefix and compares
+    in C, so neither the body nor the base is copied, and with the lengths equal
+    a match of the prefix is a match of every byte. ``body`` is whatever holds
+    bytes: the ``bytes`` wire.Client.get returns, a bytearray, a memoryview.
+    (Not ``memoryview == memoryview``: that unpacks element by element under the
+    interpreter lock, 48.6 ms a 16 MiB body on the chip's host against 1.4, and
+    caps a GET cell at its own generator.)"""
+    mv = memoryview(body).cast("B")
+    if mv.nbytes != size:
         return False
     base = pool[base_index(a, b, len(pool))]
     off = small_offset(a, b, size, len(base))
-    mv = memoryview(body)
-    return (bytes(mv[:STAMP_LEN]) == stamp(seed, a, b)
-            and mv[STAMP_LEN:] == memoryview(base)[off + STAMP_LEN: off + size])
+    return (mv[:STAMP_LEN] == stamp(seed, a, b)
+            and base.startswith(mv[STAMP_LEN:], off + STAMP_LEN, off + size))

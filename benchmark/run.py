@@ -186,10 +186,13 @@ def main() -> int:
         child.expect("ready")
         streams = traffic["params"].get("streams")
         c0 = dep.counters()
-        if traffic["warm"].get("encode"):
+        warm = traffic["warm"]
+        if warm.get("encode") or warm.get("encode_shapes"):
             sizes = traffic["params"].get("sizes") or [traffic["params"]["object_bytes"]]
             blobs = max(-(-s // config["max_blob_size"]) for s in sizes)
-            say(warm_encode=dep.warm_encode(sizes, streams * blobs if streams else 1 << 30),
+            say(warm_encode=dep.warm_encode(sizes if warm.get("encode") else [],
+                                            streams * blobs if streams else 1 << 30,
+                                            warm.get("encode_shapes", ())),
                 at_s=time.monotonic() - T_PROCESS)
         loaded = None
         if traffic.get("loads"):
@@ -202,8 +205,9 @@ def main() -> int:
             dep.node_down(traffic["nodes_down"])
         if traffic.get("switches_off"):
             dep.switch_off(traffic["switches_off"])
-        if traffic["warm"].get("decode"):
-            say(warm_decode=dep.warm_decode(loaded["locations"], streams * dep.gather_window()),
+        if warm.get("decode") or warm.get("decode_shapes"):
+            say(warm_decode=dep.warm_decode(loaded["locations"] if warm.get("decode") else [],
+                                            streams * dep.gather_window(), warm.get("decode_shapes", ())),
                 at_s=time.monotonic() - T_PROCESS)
         c1 = dep.counters()
         say(setup_compiles={k: c1.get(k, 0) - c0.get(k, 0) for k in (
@@ -260,6 +264,13 @@ def main() -> int:
                 c2c_MBps=None if c2c is None else c2c / 1e6)
         if result.get("lateness_ms"):
             say(generator_lateness_ms=result["lateness_ms"])
+        if result.get("compare_ms"):
+            # what the generator holds its own interpreter lock for, a GET: at a share
+            # near 1 the cell reads its generator and not the server (PERF.md, Findings PR 41)
+            gets_per_s = len(win.in_window(ops, t0, t1, "get")) / args.seconds
+            say(generator_compare_ms=result["compare_ms"],
+                generator_turnaround_ms=result.get("turnaround_ms"), gets_per_s=gets_per_s,
+                compare_share_of_one_lock=gets_per_s * result["compare_ms"]["mean"] / 1e3)
         if compiles:
             # the warm-up did not cover what this window reached: its numbers hold
             # compilation, so there is no result (PERF.md, Findings PR 24: mechanism 1)
@@ -297,9 +308,16 @@ def main() -> int:
         import verify
 
         t_v = time.monotonic()
+        checks = {}
+
+        def say_check(**kw) -> None:
+            if "check" in kw:
+                checks[kw["check"]] = {k: kw[k] for k in ("value", "limit", "ok")}
+            say(**kw)
+
         correct, attempted, failed = verify.decide(
             dep, config, traffic, result, t0, t1, args.seed,
-            (snap0["counters"], snap1["counters"]), say)
+            (snap0["counters"], snap1["counters"]), say_check)
         say(verify_s=time.monotonic() - t_v, total_s=time.monotonic() - T_PROCESS)
         device = dep.device()
         line = {"correct": bool(correct), "attempted": attempted, "failed": failed,
@@ -309,6 +327,7 @@ def main() -> int:
             line["device"].update(summary["device"])
             line["breakdown"] = summary["breakdown"]
         line.update(stamp)
+        line["checks"] = checks  # every number compared beside its limit: last in the line
     finally:
         if child is not None:
             child.close()
@@ -317,6 +336,8 @@ def main() -> int:
         except Exception as e:  # the result stands; a slow teardown is said on stderr
             print(f"teardown: {type(e).__name__}: {e}", file=sys.stderr)
         shutil.rmtree(data_root, ignore_errors=True)
+    for name, c in line["checks"].items():  # and the last lines of stderr
+        print(f"check {name}: {c['value']} {c['limit']} ok={c['ok']}", file=sys.stderr)
     print(json.dumps(line), flush=True)
     return 0
 

@@ -7,6 +7,7 @@ import time
 
 import pytest
 
+import closed_get
 import genlib
 import open_mixed
 import payloads
@@ -115,3 +116,56 @@ def test_payload_rebuilds_and_matches():
     assert not payloads.matches(body[:-1] + bytes([body[-1] ^ 1]), pool, 99, 3, 41, 5000)
     whole = payloads.payload(pool, 99, 0, 1, 1 << 16)
     assert whole[16:] == pool[payloads.base_index(0, 1)][16:]
+
+
+# -- payloads.matches: every byte of every body, whatever holds the bytes -------------
+
+SIZES = {5000: 1 << 16, 1 << 24: 1 << 24}  # object size -> the pool's base size (a 16 MiB object IS a base)
+
+
+@pytest.fixture(scope="module")
+def pools():
+    return {size: payloads.bases(41, base) for size, base in SIZES.items()}
+
+
+def flip(at):
+    def damage(body):
+        out = bytearray(body)
+        out[at] ^= 1
+        return out
+    return damage
+
+
+@pytest.mark.parametrize("size", sorted(SIZES))
+@pytest.mark.parametrize("name,damage,same", [
+    ("bytes", bytes, True), ("bytearray", bytearray, True), ("memoryview", memoryview, True),
+    ("first byte after the stamp", flip(payloads.STAMP_LEN), False), ("last byte", flip(-1), False),
+    ("a stamp byte", flip(3), False), ("one byte short", lambda b: b[:-1], False),
+    ("one byte long", lambda b: b + b"\0", False),
+])
+def test_matches_compares_every_byte_of_any_buffer(pools, size, name, damage, same):
+    pool = pools[size]
+    body = payloads.payload(pool, 41, genlib.LOAD_A, 7, size)
+    assert len(body) == size
+    assert payloads.matches(damage(body), pool, 41, genlib.LOAD_A, 7, size) is same, name
+    assert not payloads.matches(damage(body), pool, 41, genlib.LOAD_A, 8, size)  # another object's bytes
+
+
+def test_closed_get_says_what_it_holds_its_lock_for(gateway):
+    # 2 streams over 3 loaded objects for half a second: every body compared, and the
+    # generator's own compare and turnaround come back beside the ops
+    gen = closed_get.Generator({"addr": gateway, "seed": 5, "params": {
+        "streams": 2, "object_bytes": 1 << 16, "objects": 3, "load_streams": 2, "stagger_s": 0.01}})
+    gen.prepare()
+    assert gen.load()["failed"] == []
+    start = genlib.now() + 0.05
+    res = gen.run(start, start + 0.1, start + 0.5)
+    gets = res["ops"]
+    assert len(gets) >= 4 and all(o["ok"] for o in gets)
+    cmp_ms, turn_ms = res["compare_ms"], res["turnaround_ms"]
+    assert cmp_ms["count"] == len(gets) and turn_ms["count"] == len(gets) - 2  # none before a stream's first
+    assert 0 < cmp_ms["mean"] <= cmp_ms["max"] < 50 and 0 < turn_ms["mean"] <= turn_ms["max"]
+    # a body that differs is said so, op by op
+    FakeGateway.store = {k: bytes(len(v)) for k, v in FakeGateway.store.items()}
+    bad = gen.run(genlib.now(), 0, genlib.now() + 0.2)["ops"]
+    assert bad and all(o["err"] == "body differs from the bytes put" and not o["ok"] for o in bad)

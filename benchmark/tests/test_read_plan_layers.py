@@ -1,5 +1,6 @@
 """get_blob_read_ms and get_one_round_share (data files over stage_ms and
-counter_ratio) reduce on a CPU rehearsal window of each degraded-GET cell, and
+counter_ratio) reduce on a CPU rehearsal window of each degraded-GET cell (which
+also prints the generator's own compare and turnaround on a diagnostic line), and
 on the counters of a program that has no cfs_access_read_plan_total (the parent
 of the PR that added it) the share reads nothing while the stage metric reads
 both of that program's rounds."""
@@ -14,7 +15,8 @@ from run import load_reducer
 from test_control import ROOT
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-GET_CELLS = ["az1.get16m-nodedown", "az2.get16m-azdown"]
+FROZEN = ["az1.get16m-nodedown", "az2.get16m-azdown"]  # the damage stands still: every blob one round
+GET_CELLS = FROZEN + ["az1.get16m-rebuild"]  # PR 41: its share falls as units are re-homed
 
 
 def layer(name):
@@ -29,11 +31,21 @@ def test_both_reduce_on_a_rehearsal_window(cell):
          "--seconds", "6", "--trace", "1", "--rehearse-cpu"],
         cwd=ROOT, env=dict(os.environ, JAX_PLATFORMS="cpu"), capture_output=True, text=True, timeout=900)
     assert p.returncode == 0, p.stderr[-2000:]
-    last = [json.loads(l) for l in p.stdout.splitlines() if l.startswith("{")][-1]
+    lines = [json.loads(l) for l in p.stdout.splitlines() if l.startswith("{")]
+    last = lines[-1]
     assert last["platform"] == "cpu" and last["failed"] == 0
     m = last["metrics"]
-    # every stripe of both deployments has a data unit on what is down
-    assert m["get_one_round_share"]["value"] == 1.0
+    # every stripe of the frozen-damage deployments has a data unit on what is down; under the
+    # rebuild a re-homed unit's blobs go back to the direct plan (none need be, in 6 s on the CPU)
+    share = m["get_one_round_share"]["value"]
+    assert share == 1.0 if cell in FROZEN else 0 < share <= 1.0
+    # the generator says what it holds its own lock for, beside the window's other diagnostics
+    held = next(l for l in lines if "generator_compare_ms" in l)
+    assert held["generator_compare_ms"]["count"] >= len([1 for l in lines if l.get("kind") == "get"])
+    assert 0 < held["generator_compare_ms"]["mean"] <= held["generator_compare_ms"]["max"]
+    assert held["generator_turnaround_ms"]["mean"] > 0 and held["gets_per_s"] > 0
+    assert held["compare_share_of_one_lock"] == pytest.approx(
+        held["gets_per_s"] * held["generator_compare_ms"]["mean"] / 1e3)
     assert 0 < m["get_blob_read_ms"]["value"] < 1000
     # no shard read twice (az1 read 13 / 12 = 1.083); a 6 s window on the CPU holds few
     # enough GETs that those in flight at its edges move the ratio by a per cent or two
@@ -41,7 +53,7 @@ def test_both_reduce_on_a_rehearsal_window(cell):
 
 
 @pytest.mark.parametrize("name", ["get_blob_read_ms", "get_one_round_share"])
-def test_entries_list_both_get_cells(name):
+def test_entries_list_the_get_cells(name):
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         entry = next(e for e in json.load(f)["per_layer"] if e["name"] == name)
     spec = layer(name)
