@@ -822,6 +822,14 @@ class Access:
         dst.put(0, data)
         return True
 
+    def _can_answer(self, unit) -> bool:
+        """This unit can answer a read: its node is routed AND the cluster
+        manager holds its disk NORMAL (cm.disk_serves: a dict read, no lock).
+        One that cannot (a dark host, a dark AZ, a disk declared BROKEN on a
+        node that stays up) is a failed read known without one: the read plan
+        goes around it and nothing is handed to a pool for it."""
+        return unit.node_id in self.nodes and self.cm.disk_serves(unit.disk_id)
+
     def _read_blob_ec(self, mode: int, blob: Blob, offset: int, size: int,
                       dst: _BlobDest) -> None:
         """One blob from the EC tier, by one of three plans, counted once a
@@ -830,8 +838,9 @@ class Access:
           direct     every in-window data unit is routed and answers: ranged
                      sub-shard reads of only the data shards the byte range
                      touches, issued concurrently.
-          one_round  the routing table shows, before any read, an in-window
-                     data unit that cannot answer (a dark host, a dark AZ):
+          one_round  the routing table or the disk table shows, before any
+                     read, an in-window data unit that cannot answer (a dark
+                     host, a dark AZ, a BROKEN disk: _can_answer):
                      the blob is degraded by plan, so there is no direct
                      phase. The degraded read launches its whole survivor set
                      (the live in-window data shards with them) in ONE
@@ -856,9 +865,9 @@ class Access:
             return self._read_shard(vol, idx, blob.bid, lo, hi - lo)
 
         idxs = list(range(first_shard, last_shard + 1))
-        # a unit whose node is not routed (a dark host, a dark AZ) is a failed
-        # read known without one: it is never handed to the pool
-        dark = {i for i in idxs if vol.units[i].node_id not in self.nodes}
+        # a unit that cannot answer (an unrouted node, a BROKEN disk) is a
+        # failed read known without one: it is never handed to the pool
+        dark = {i for i in idxs if not self._can_answer(vol.units[i])}
         if dark:
             _count_read_plan("one_round")
             self._read_blob_degraded(t, vol, blob, shard_len, offset, size,
@@ -915,7 +924,8 @@ class Access:
             recoverable = [g for g in globals_in_az if g not in pres]
             if not recoverable:
                 continue  # nothing this AZ's stripe could win back
-            locals_in_az = [g for g in idx_list if g >= t.N + t.M]
+            locals_in_az = [g for g in idx_list if g >= t.N + t.M
+                            and self._can_answer(vol.units[g])]
             az_reads: dict[int, np.ndarray] = {
                 g: stripe[g] for g in globals_in_az if g in pres
             }
@@ -1019,9 +1029,10 @@ class Access:
         hedge replacement while the original keeps running (slow-but-alive
         may still answer first) — so unselected candidates (the parity tail
         of the list) are never fetched unless a selected read lets the gather
-        down. A candidate whose node is not routed has failed already: it is
-        never launched, and every such candidate is among the failures
-        whether or not the gather would have reached it.
+        down. A candidate that cannot answer (_can_answer: its node is not
+        routed, or its disk is held BROKEN) has failed already: it is never
+        launched, and every such candidate is among the failures whether or
+        not the gather would have reached it.
 
         The caller's thread sleeps on ONE event a round: the reads'
         done-callbacks set it when a read has failed, or when as many reads
@@ -1034,7 +1045,7 @@ class Access:
         (idx -> bytes, failed idxs)."""
         got: dict[int, bytes] = {}
         failures = [i for i in candidates
-                    if vol.units[i].node_id not in self.nodes]
+                    if not self._can_answer(vol.units[i])]
         candidates = [i for i in candidates if i not in failures]
         if needed <= 0:
             return got, failures
@@ -1282,6 +1293,9 @@ class Access:
         wedged blobnode would pin write workers and stall unrelated stripe
         writes) and dedupe per (vid, bid): a burst of degraded GETs of one
         hot blob probes it once."""
+        # what cannot answer is known damaged without a probe (and is the
+        # disk repair's, or waits for its node): no read is issued for it
+        unprobed = [i for i in unprobed if self._can_answer(vol.units[i])]
         if not unprobed:
             return
         key = (vol.vid, blob.bid)

@@ -82,9 +82,16 @@ class ChunkFull(BlobNodeError):
     pass
 
 
+class DiskBroken(BlobNodeError):
+    """The cluster manager holds the chunk's disk other than NORMAL: the node
+    answers no shard I/O for it (upstream's blobnode refuses a broken disk's
+    chunk I/O the same way)."""
+
+
 def classify_io_error(e: BaseException) -> str:
     """Bucket a shard-IO failure for {reason}-labeled metrics: 'missing'
     (routine absence — the shard was never written or already lost),
+    'disk_broken' (the node refused: its disk is held BROKEN or DROPPED),
     'timeout' (a silent hang that hit a deadline), 'io' (infrastructure:
     sockets, disks, injected faults), or 'error' (everything else — the
     bucket that should be a bug). The split is what makes a wedged node and
@@ -95,6 +102,8 @@ def classify_io_error(e: BaseException) -> str:
 
     if isinstance(e, NoSuchShard):
         return "missing"
+    if isinstance(e, DiskBroken):
+        return "disk_broken"
     if isinstance(e, (TimeoutError, _FutTimeout)):
         return "timeout"
     if isinstance(e, (BlobNodeError, OSError, ConnectionError,
@@ -483,8 +492,12 @@ class BlobNode:
     """
 
     def __init__(self, node_id: int, disk_roots: list[str],
-                 iostat: bool = False, scrub_rate: float | None = None):
+                 iostat: bool = False, scrub_rate: float | None = None,
+                 cm=None):
         self.node_id = node_id
+        # the cluster manager whose disk table says which of this node's disks
+        # still serve (_refuse_unless_normal); None: a bare engine serves all
+        self._cm = cm
         self.disks: dict[int, Disk] = {}
         for i, root in enumerate(disk_roots):
             d = Disk(root, disk_id=node_id * 1000 + i)
@@ -560,6 +573,25 @@ class BlobNode:
         disk_id, cid = loc
         return self.disks[disk_id].chunks[cid]
 
+    def _serves(self, disk_id: int) -> bool:
+        """The cluster manager holds this disk NORMAL (cm.disk_serves: one
+        dict read, no lock, no tick to wait for); a bare engine serves all."""
+        return self._cm is None or self._cm.disk_serves(disk_id)
+
+    def _refuse_unless_normal(self, vuid: int) -> None:
+        """The entry gate of every shard call: a chunk on a disk the cluster
+        manager holds BROKEN (the operator's declaration, this node's own
+        io_errors report) or DROPPED is refused before any file is touched,
+        from the moment the status is set. The node's other disks serve as
+        before, and a disk set back to NORMAL serves again. The node's own
+        sweeps (scrub, inspect, compaction) pass such a disk by."""
+        loc = self._chunk_of_vuid.get(vuid)
+        if loc is None:
+            return  # no chunk here: _chunk says so
+        if not self._serves(loc[0]):
+            self._reg.counter("io_refused", {"reason": "disk_broken"}).add()
+            raise DiskBroken(f"disk {loc[0]} is not NORMAL: no I/O for vuid {vuid}")
+
     def _disk_io(self, vuid: int, op):
         """Run one chunk op tracking CONSECUTIVE per-disk OSErrors — the
         disk-failure signal heartbeat() reports to clustermgr. Logical
@@ -595,6 +627,7 @@ class BlobNode:
     def put_shard(self, vuid: int, bid: int, payload: bytes) -> None:
         import time as _time
 
+        self._refuse_unless_normal(vuid)
         t0 = _time.perf_counter()
         if self._iostat is not None:
             self._iostat.write_begin()
@@ -617,6 +650,7 @@ class BlobNode:
     def get_shard(self, vuid: int, bid: int, offset: int = 0, size: int | None = None) -> bytes:
         import time as _time
 
+        self._refuse_unless_normal(vuid)
         t0 = _time.perf_counter()
         data = b""
         if self._iostat is not None:
@@ -650,6 +684,7 @@ class BlobNode:
 
         from chubaofs_tpu.ops import gf256
 
+        self._refuse_unless_normal(vuid)
         t0 = _time.perf_counter()
         data = b""
         if self._iostat is not None:
@@ -681,12 +716,15 @@ class BlobNode:
                     len(data), int((_time.perf_counter() - t0) * 1e6))
 
     def mark_delete_shard(self, vuid: int, bid: int) -> None:
+        self._refuse_unless_normal(vuid)
         self._chunk(vuid).mark_delete(bid)
 
     def delete_shard(self, vuid: int, bid: int) -> None:
+        self._refuse_unless_normal(vuid)
         self._chunk(vuid).delete(bid)
 
     def list_shards(self, vuid: int) -> list[ShardMeta]:
+        self._refuse_unless_normal(vuid)
         return self._chunk(vuid).list_shards()
 
     def lose_shard(self, vuid: int, bid: int) -> None:
@@ -739,6 +777,8 @@ class BlobNode:
         returns total bytes reclaimed."""
         reclaimed = 0
         for disk in self.disks.values():
+            if not self._serves(disk.disk_id):
+                continue
             for chunk in list(disk.chunks.values()):
                 if chunk.used and chunk.holes >= min_holes and \
                         chunk.holes / chunk.used >= min_hole_ratio:
@@ -752,7 +792,7 @@ class BlobNode:
         bad: list[tuple[int, int]] = []
         for vuid, (disk_id, cid) in list(self._chunk_of_vuid.items()):
             chunk = self.disks[disk_id].chunks.get(cid)
-            if chunk is None:
+            if chunk is None or not self._serves(disk_id):
                 continue
             for meta in chunk.list_shards():
                 if meta.status != STATUS_NORMAL:
@@ -774,7 +814,7 @@ class BlobNode:
             if loc is None:
                 continue
             chunk = self.disks[loc[0]].chunks.get(loc[1])
-            if chunk is None:
+            if chunk is None or not self._serves(loc[0]):
                 continue
             for meta in chunk.list_shards():
                 if cur is not None and vuid == cur[0] and meta.bid <= cur[1]:

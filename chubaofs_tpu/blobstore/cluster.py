@@ -49,7 +49,7 @@ class MiniCluster:
         self.nodes: dict[int, BlobNode] = {}
         for n in range(1, n_nodes + 1):
             roots = [os.path.join(root, f"node{n}", f"disk{d}") for d in range(disks_per_node)]
-            node = BlobNode(node_id=n, disk_roots=roots)
+            node = BlobNode(node_id=n, disk_roots=roots, cm=self.cm)
             self.nodes[n] = node
             az = (n - 1) % azs
             self.cm.register_disks([

@@ -313,6 +313,14 @@ class ClusterMgr:
             d = self.disks.get(disk_id)
             return None if d is None else d.status
 
+    def disk_serves(self, disk_id: int) -> bool:
+        """This disk may be read and written: it is held NORMAL (or is not in
+        the table: nothing is known against it). One dict read, no lock: the
+        per-shard question of the blobnode's entry gate, of the access
+        layer's read plan and of the repair worker."""
+        d = self.disks.get(disk_id)
+        return d is None or d.status == DISK_NORMAL
+
     def set_disk_status(self, disk_id: int, status: str,
                         reason: str = "report") -> None:
         """The ONE public disk-status transition (the error-count path:

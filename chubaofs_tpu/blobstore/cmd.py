@@ -116,7 +116,7 @@ def add_admin_routes(router, cluster, runner: ModuleRunner | None = None):
     switches + graceful reload) — what the blobstore CLI drives."""
     import json
 
-    from chubaofs_tpu.blobstore.taskswitch import ALL_SWITCHES
+    from chubaofs_tpu.blobstore.taskswitch import ALL_SWITCHES, SWITCH_DISK_REPAIR
     from chubaofs_tpu.rpc.router import Response
 
     def _json(data, status=200):
@@ -151,7 +151,10 @@ def add_admin_routes(router, cluster, runner: ModuleRunner | None = None):
         """The operator's declaration (clustermgr /disk/set): this disk is
         BROKEN. A dead node's disks need not wait out the heartbeat timeout:
         the status is set now, the disk-repair task exists when the call
-        returns, and the repair worker is already on it. Refused for an
+        returns, and the repair worker is already on it; from the answer on
+        the disk's blobnode refuses every shard call for it (it reads the
+        status set here: BlobNode._refuse_unless_normal) and readers plan
+        around its units (Access._can_answer). Refused for an
         unknown disk, for any status but `broken` (NORMAL and DROPPED are the
         repair's to set), and for a disk already DROPPED; declaring a broken
         disk again changes nothing."""
@@ -211,6 +214,12 @@ def add_admin_routes(router, cluster, runner: ModuleRunner | None = None):
             return _json({"error": f"unknown switch {name!r}"}, 400)
         enabled = req.q("enabled") in ("1", "true", "on")
         cluster.scheduler.switches.set(name, enabled)
+        if enabled and name == SWITCH_DISK_REPAIR:
+            # like the declaration itself: disks declared broken while the
+            # switch was held get their tasks now and the worker is woken,
+            # not at the end of a tick that may be one inspector pass away
+            cluster.scheduler.check_disks()
+            cluster.worker.kick()
         return _json({name: enabled})
 
     def forgive(req):

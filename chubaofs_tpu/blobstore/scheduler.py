@@ -159,6 +159,12 @@ class Scheduler:
         self._load_tasks()
         with self._lock:
             self._update_gauges_locked()
+        # how an LRC volume's disk was rebuilt: made here, at 0, so that they
+        # render before their first increment (a reader of "no byte crossed
+        # the AZ boundary" must find a series that says 0, not none)
+        for name in ("rebuild_local_jobs", "rebuild_cross_az_bytes",
+                     "rebuild_local_fallbacks"):
+            registry("scheduler").counter(name)
 
     # -- task table (persisted in the clustermgr config KV, the reference's
     # migrate-task tables in clustermgr: migrate.go:346-347) -------------------
@@ -1182,10 +1188,7 @@ class RepairWorker:
         """This stripe position can be read and written now: its node is
         routed and its disk NORMAL."""
         u = vol.units[idx]
-        if u.node_id not in self.nodes:
-            return False
-        d = self.cm.disks.get(u.disk_id)
-        return d is None or d.status == DISK_NORMAL
+        return u.node_id in self.nodes and self.cm.disk_serves(u.disk_id)
 
     def _repair_shards(self, vid: int, bid: int, bad_idx: list[int]):
         vol = self.cm.get_volume(vid)
@@ -1231,12 +1234,10 @@ class RepairWorker:
                 continue
             # the AZ's local stripe is RS(local_n, local_m) over `idx`: the
             # lost rows from its first local_n readable ones
-            pos = {g: p for p, g in enumerate(idx)}
-            srv = [g for g in idx if g in reads][:local_n]
-            sub = np.stack([np.frombuffer(reads[g], np.uint8) for g in srv])
+            present, sub = self._local_rows(idx, local_n, reads)
             rows = self.codec.decode_rows(
-                local_n, local_m, [pos[g] for g in srv], sub,
-                [pos[g] for g in az_bad]).result()
+                local_n, local_m, present, sub,
+                [idx.index(g) for g in az_bad]).result()
             for p, g in enumerate(az_bad):
                 self._write_back(vol, g, bid, rows[p].tobytes())
             # the repair-traffic win the LRC layout buys: these shards were
@@ -1244,6 +1245,23 @@ class RepairWorker:
             registry("scheduler").counter(
                 "repair_local_shards").add(len(az_bad))
         return leftover
+
+    @staticmethod
+    def _local_stripe_of(t, index: int) -> tuple[list[int], int, int]:
+        """(positions, local_n, local_m) of the AZ-local stripe that holds
+        stripe position `index` (Tactic.local_stripes)."""
+        return next(ls for ls in t.local_stripes() if index in ls[0])
+
+    @staticmethod
+    def _local_rows(idx: list[int], local_n: int, reads: dict):
+        """The first local_n rows of the AZ stripe `idx` that `reads` holds, in
+        stripe order, for CodecService.decode_rows(local_n, local_m, ...):
+        (their positions in the LOCAL stripe's own coordinates, (local_n, k)
+        bytes). Every stripe of a unit has the same rows where nothing else
+        is damaged, and with them the same decode matrix."""
+        srv = [g for g in idx if g in reads][:local_n]
+        return [idx.index(g) for g in srv], np.stack(
+            [np.frombuffer(reads[g], np.uint8) for g in srv])
 
     def _repair_global(self, vol: VolumeInfo, t, bid: int):
         """Global-stripe repair + recompute of any missing local parities."""
@@ -1533,6 +1551,32 @@ class RepairWorker:
             self._put_row(prep, bid, payload)
         return left
 
+    def _read_first(self, vol: VolumeInfo, bid: int, cands: list[int],
+                    need: int, span=None) -> dict[int, bytes]:
+        """Reads of the first `need` of `cands` that answer: the first `need`
+        are launched, and only what fails is replaced from the rest. Fewer
+        than `need` entries where the candidates run out."""
+        reads: dict[int, bytes] = {}
+        tried = 0
+        while len(reads) < need and tried < len(cands):
+            batch = cands[tried: tried + need - len(reads)]
+            tried += len(batch)
+            reads.update(self._probe(vol, bid, batch, span=span))
+        return reads
+
+    def _count_rebuild_reads(self, vol: VolumeInfo, unit, reads: dict) -> None:
+        """Survivor bytes a rebuild's gather read, and those of them that came
+        from a disk of another AZ than the rebuilt unit's (the inter-AZ link an
+        LRC mode's local parities are bought to spare)."""
+        reg = registry("scheduler")
+        disks = self.cm.disks
+        az = disks[unit.disk_id].az
+        reg.counter("rebuild_bytes", {"kind": "read"}).add(
+            sum(len(b) for b in reads.values()))
+        reg.counter("rebuild_cross_az_bytes").add(
+            sum(len(b) for i, b in reads.items()
+                if disks[vol.units[i].disk_id].az != az))
+
     def _gather_rows(self, vol: VolumeInfo, t, unit, bid: int, span=None):
         """Exactly N survivor rows of a stripe for the rebuild of `unit`:
         (global positions, (N, k) bytes in that order). Reads the first N
@@ -1543,20 +1587,33 @@ class RepairWorker:
         with trace.stage("repair.gather"):
             cands = [i for i in range(t.N + t.M)
                      if i != unit.index and self._usable(vol, i)]
-            reads: dict[int, bytes] = {}
-            tried = 0
-            while len(reads) < t.N and tried < len(cands):
-                batch = cands[tried: tried + t.N - len(reads)]
-                tried += len(batch)
-                reads.update(self._probe(vol, bid, batch, span=span))
+            reads = self._read_first(vol, bid, cands, t.N, span=span)
             if len(reads) < t.N:
                 raise RuntimeError(
                     f"stripe {vol.vid}/{bid}: {len(reads)} < N={t.N} readable")
             present = sorted(reads)
-            registry("scheduler").counter("rebuild_bytes", {"kind": "read"}).add(
-                sum(len(reads[i]) for i in present))
+            self._count_rebuild_reads(vol, unit, reads)
             return present, np.stack(
                 [np.frombuffer(reads[i], np.uint8) for i in present])
+
+    def _gather_local_rows(self, vol: VolumeInfo, t, unit, bid: int, span=None):
+        """Local-stripe-first (work_shard_recover.go:517 recoverByLocalStripe,
+        tried before recoverByGlobalStripe): local_n rows of the unit's OWN
+        AZ's local stripe (its other globals and its local parity), read from
+        that AZ alone: (their positions in the local stripe's coordinates,
+        (local_n, k) bytes). None where the AZ's stripe has a second hole
+        (another unit of it unrouted, on a disk that is not NORMAL, or
+        unreadable): the caller takes the global gather."""
+        idx, local_n, _ = self._local_stripe_of(t, unit.index)
+        cands = [i for i in idx if i != unit.index and self._usable(vol, i)]
+        if len(cands) < local_n:
+            return None  # a hole known without a read
+        with trace.stage("repair.gather_local"):
+            reads = self._read_first(vol, bid, cands, local_n, span=span)
+            self._count_rebuild_reads(vol, unit, reads)
+            if len(reads) < local_n:
+                return None
+            return self._local_rows(idx, local_n, reads)
 
     def _gather_for_unit(self, vol: VolumeInfo, t, unit, bid: int,
                          span=None):
@@ -1564,9 +1621,13 @@ class RepairWorker:
         regenerating volume first tries the beta-fetch for the migrating
         unit's row (d combined payloads instead of a full-stripe gather —
         the bulk-rebuild path is where nearly all repair bytes move) and
-        falls back to the full gather when helpers can't cover it. An RS or
-        LRC global unit gathers N survivors; an LRC local parity gathers its
-        AZ's local stripe, and N survivors only where that has holes."""
+        falls back to the full gather when helpers can't cover it. An RS
+        global unit gathers N survivors; an LRC global unit its AZ's local
+        stripe first, as upstream's repair worker does (local_n reads, none
+        across the AZ boundary), and N survivors of any AZ only where that
+        stripe has a second hole (counted: rebuild_local_fallbacks, one a
+        stripe); an LRC local parity gathers its AZ's local stripe, and N
+        survivors only where that has holes."""
         if t.is_regenerating:
             if unit.index < t.global_count:
                 got = self._gather_beta(vol, t, bid, unit.index, span=span)
@@ -1574,8 +1635,13 @@ class RepairWorker:
                     return ("beta",) + got
             return ("full", self._gather(vol, t, bid, span=span))
         if unit.index < t.N + t.M:
+            if t.L:
+                got = self._gather_local_rows(vol, t, unit, bid, span=span)
+                if got is not None:
+                    return ("local_rows",) + got
+                registry("scheduler").counter("rebuild_local_fallbacks").add()
             return ("rows",) + self._gather_rows(vol, t, unit, bid, span=span)
-        idx, local_n, _ = next(ls for ls in t.local_stripes() if unit.index in ls[0])
+        idx, local_n, _ = self._local_stripe_of(t, unit.index)
         have = self._probe(vol, bid, [i for i in idx[:local_n] if self._usable(vol, i)],
                            span=span)
         survivors = None
@@ -1587,8 +1653,9 @@ class RepairWorker:
         """Turn one gathered stripe into the migrating unit's row: its bytes,
         or a _PendingRow of them. A lost global shard is ONE row of the degraded
         GET's decode ((1, N) @ (N, k) through CodecService.decode_rows: every
-        stripe of the unit shares the matrix, so the jobs batch by content); a
-        lost local parity decodes what its AZ's local stripe lacks and
+        stripe of the unit shares the matrix, so the jobs batch by content),
+        or of its AZ's local stripe's ((1, local_n) @ (local_n, k)) where the
+        gather took that; a lost local parity decodes what its AZ's local stripe lacks and
         re-encodes it. A beta-gather (regenerating modes) becomes the
         (alpha, d) repair matmul — batchable on the device like the decodes."""
         reg = registry("scheduler")
@@ -1616,6 +1683,16 @@ class RepairWorker:
             reg.counter("rebuild_decode_jobs").add()
             return _PendingRow(self.codec.decode_rows(t.N, t.M, present, survivors,
                                                 [unit.index]),
+                         lambda rows: rows[0].tobytes())
+        if kind == "local_rows":
+            # the one lost row of RS(local_n, local_m), in the local stripe's
+            # own coordinates: one matrix a unit here too
+            _, present, survivors = gathered
+            idx, local_n, local_m = self._local_stripe_of(t, unit.index)
+            reg.counter("rebuild_decode_jobs").add()
+            reg.counter("rebuild_local_jobs").add()
+            return _PendingRow(self.codec.decode_rows(local_n, local_m, present, survivors,
+                                                [idx.index(unit.index)]),
                          lambda rows: rows[0].tobytes())
         # LRC local parity: complete its AZ's local stripe, then re-encode
         _, idx, have, survivors = gathered
@@ -1818,9 +1895,10 @@ class RepairWorker:
             self._carry_deletes(prep)
             registry("scheduler").counter("rebuild_units_committed").add()
             # the move must FREE the source: drop the superseded chunk (best
-            # effort — an unreachable/broken source just leaks until re-imaged)
+            # effort — an unreachable source just leaks until re-imaged, and a
+            # disk that is not NORMAL is not touched at all)
             old_node = self.nodes.get(unit.node_id)
-            if old_node is not None:
+            if old_node is not None and self.cm.disk_serves(unit.disk_id):
                 try:
                     old_node.drop_vuid(unit.vuid)
                 except Exception:

@@ -365,7 +365,8 @@ STAGES = frozenset((
     "codec.concat", "codec.deliver",
     "hostbatch.group", "hostbatch.launch", "hostbatch.fetch",
     "scheduler.tick", "scheduler.scrub", "scheduler.inspect",
-    "repair.gather", "repair.decode_wait", "repair.write_back", "repair.commit",
+    "repair.gather", "repair.gather_local", "repair.decode_wait",
+    "repair.write_back", "repair.commit",
 ))
 # per-shard steps, on the profiler's clock only (`mark`): six to sixteen of
 # each run per blob, and the background tick reads thousands of shards a

@@ -248,4 +248,5 @@ class ChaosScheduler:
             return
         # rebuilt from its superblock + metadb, exactly a process restart;
         # the shared nodes dict makes access/scheduler see the new engine
-        self.cluster.nodes[node] = BlobNode(node_id=node, disk_roots=roots)
+        self.cluster.nodes[node] = BlobNode(node_id=node, disk_roots=roots,
+                                            cm=self.cluster.cm)

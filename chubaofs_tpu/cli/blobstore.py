@@ -58,7 +58,13 @@ class BlobCli:
         return json.dumps(self._get("/admin/stat"), indent=2)
 
     def cmd_disk(self, verb: str = "ls", disk_id: str = "", status: str = "", *a) -> str:
-        if verb == "set":  # the operator's declaration: only `broken` is accepted
+        """`disk ls`, or `disk set ID broken`: the operator's declaration
+        (only `broken` is accepted). From the answer on the disk serves
+        nothing (its blobnode refuses every shard call, readers plan around
+        its units) and the repair worker rebuilds what it held: an LRC
+        volume's units inside their AZ, from the AZ's local stripe, and by N
+        survivors of any AZ only where that stripe has a second hole."""
+        if verb == "set":
             return json.dumps(self._post(
                 f"/admin/disk/set?disk_id={int(disk_id)}&status={status}"))
         disks = self._get("/admin/disks")
@@ -100,7 +106,10 @@ class BlobCli:
     def cmd_help(self, *a) -> str:
         return ("commands: stat | disk ls | disk set ID broken | vol ls | vol info VID | task ls | "
                 "switch ls | switch set NAME on|off | forgive | module ls | "
-                "reload | help | exit")
+                "reload | help | exit\n"
+                "disk set ID broken: the disk serves nothing from the answer on and what it held is "
+                "rebuilt (an LRC volume's units inside their AZ, from the AZ's local stripe; by N "
+                "survivors of any AZ only where that stripe has a second hole)")
 
     def dispatch(self, argv: list[str]) -> str:
         if not argv:

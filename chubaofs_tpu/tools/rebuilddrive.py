@@ -1,10 +1,12 @@
-"""Node-loss rebuild drive: load, lose a node, declare its disks broken, let the
-scheduler rebuild them under the cell's read traffic, then compare EVERY
-rebuilt shard, data and parity, with the plain reference's stripe and the unit
-map with the placement guarantees.
+"""Rebuild drive: load, lose a node (or single disks of nodes that stay up),
+declare the disks broken, let the scheduler rebuild them under the cell's read
+traffic, then compare EVERY rebuilt shard, data and parity, with the plain
+reference's stripe and the unit map with the placement guarantees.
 
     python -m chubaofs_tpu.tools.rebuilddrive --root /tmp/rebuild      # needs the TPU
     python -m chubaofs_tpu.tools.rebuilddrive --root /tmp/rebuild --jax-platform cpu --objects 6
+    python -m chubaofs_tpu.tools.rebuilddrive --root /tmp/lrc --config az2-ec16p20l2-localrepair \
+        [--disks 1:0,2:0] [--jax-platform cpu --objects 6]
 
 Layout, the node that is lost, the objects and the reader streams come from the
 benchmark's configuration and traffic file of this deployment
@@ -19,7 +21,21 @@ closed and dropped from the routing table under the daemon's runner lock, as
 chaos/scheduler.py `_kill` does. Nothing is timed for a result: the rebuild runs
 to its end, outside any window. One JSON line on stdout; exit 1 and
 `"ok": false` if a body or a rebuilt shard differs, a placement guarantee is
-broken, or a disk is not DROPPED."""
+broken, or a disk is not DROPPED.
+
+`--config az2-ec16p20l2-localrepair` (the timed cell az2.get16m-localrepair)
+takes layout, objects and the disks to declare from that configuration and its
+traffic file (`declare_broken_disks`: {node, nth}, the nth disk of the node in
+GET /admin/disks order; `--disks node:nth,...` overrides them). No engine is
+closed: the nodes stay routed and the program's own refusal of a BROKEN disk's
+I/O is what is under test. Every rebuilt shard of an LRC volume is also
+compared with benchmark/reference_local_repair.py's row, solved from the stored
+shards of the unit's OWN AZ's local stripe (in blocks of stripes); the line
+also carries `placement_violations` with the same-AZ rule, the bytes the
+rebuild read across the AZ boundary (`rebuild_cross_az_bytes`, must be 0 where
+every AZ stripe has one hole), `rebuild_local_jobs` beside
+`rebuild_decode_jobs`, the fall-backs, and what the broken disks' blobnodes
+refused."""
 
 from __future__ import annotations
 
@@ -52,8 +68,26 @@ def _bench_module(name: str):
         sys.path.remove(BENCH)
 
 
+LOCAL_BLOCK = 64  # stripes solved at once by the local reference (18 x 64 x 256 KiB = 300 MB)
+
+
+def _cell_files(config_name: str) -> tuple[dict, dict]:
+    """(configuration, traffic parameters) of the benchmark cell that runs
+    ``config_name`` (BENCHMARK.json names both files)."""
+    root = os.path.dirname(BENCH)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    entry = next(c for c in bench["configs"] if c["name"] == config_name)
+    cell = next(w for w in bench["workloads"] if w["config"] == config_name)
+    with open(os.path.join(root, entry["file"])) as f:
+        config = json.load(f)
+    with open(os.path.join(BENCH, "traffic", cell["traffic"] + ".json")) as f:
+        return config, json.load(f)["params"]
+
+
 def drive(root: str, platform: str | None, objects: int | None, node: int | None,
-          seed: int, timeout_s: float) -> dict:
+          seed: int, timeout_s: float, config_name: str = "az1-ec12p4-rebuild",
+          disks_arg: list[dict] | None = None) -> dict:
     from chubaofs_tpu import cmd
     from chubaofs_tpu.blobstore.gateway import AccessClient
     from chubaofs_tpu.codec.codemode import CodeMode
@@ -63,12 +97,12 @@ def drive(root: str, platform: str | None, objects: int | None, node: int | None
 
     reference = _bench_module("reference")
     reference_rebuild = _bench_module("reference_rebuild")
-    with open(os.path.join(BENCH, "configs", "az1-ec12p4-rebuild.json")) as f:
-        config = json.load(f)
-    with open(os.path.join(BENCH, "traffic", "get16m-rebuild.json")) as f:
-        params = json.load(f)["params"]
+    reference_local = _bench_module("reference_local_repair")
+    config, params = _cell_files(config_name)
     lay = config["layout"]
-    lost = [node] if node else config["failure"]["nodes"]
+    # single disks of nodes that stay up, or whole nodes closed
+    want_disks = disks_arg or ([] if node else params.get("declare_broken_disks", []))
+    lost = [] if want_disks else ([node] if node else config["failure"]["nodes"])
     n_objects = objects or params["objects"]
     size, streams = params["object_bytes"], params["streams"]
     device.request_platform(platform)
@@ -78,8 +112,8 @@ def drive(root: str, platform: str | None, objects: int | None, node: int | None
     if platform:
         cfg["jaxPlatform"] = platform
     daemon = cmd.start_role(cfg)
-    out: dict = {"boot": dict(daemon.boot_info), "lost_nodes": lost, "objects": n_objects,
-                 "object_bytes": size, "seed": seed}
+    out: dict = {"boot": dict(daemon.boot_info), "config": config_name, "lost_nodes": lost,
+                 "lost_disks": want_disks, "objects": n_objects, "object_bytes": size, "seed": seed}
     try:
         cluster = daemon.runner.handles["cluster"]
         bases = [np.random.default_rng([seed, 0x4EB, i]).bytes(size) for i in range(4)]
@@ -111,9 +145,21 @@ def drive(root: str, platform: str | None, objects: int | None, node: int | None
             for _ in range(params["load_streams"]):
                 pool.submit(guarded, loader)
         out["load_s"] = time.monotonic() - t0
+        admin = RPCClient([daemon.addr])
+        listed = admin.get("/admin/disks")
+        disks = [d["disk_id"] for d in listed if d["node_id"] in lost] + [
+            [d["disk_id"] for d in listed if d["node_id"] == w["node"]][w["nth"]] for w in want_disks]
         held = [(v.vid, u.index) for v in cluster.cm.volumes.values() for u in v.units
-                if u.node_id in lost]
+                if u.disk_id in disks]
+        was = {(v.vid, u.index): u.disk_id for v in cluster.cm.volumes.values() for u in v.units
+               if u.disk_id in disks}
         daemon.runner.call_with("cluster", lambda c: [c.nodes.pop(n).close() for n in lost])
+        reg, bn = registry("scheduler"), registry("blobnode")
+        series = ("repaired_shards", "rebuild_units_committed", "rebuild_decode_jobs", "rebuild_local_jobs",
+                  "rebuild_local_fallbacks", "rebuild_cross_az_bytes")
+        base = {n: reg.counter(n).value for n in series}
+        base.update({k: reg.counter("rebuild_bytes", {"kind": k}).value for k in ("read", "written")})
+        refused0 = bn.counter("io_refused", {"reason": "disk_broken"}).value
 
         # -- the damaged state reads back before the rebuild ---------------------
         c0 = AccessClient([daemon.addr])
@@ -137,8 +183,6 @@ def drive(root: str, platform: str | None, objects: int | None, node: int | None
         with ThreadPoolExecutor(streams, thread_name_prefix="reader") as pool:
             for s_ in range(streams):
                 pool.submit(guarded, reader, s_)
-            admin = RPCClient([daemon.addr])
-            disks = [d["disk_id"] for d in admin.get("/admin/disks") if d["node_id"] in lost]
             t_declared = time.monotonic()
             try:
                 out["declared"] = [admin.post(f"/admin/disk/set?disk_id={d}&status=broken")
@@ -156,9 +200,13 @@ def drive(root: str, platform: str | None, objects: int | None, node: int | None
         # no closed-set warm-up here (that is the timed cell's harness): programs
         # the rebuild's batch counts compile are only reported
         out["compiles_during_rebuild"] = registry("codec").counter("compile_total").value - compiled
-        reg = registry("scheduler")
-        out["rebuilt_shards"] = reg.counter("repaired_shards").value
-        out["units_committed"] = reg.counter("rebuild_units_committed").value
+        grown = {n: reg.counter(n).value - base[n] for n in series}
+        out["rebuilt_shards"], out["units_committed"] = grown.pop("repaired_shards"), grown.pop(
+            "rebuild_units_committed")
+        out.update(grown)
+        out["rebuild_bytes"] = {k: reg.counter("rebuild_bytes", {"kind": k}).value - base[k]
+                                for k in ("read", "written")}
+        out["io_refused_disk_broken"] = bn.counter("io_refused", {"reason": "disk_broken"}).value - refused0
         out["read_plans"] = {p: registry("access").counter("read_plan_total", {"plan": p}).value
                              for p in ("direct", "one_round", "two_round")}
 
@@ -189,15 +237,51 @@ def drive(root: str, platform: str | None, objects: int | None, node: int | None
                         bad += 1
         out["shards_compared"], out["shards_differing"] = compared, bad
         out["positions_rebuilt"] = sorted(held)
+
+        # -- an LRC volume's rebuilt rows against the local reference: each solved from
+        # the STORED shards of the unit's own AZ's local stripe, a block of stripes at once
+        local_compared = local_bad = 0
+        bids: dict[int, list[int]] = {}
+        for token in tokens:
+            for b in token.blobs:
+                bids.setdefault(b.vid, []).append(b.bid)
+        for vid, pos in sorted(held):
+            vol = cluster.cm.get_volume(vid)
+            mode = config["modes"][CodeMode(vol.code_mode).name]
+            if not mode["L"] or pos >= mode["N"] + mode["M"]:
+                continue
+            own = reference_local.local_stripe(mode, reference_local.az_of(mode, pos))
+            mine = bids.get(vid, [])
+            for lo in range(0, len(mine), LOCAL_BLOCK):
+                block = mine[lo: lo + LOCAL_BLOCK]
+                stored: list = [None] * (mode["N"] + mode["M"] + mode["L"])
+                try:
+                    for g in own:
+                        u = vol.units[g]
+                        stored[g] = b"".join(cluster.nodes[u.node_id].get_shard(u.vuid, bid) for bid in block)
+                    got, stored[pos] = stored[pos], None
+                    same = reference_local.local_rebuilt_row(stored, pos, mode, config["code"]) == got
+                except Exception as e:
+                    errors.append(f"local reference {vid}/{pos}: {type(e).__name__}: {e}")
+                    same = False
+                local_compared += len(block)
+                local_bad += 0 if same else len(block)
+        out["shards_compared_local_reference"], out["shards_differing_local_reference"] = local_compared, local_bad
         out["placement_violations"] = reference_rebuild.placement_violations(
             {v.vid: [u.disk_id for u in v.units] for v in cluster.cm.volumes.values()},
             {d.disk_id: d.status for d in cluster.cm.disks.values()})
+        az = {d.disk_id: d.az for d in cluster.cm.disks.values()}
+        for (vid, pos), old in sorted(was.items()):
+            new = cluster.cm.get_volume(vid).units[pos].disk_id
+            if az[new] != az[old]:
+                out["placement_violations"].append(
+                    f"volume {vid} position {pos} moved from AZ {az[old]} (disk {old}) to AZ {az[new]} (disk {new})")
         for i in range(n_objects):
             if c0.get(tokens[i]) != payload(i):
                 errors.append(f"object {i} differs after the rebuild")
         out["errors"] = errors[:5]
         out["ok"] = bool(
-            not errors and not bad and compared > 0
+            not errors and not bad and compared > 0 and not local_bad
             and out["rebuilt_shards"] >= compared and not out["bodies_differing"]
             and not out["placement_violations"]
             and all(s == "dropped" for s in out["disk_status"].values()))
@@ -208,12 +292,18 @@ def drive(root: str, platform: str | None, objects: int | None, node: int | None
 
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(
-        description="lose a node, declare it, rebuild it under reads, compare every rebuilt shard")
+        description="lose a node or single disks, declare them, rebuild them under reads, "
+                    "compare every rebuilt shard")
     p.add_argument("--root", required=True, help="state directory (made, must be empty)")
     p.add_argument("--jax-platform", default="",
                    help="pin the codec to a platform (cpu for the sandbox); default JAX's own")
     p.add_argument("--objects", type=int, default=0, help="default: the traffic file's")
     p.add_argument("--node", type=int, default=0, help="default: the configuration's failure.nodes")
+    p.add_argument("--config", default="az1-ec12p4-rebuild",
+                   help="a benchmark configuration with a rebuild cell (also: az2-ec16p20l2-localrepair)")
+    p.add_argument("--disks", default="",
+                   help="node:nth,... single disks to declare, their nodes staying up "
+                        "(default: the traffic file's declare_broken_disks)")
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--timeout", type=float, default=900.0, help="seconds the rebuild may take")
     args = p.parse_args(argv)
@@ -221,8 +311,9 @@ def main(argv=None) -> int:
     if os.listdir(args.root):
         print(f"--root {args.root} is not empty", file=sys.stderr)
         return 2
+    disks = [dict(zip(("node", "nth"), map(int, d.split(":")))) for d in args.disks.split(",") if d]
     out = drive(args.root, args.jax_platform or None, args.objects or None, args.node or None,
-                args.seed, args.timeout)
+                args.seed, args.timeout, args.config, disks)
     print(json.dumps(out))
     return 0 if out["ok"] else 1
 

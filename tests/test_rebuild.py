@@ -71,8 +71,10 @@ def test_configuration_is_az1_with_node_1_rebuilt():
     entry = next(c for c in bench["configs"] if c["name"] == CONFIG["name"])
     assert entry["source"] == CONFIG["source"] and len(entry["source"]) <= 200
     assert all(1 <= len(e["why"]) <= 200 for g in ("configs", "workloads") for e in bench[g])
-    assert entry == bench["configs"][-1], "appended, not inserted"
-    cell = bench["workloads"][-1]
+    # appended, not inserted: right after what the benchmark held before PR 36
+    assert [c["name"] for c in bench["configs"]].index(CONFIG["name"]) == 4
+    assert [w["name"] for w in bench["workloads"]].index("az1.get16m-rebuild") == 6
+    cell = bench["workloads"][6]
     assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
         "az1.get16m-rebuild", CONFIG["name"], "get16m-rebuild", 1)
     p = TRAFFIC["params"]

@@ -660,7 +660,7 @@ def test_drain_layer_is_the_benchmarks_entry_and_reads_a_live_service(name, cell
     entry = next(e for e in bench["per_layer"] if e["name"] == name)
     assert entry == {k: spec[k] for k in entry}
     assert (entry["workloads"], entry["moves"], entry["layer"]) == (cells, moves, "codec service")
-    assert bench["per_layer"].index(entry) >= len(bench["per_layer"]) - len(DRAIN_LAYERS), "appended"
+    assert 76 <= bench["per_layer"].index(entry) < 76 + len(DRAIN_LAYERS), "appended to PR 38's 76"
     assert set(cells) <= set(next(m for m in bench["end_to_end"] if m["name"] == moves)["workloads"])
     assert spec["params"] == {"stages": ["codec.drain"]} and "codec.drain" in trace.STAGES
     modspec = importlib.util.spec_from_file_location(
