@@ -64,6 +64,12 @@ class LocationError(AccessError):
     pass
 
 
+class BlobDeleted(AccessError):
+    """A unit's index holds the blob mark-deleted or as a tombstone: the
+    object was deleted. Not-found, at once: no degraded read, no probe, no
+    report to the repair plane (the tombstones are the durable answer)."""
+
+
 class DiskPunished(AccessError):
     """Disk is in its punish window after repeated errors/timeouts — writes
     fail fast instead of queueing behind a wedged device (stream_put.go:303-340
@@ -270,6 +276,10 @@ class Access:
         self._pipe_pool = ThreadPoolExecutor(max_workers=8,
                                              thread_name_prefix="access-pipe")
         declare_label_values("plan", READ_PLANS)
+        # the served DELETE's series, made at 0 (delete())
+        registry("access").summary("delete")
+        registry("access").counter("delete_errors")
+        trace.declare_stages(("access.delete",))
 
     # -- failure containment --------------------------------------------------
 
@@ -891,6 +901,10 @@ class Access:
                 except FutureTimeout:
                     slow.add(i)
                     continue
+                except BlobDeleted:
+                    for g in futs:  # not-found at once: nothing else is read
+                        g.cancel()
+                    raise
                 if piece is not None:
                     have[i] = piece
                     dst.put(max(offset, i * shard_len) - offset, piece)
@@ -960,7 +974,7 @@ class Access:
         self, vol: VolumeInfo, idx: int, bid: int, offset: int, size: int,
         count: bool = True,
     ) -> bytes | None:
-        from chubaofs_tpu.blobstore.blobnode import classify_io_error
+        from chubaofs_tpu.blobstore.blobnode import ShardDeleted, classify_io_error
 
         unit = vol.units[idx]
         node = self.nodes.get(unit.node_id)
@@ -981,6 +995,9 @@ class Access:
                 registry("access").counter(
                     "read_bytes", {"kind": "shards_read"}).add(size)
             return data
+        except ShardDeleted:
+            # deleted, not damaged: the read of the blob ends here
+            raise BlobDeleted(f"blob {bid} is deleted") from None
         except Exception as e:
             # the caller's contract stays None-on-failure (degraded path
             # reconstructs around it) but the CLASS of failure is no longer
@@ -1060,11 +1077,15 @@ class Access:
         # drained; the read that makes it `target` long wakes the gather
         finished: list = []
         seen, target = 0, min(needed, len(candidates))
+        deleted: list = []  # a read that found the blob deleted ends the gather
 
         def on_done(f) -> None:
             if f.cancelled():
                 return  # a straggler this gather abandoned
-            data = f.result() if f.exception() is None else None
+            exc = f.exception()
+            data = f.result() if exc is None else None
+            if isinstance(exc, BlobDeleted):
+                deleted.append(exc)
             finished.append((f, data))
             if data is None or len(finished) >= target:
                 wake.set()
@@ -1102,6 +1123,10 @@ class Access:
                 wake.set()  # they finished before the target was theirs to see
             wake.wait(max(0.0, timeout))
             wake.clear()  # before the drain: a later failure sets it again
+            if deleted:
+                for fut in pending:
+                    fut.cancel()
+                raise deleted[0]
             while seen < len(finished):
                 fut, data = finished[seen]
                 seen += 1
@@ -1343,17 +1368,22 @@ class Access:
     # -- DELETE --------------------------------------------------------------
 
     def delete(self, loc: Location | str) -> None:
-        if isinstance(loc, str):
-            loc = Location.from_json(loc)
-        self._check_sig(loc)
-        for blob in loc.blobs:
-            # write-through punch-out BEFORE the async delete fans out: once
-            # invalidate returns (however long a chaos failpoint stretches
-            # it), no cached copy is reachable — so by the time the deleter
-            # punches shards, a GET can only see the backend's truth
-            if self.cache is not None:
-                self.cache.invalidate(blob.vid, blob.bid)
-            self.proxy.send_blob_delete(blob.vid, blob.bid)
+        """Fire-and-ack (access/service.go): returns once a message for every
+        blob of the object is in the blob_delete topic; the deleter applies
+        them. cfs_access_delete_count counts the calls, cfs_access_delete_errors
+        those that raised."""
+        with trace.stage("access.delete"), registry("access").tp("delete"):
+            if isinstance(loc, str):
+                loc = Location.from_json(loc)
+            self._check_sig(loc)
+            for blob in loc.blobs:
+                # write-through punch-out BEFORE the async delete fans out: once
+                # invalidate returns (however long a chaos failpoint stretches
+                # it), no cached copy is reachable — so by the time the deleter
+                # punches shards, a GET can only see the backend's truth
+                if self.cache is not None:
+                    self.cache.invalidate(blob.vid, blob.bid)
+                self.proxy.send_blob_delete(blob.vid, blob.bid)
 
     def close(self) -> None:
         """Shut down the gateway's worker pools (racelint: unjoined-thread).

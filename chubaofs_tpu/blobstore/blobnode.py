@@ -10,7 +10,8 @@ role RocksDB plays under the reference blobnode.
 
 Layout on disk:
     <root>/superblock.json                 disk identity + chunk registry
-    <root>/chunks/<chunk_id>.data          append-only shard records
+    <root>/chunks/<chunk_id>.data          append-only shard records (extent 0)
+    <root>/chunks/<chunk_id>.x<k>.data     extent k of the same datafile (_Extents)
     <root>/metadb/                         per-disk shard index (libcfskv — the
                                            native KV engine standing in for the
                                            reference's RocksDB metadb,
@@ -30,6 +31,21 @@ sits on a datafile: there is no file position to share and nothing to flush.
 Durability is what it always was here: when put_shard returns, the record is
 in the OS (written, not fsynced) and its meta in the metadb; only compaction
 syncs, before its commit.
+
+Deletes are two phases, each a chunk's BATCH of bids in one take of the chunk
+lock and one metadb batch (blob_deleter.go: markDelete on every unit, then
+delete): `mark_delete_batch` makes the bids unreadable and durable as such,
+`delete_batch` punches their records (never before the mark is in the index)
+and leaves tombstones. Compaction copies OUTSIDE the chunk lock and takes it
+only to catch up and swap (Chunk.compact).
+
+What a delete gives back to the filesystem, and when: the punch, at once,
+where the filesystem takes it (`cfs_blobnode_punched_bytes`); where it refuses
+(`cfs_blobnode_punch_failed`: a 9p or NFS root), the record's bytes stay held
+until the EXTENT they lie in has no live record left and is unlinked (no copy;
+_Extents), or until the chunk's compaction. `Chunk.used` is the datafile's
+length, as it always was; `Chunk.held` is what the filesystem still holds of
+it, and `cfs_blobnode_released_bytes` counts every byte that really went back.
 """
 
 from __future__ import annotations
@@ -37,14 +53,19 @@ from __future__ import annotations
 import ctypes
 import json
 import os
+import re
 import struct
+import threading
+import time
+import weakref
 import zlib
 from dataclasses import dataclass
 
 from chubaofs_tpu import chaos
 from chubaofs_tpu.blobstore import trace
 from chubaofs_tpu.blobstore.clustermgr import DISK_BROKEN, DISK_NORMAL
-from chubaofs_tpu.utils import crc32block
+from chubaofs_tpu.utils import crc32block, exporter
+from chubaofs_tpu.utils.exporter import registry
 from chubaofs_tpu.utils.locks import SanitizedLock
 from chubaofs_tpu.utils.kvstore import open_kv
 
@@ -58,16 +79,24 @@ STATUS_MARK_DELETE = 2
 STATUS_DELETED = 3
 
 
-def _punch_hole(fd: int, offset: int, length: int) -> None:
+_libc = None
+
+
+def _punch_hole(fd: int, offset: int, length: int) -> bool:
     """Release a byte range back to the filesystem (core/blobfile.go:83 analog).
 
     FALLOC_FL_PUNCH_HOLE | FALLOC_FL_KEEP_SIZE; best-effort — filesystems
-    without hole support just keep the bytes until compaction."""
+    without hole support (a 9p root answers EOPNOTSUPP) just keep the bytes
+    until compaction. Says whether the filesystem took it."""
+    global _libc
     try:
-        libc = ctypes.CDLL(None, use_errno=True)
-        libc.fallocate(fd, 0x03, ctypes.c_long(offset), ctypes.c_long(length))
+        if _libc is None:
+            _libc = ctypes.CDLL(None, use_errno=True)
+            _libc.fallocate.argtypes = (ctypes.c_int, ctypes.c_int,
+                                        ctypes.c_int64, ctypes.c_int64)
+        return _libc.fallocate(fd, 0x03, offset, length) == 0
     except Exception:
-        pass
+        return False
 
 
 class BlobNodeError(Exception):
@@ -78,8 +107,18 @@ class NoSuchShard(BlobNodeError):
     pass
 
 
+class ShardDeleted(NoSuchShard):
+    """The index holds the bid MARK_DELETE or as a tombstone: deleted here,
+    not lost. A reader answers not-found on the first one it meets and
+    reports nothing to the repair plane."""
+
+
 class ChunkFull(BlobNodeError):
     pass
+
+
+class _ChunkClosed(BlobNodeError):
+    """close() or destroy() reached a chunk whose compaction was copying."""
 
 
 class DiskBroken(BlobNodeError):
@@ -121,21 +160,170 @@ class ShardMeta:
     status: int = STATUS_NORMAL
 
 
+def _record_len(meta: ShardMeta) -> int:
+    return HEADER_LEN + crc32block.encoded_len(meta.size)
+
+
+_DATA_SUFFIX = re.compile(r"(?:\.g(\d+))?(?:\.x(\d+))?\.data")
+
+
+def _parse_data_name(stem: str, fname: str) -> tuple[int, int] | None:
+    """(generation, extent) of a chunk's datafile name, None for any other
+    file: 'vuid-2560.data' is NOT a file of chunk 'vuid-256'."""
+    if not fname.startswith(stem):
+        return None
+    m = _DATA_SUFFIX.fullmatch(fname[len(stem):])
+    return None if m is None else (int(m.group(1) or 0), int(m.group(2) or 0))
+
+
+class _Extents:
+    """One generation of a chunk's datafile, as extent files.
+
+    The datafile's offsets are cut every `span` bytes: extent k holds
+    [k x span, (k+1) x span) in a file of its own, `<chunk>[.g<G>][.x<k>].data`
+    (extent 0 carries no `.x`, so a chunk smaller than one span is the one
+    file it always was), and no record straddles two: the one that would is
+    placed at the start of the next, and the rest of its extent is never
+    written. A datafile written before there were extents is a long extent 0
+    (`long0`), appended to by extents past its end.
+
+    What the cut buys: an extent whose records are all dead is UNLINKED. That
+    gives its bytes back on any filesystem and copies nothing, where a punch
+    needs the filesystem's consent and a compaction copies every live record
+    of the chunk. A retention policy expires oldest first, and a chunk is
+    appended in time order, so its dead records fill whole extents."""
+
+    def __init__(self, base: str, gen: int, span: int):
+        self.base, self.gen, self.span = base, gen, span
+        self.fds: dict[int, int] = {}  # extent -> descriptor
+        self.long0 = 0
+        self.closed = False
+
+    def path(self, k: int = 0) -> str:
+        return (self.base + (f".g{self.gen}" if self.gen else "")
+                + (f".x{k}" if k else "") + ".data")
+
+    def _files(self) -> list[tuple[int, str]]:
+        d, stem = os.path.split(self.base)
+        d = d or "."
+        got = ((_parse_data_name(stem, f), os.path.join(d, f)) for f in os.listdir(d))
+        return [(name[1], full) for name, full in got if name is not None and name[0] == self.gen]
+
+    def open_present(self) -> None:
+        for k, full in self._files():
+            self.fds[k] = os.open(full, os.O_RDWR)
+        if 0 in self.fds and os.fstat(self.fds[0]).st_size > self.span:
+            self.long0 = os.fstat(self.fds[0]).st_size
+
+    def unlink_present(self) -> None:
+        """Files of this generation that nobody holds open (a compaction that
+        failed in this process left them; one that crashed is swept on open)."""
+        for _, full in self._files():
+            os.unlink(full)
+
+    def k(self, at: int) -> int:
+        return 0 if at < self.long0 else at // self.span
+
+    def place(self, at: int, length: int) -> int:
+        """Where a record of `length` bytes goes when the datafile ends at
+        `at`: there, or at the start of the next extent if it would straddle."""
+        if length > self.span:
+            raise BlobNodeError(f"a record of {length} bytes is larger than an extent ({self.span})")
+        room = self.span - at % self.span
+        return at if length <= room else at + room
+
+    def fd(self, at: int, create: bool = False) -> tuple[int, int]:
+        """(descriptor, offset in its file) of datafile offset `at`."""
+        k = self.k(at)
+        fd = self.fds.get(k)
+        if fd is None and self.closed:
+            fd = -1  # the OS refuses it (EBADF), as any closed descriptor
+        if fd is None:
+            if not create:
+                raise BlobNodeError(f"{self.path(k)}: no such extent")
+            fd = self.fds[k] = os.open(self.path(k), os.O_RDWR | os.O_CREAT, 0o644)
+        return fd, at - (0 if k == 0 else k * self.span)
+
+    def end(self) -> int:
+        if not self.fds:
+            return 0
+        k = max(self.fds)
+        return (k * self.span if k else 0) + os.fstat(self.fds[k]).st_size
+
+    def held(self, k: int) -> int:
+        """Bytes the filesystem holds of extent k (a punched range holds none)."""
+        st = os.fstat(self.fds[k])
+        return min(st.st_size, st.st_blocks * 512)
+
+    def adopt(self, other: "_Extents") -> None:
+        """Descriptors of our own (a compaction's copy reads through them:
+        close(), destroy() and a dropped extent cannot pull them) for every
+        extent `other` has and we have not."""
+        self.long0 = other.long0
+        for k in other.fds.keys() - self.fds.keys():
+            self.fds[k] = os.dup(other.fds[k])
+
+    def sync(self) -> None:
+        for fd in self.fds.values():
+            os.fsync(fd)
+        # the new files' DIRECTORY ENTRIES must be durable before the gen bump
+        # commits, or a crash could leave a committed gen with no file
+        dfd = os.open(os.path.dirname(self.base) or ".", os.O_RDONLY)
+        try:
+            os.fsync(dfd)
+        finally:
+            os.close(dfd)
+
+    def drop(self, k: int) -> None:
+        fd = self.fds.pop(k)
+        if fd >= 0:
+            os.close(fd)
+        os.unlink(self.path(k))
+
+    def close(self) -> None:
+        """Idempotent. A descriptor's number is reused by the next open in the
+        process: each is closed once and a later call gets -1, never it."""
+        self.closed = True
+        for k, fd in self.fds.items():
+            if fd >= 0:
+                os.close(fd)
+                self.fds[k] = -1
+
+    def unlink_all(self) -> None:
+        self.closed = True
+        for k in list(self.fds):
+            try:
+                self.drop(k)
+            except OSError:
+                pass
+
+
 class Chunk:
     """One append-only chunk datafile + its shard index.
 
     Compaction is generational (core/storage compaction analog): gen G lives
-    in `<chunk>.data` (G=0) or `<chunk>.g<G>.data`; a compaction writes gen
-    G+1 fully, then commits the gen bump AND every re-offset shard meta in ONE
-    atomic metadb batch. A crash before the batch leaves gen G valid (the
-    orphan G+1 file is swept on open); after it, gen G+1 is valid and stale
+    in `<chunk>.data` (G=0) or `<chunk>.g<G>.data`, cut into extent files
+    (_Extents); a compaction writes gen G+1 fully (outside the chunk lock; see
+    `compact`), then commits the gen bump AND every re-offset shard meta in
+    ONE atomic metadb batch. A crash before the batch leaves gen G valid (the
+    orphan G+1 files are swept on open); after it, gen G+1 is valid and stale
     files are swept on open.
 
     `_lock` guards the offset reservation (`_size`), `shards`, the metadb
-    put, the descriptor and compaction's swap of it. Reads hold it through
+    put, the descriptors and compaction's swap of them. Reads hold it through
     their one positional call, so `compact` / `delete` / `destroy` / `close`
-    never pull the file (or the descriptor's number) from under a reader.
+    never pull a file (or a descriptor's number) from under a reader.
+
+    Three sizes, all in bytes of the datafile: `used` its length (what was
+    ever appended to this generation, the skipped ends of extents included),
+    `holes` the part of it no live record owns, `held` what the filesystem
+    still holds of it (`used` less what a punch, a dropped extent or a skip
+    gave back). `held - live` is dead and still paid for: what a filesystem
+    that refuses the punch keeps until the extent dies or the chunk compacts.
     """
+
+    # the datafile's cut (see _Extents); a chunk of at most one span is one file
+    EXTENT_SIZE = 64 << 20
 
     def __init__(self, path: str, chunk_id: str, max_size: int, metadb):
         self.chunk_id = chunk_id
@@ -144,63 +332,75 @@ class Chunk:
         self._idx_path = path + ".idx"  # legacy json-line WAL (migrated)
         self._db = metadb
         self._lock = SanitizedLock(name="blobnode.chunk")
+        self._compact_lock = threading.Lock()  # one compaction a chunk at a time
         self.shards: dict[int, ShardMeta] = {}
         self.gen = int(self._db.get(self._gen_key()) or 0)
-        self._data_path = self._gen_path(self.gen)
+        self._x = _Extents(path, self.gen, self.EXTENT_SIZE)
+        self._data_path = self._x.path()
         self.tombstones: set[int] = set()  # deleted bids (metadb tombstones)
         self._check_committed_gen()
         self._sweep_stale_gens()
         self._load()
-        self._fd = os.open(self._data_path, os.O_RDWR | os.O_CREAT, 0o644)
-        self._size = os.fstat(self._fd).st_size
-        # garbage metric survives restarts: everything in the file that is not
-        # a live record is punched/superseded space (compaction trigger)
-        live = sum(HEADER_LEN + crc32block.encoded_len(m.size)
-                   for m in self.shards.values())
-        self.holes = max(0, self._size - live)
+        self._x.open_present()
+        if not self._x.fds:
+            self._x.fd(0, create=True)
+        self._closed = False
+        self._size = self._x.end()
+        self._account_locked()
+
+    def _account_locked(self) -> None:
+        """From the index and the files: `holes` (everything in the datafile
+        that is not a live record: the compaction trigger survives restarts),
+        each extent's live bytes, and what the filesystem holds of each. An
+        extent with no live record (but the last) is dropped here too."""
+        self._live_in: dict[int, int] = {}  # extent -> bytes of records in `shards`
+        for m in self.shards.values():
+            k = self._x.k(m.offset)
+            self._live_in[k] = self._live_in.get(k, 0) + _record_len(m)
+        self.holes = max(0, self._size - sum(self._live_in.values()))
+        self._kept: dict[int, int] = {}  # extent -> dead bytes the filesystem still holds
+        held = 0
+        for k in sorted(self._x.fds):
+            if not self._live_in.get(k) and k != max(self._x.fds):
+                self._x.drop(k)
+                continue
+            here = self._x.held(k)
+            held += here
+            self._kept[k] = max(0, here - self._live_in.get(k, 0))
+        self.released = self._size - held  # bytes of the datafile the filesystem does not hold
+
+    @property
+    def _fd(self) -> int:
+        """-1 once closed (tests and tools look; the I/O goes through _x)."""
+        return -1 if self._closed else self._x.fds[max(self._x.fds)]
 
     def _check_committed_gen(self):
-        """Never sweep while the committed generation's datafile is missing:
-        deleting the survivors would turn a recoverable inconsistency into
-        silent data loss. (compact() fsyncs the directory before the commit,
-        so this only fires on external damage — fail loudly.)"""
-        if os.path.exists(self._data_path):
-            return
-        d = os.path.dirname(self._base_path) or "."
-        stem = os.path.basename(self._base_path)
-        others = []
-        for f in os.listdir(d):
-            # same gen-suffix filter as _sweep_stale_gens: 'vuid-2560.data' is
-            # NOT a generation of chunk 'vuid-256'
-            if not f.startswith(stem) or not f.endswith(".data"):
-                continue
-            mid = f[len(stem):-len(".data")]
-            if (mid == "" or (mid.startswith(".g") and mid[2:].isdigit())) \
-                    and os.path.join(d, f) != self._data_path:
-                others.append(f)
-        if others:
+        """Never sweep while the committed generation has no datafile and
+        another has: deleting the survivors would turn a recoverable
+        inconsistency into silent data loss. (compact() fsyncs the directory
+        before the commit, so this only fires on external damage — fail
+        loudly.)"""
+        d, stem = os.path.split(self._base_path)
+        gens = {name[0] for f in os.listdir(d or ".")
+                if (name := _parse_data_name(stem, f)) is not None}
+        if gens and self.gen not in gens:
             raise BlobNodeError(
                 f"chunk {self.chunk_id}: committed gen {self.gen} datafile "
-                f"missing but {others} exist — refusing to sweep")
+                f"missing but generations {sorted(gens)} exist — refusing to sweep")
 
     def _gen_key(self) -> bytes:
         return f"g/{self.chunk_id}".encode()
 
     def _gen_path(self, gen: int) -> str:
-        return self._base_path + (".data" if gen == 0 else f".g{gen}.data")
+        return _Extents(self._base_path, gen, self.EXTENT_SIZE).path()
 
     def _sweep_stale_gens(self):
         """Drop datafiles of any generation other than the committed one."""
-        d = os.path.dirname(self._base_path) or "."
-        stem = os.path.basename(self._base_path)
-        for fname in os.listdir(d):
-            if not fname.startswith(stem) or not fname.endswith(".data"):
-                continue
-            full = os.path.join(d, fname)
-            if full != self._data_path:
-                mid = fname[len(stem):-len(".data")]
-                if mid == "" or (mid.startswith(".g") and mid[2:].isdigit()):
-                    os.unlink(full)
+        d, stem = os.path.split(self._base_path)
+        for fname in os.listdir(d or "."):
+            name = _parse_data_name(stem, fname)
+            if name is not None and name[0] != self.gen:
+                os.unlink(os.path.join(d, fname))
 
     def _key(self, bid: int) -> bytes:
         # fixed-width decimal keeps the metadb's byte order == bid order
@@ -230,11 +430,19 @@ class Chunk:
         # STATUS_DELETED stays in the metadb as a TOMBSTONE: the volume
         # inspector must be able to tell "deleted here" from "lost here", or a
         # partially-applied blob delete would be resurrected as a repair
-        self._db.put(self._key(meta.bid), json.dumps(meta.__dict__).encode())
+        self._db.put(*self._encode(meta))
 
     @property
     def used(self) -> int:
         return self._size
+
+    @property
+    def held(self) -> int:
+        return 0 if self._closed else self._size - self.released
+
+    @property
+    def live(self) -> int:
+        return self._size - self.holes
 
     def put(self, bid: int, vuid: int, payload: bytes) -> ShardMeta:
         head = _HEADER.pack(MAGIC, bid, vuid, len(payload), 0)[:-4]
@@ -249,15 +457,21 @@ class Chunk:
     def _put_locked(self, bid: int, vuid: int, head: bytes,
                     payload: bytes) -> ShardMeta:
         length = HEADER_LEN + crc32block.encoded_len(len(payload))
-        if self._size + length > self.max_size:
+        offset = self._x.place(self._size, length)
+        if offset + length > self.max_size:
             raise ChunkFull(self.chunk_id)
         old = self.shards.get(bid)
-        offset = self._size
+        fd, local = self._x.fd(offset, create=True)
         # header + framed payload, checksummed and written in one call; on
         # return the record is in the OS, as after write + flush
         with trace.mark("chunk.write"):
-            crc32block.pwrite(self._fd, offset, payload, prefix=head)
+            crc32block.pwrite(fd, local, payload, prefix=head)
+        skipped = offset - self._size  # the end of an extent: nobody's, never written
+        self.holes += skipped
+        self.released += skipped
         self._size = offset + length
+        k = self._x.k(offset)
+        self._live_in[k] = self._live_in.get(k, 0) + length
         meta = ShardMeta(bid=bid, vuid=vuid, offset=offset, size=len(payload))
         self.shards[bid] = meta
         self.tombstones.discard(bid)  # re-put over a tombstone revives it
@@ -269,14 +483,52 @@ class Chunk:
         return meta
 
     def _punch_locked(self, meta: ShardMeta) -> None:
-        length = HEADER_LEN + crc32block.encoded_len(meta.size)
-        _punch_hole(self._fd, meta.offset, length)
+        """The record becomes a hole: cfs_blobnode_hole_bytes counts it
+        (deleted, superseded or lost), and its bytes are given back."""
+        length = _record_len(meta)
+        registry("blobnode").counter("hole_bytes").add(length)
         self.holes += length
+        self._release_locked(meta.offset, length, counted=True)
+
+    def _release_locked(self, offset: int, length: int, counted: bool) -> None:
+        """Give a dead record's bytes back to the filesystem. Where it takes
+        the punch they go at once (cfs_blobnode_punched_bytes); where it
+        refuses (cfs_blobnode_punch_failed) they stay held until the last
+        record of their extent is dead and the extent is unlinked, or the
+        chunk compacts. cfs_blobnode_released_bytes counts what went back, by
+        either way, when it went. ``counted`` False: a compaction's garbage,
+        whose record these counters met when it died; the compaction counts
+        what it wrote dead and what it gave back itself."""
+        k = self._x.k(offset)
+        if k not in self._x.fds:
+            return  # the extent is gone (damage from outside): nothing is held
+        fd, local = self._x.fd(offset)
+        punched = _punch_hole(fd, local, length)
+        back = length if punched else 0
+        if not punched:
+            self._kept[k] = self._kept.get(k, 0) + length
+        self._live_in[k] = self._live_in.get(k, 0) - length
+        dropped = self._live_in[k] <= 0 and k != max(self._x.fds)
+        if dropped:
+            self._x.drop(k)
+            back += self._kept.pop(k, 0)
+        self.released += back
+        if counted:
+            reg = registry("blobnode")
+            if punched:
+                reg.counter("punched_bytes").add(length)
+            else:
+                reg.counter("punch_failed").add()
+            if dropped:
+                reg.counter("extents_dropped").add()
+            reg.counter("released_bytes").add(back)
 
     def get(self, bid: int, offset: int = 0, size: int | None = None) -> bytes:
         with self._lock:
             meta = self.shards.get(bid)
             if meta is None or meta.status != STATUS_NORMAL:
+                if meta is not None or bid in self.tombstones:
+                    raise ShardDeleted(f"chunk {self.chunk_id} bid {bid}")
                 raise NoSuchShard(f"chunk {self.chunk_id} bid {bid}")
             if size is None:
                 size = meta.size - offset
@@ -286,83 +538,227 @@ class Chunk:
             # them is verified in the same call
             fstart, fend = crc32block.block_range(offset, size)
             fend = min(fend, crc32block.encoded_len(meta.size))
+            fd, local = self._x.fd(meta.offset)
             with trace.mark("chunk.verify"):
-                blocks = crc32block.pread(
-                    self._fd, meta.offset + HEADER_LEN + fstart, fend - fstart)
+                blocks = crc32block.pread(fd, local + HEADER_LEN + fstart, fend - fstart)
         inner = offset - (fstart // (crc32block.BLOCK_SIZE + 4)) * crc32block.BLOCK_SIZE
         return blocks[inner : inner + size]
 
+    # -- delete: two phases, a batch of bids a lock take ------------------------
+
+    def _encode(self, meta: ShardMeta, status: int | None = None) -> tuple[bytes, bytes]:
+        """The metadb entry of ``meta`` (under ``status``, where given)."""
+        entry = meta.__dict__ if status is None else {**meta.__dict__, "status": status}
+        return self._key(meta.bid), json.dumps(entry).encode()
+
+    def _held_locked(self, bids) -> list[ShardMeta]:
+        return [m for b in bids if (m := self.shards.get(b)) is not None]
+
+    def _mark_locked(self, metas: list[ShardMeta]) -> None:
+        """ONE metadb batch, written before the memory says so."""
+        if metas:
+            self._db.write_batch(
+                puts=[self._encode(m, STATUS_MARK_DELETE) for m in metas])
+            for m in metas:
+                m.status = STATUS_MARK_DELETE
+
+    def mark_delete_batch(self, bids) -> int:
+        """Phase one: the bids stop being readable, durably. Bids this chunk
+        does not hold are passed by; returns how many it marked.
+
+        Stage `chunk.delete` with the chunk lock taken inside it; the wait for
+        the lock is `chunk.delete_wait` (observed): the shard writers hold it
+        meanwhile, and what is left of the stage is how long they wait for us."""
+        with trace.stage("chunk.delete"):
+            t0 = time.perf_counter()
+            with self._lock:
+                trace.observe_stage("chunk.delete_wait", t0, time.perf_counter() - t0)
+                metas = self._held_locked(bids)
+                self._mark_locked(metas)
+                return len(metas)
+
+    def delete_batch(self, bids) -> int:
+        """Phase two: punch the records out and leave tombstones; returns how
+        many went. Nothing is released before its mark-delete is in the
+        index: a bid that reaches here unmarked (a direct delete, the
+        inspector finishing a partial one) is marked in the same take first,
+        so a crash between the punch and the tombstone reopens a shard that
+        is not served and is punched again on replay."""
+        with trace.stage("chunk.delete"):
+            t0 = time.perf_counter()
+            with self._lock:
+                trace.observe_stage("chunk.delete_wait", t0, time.perf_counter() - t0)
+                metas = self._held_locked(bids)
+                if not metas:
+                    return 0
+                self._mark_locked([m for m in metas if m.status != STATUS_MARK_DELETE])
+                chaos.failpoint("blobnode.delete_punch")
+                for m in metas:
+                    self._punch_locked(m)
+                # STATUS_DELETED stays in the metadb as a TOMBSTONE (_log_idx)
+                self._db.write_batch(
+                    puts=[self._encode(m, STATUS_DELETED) for m in metas])
+                for m in metas:
+                    m.status = STATUS_DELETED
+                    self.tombstones.add(m.bid)
+                    del self.shards[m.bid]
+                return len(metas)
+
     def mark_delete(self, bid: int):
-        with self._lock:
-            meta = self.shards.get(bid)
-            if meta is None:
-                raise NoSuchShard(f"chunk {self.chunk_id} bid {bid}")
-            meta.status = STATUS_MARK_DELETE
-            self._log_idx(meta)
+        if not self.mark_delete_batch((bid,)):
+            raise NoSuchShard(f"chunk {self.chunk_id} bid {bid}")
 
     def delete(self, bid: int):
         """Punch-hole delete: release the record's bytes, drop the index entry."""
-        with self._lock:
-            meta = self.shards.get(bid)
-            if meta is None:
-                raise NoSuchShard(f"chunk {self.chunk_id} bid {bid}")
-            self._punch_locked(meta)
-            meta.status = STATUS_DELETED
-            self._log_idx(meta)
-            self.tombstones.add(meta.bid)
-            del self.shards[meta.bid]
+        if not self.delete_batch((bid,)):
+            raise NoSuchShard(f"chunk {self.chunk_id} bid {bid}")
+
+    # -- compaction: copy outside the lock, catch up and swap under it ----------
+
+    # what one take of the chunk lock may copy: records appended while the
+    # copy ran are caught up outside it, round by round, until the tail is
+    # at most this (or CATCH_UP_ROUNDS have run: a chunk written faster than
+    # it is copied must still end)
+    SWAP_TAIL_MAX = 4 << 20
+    CATCH_UP_ROUNDS = 6
+
+    @staticmethod
+    def _copy_records(src: _Extents, dst: _Extents, metas, at: int) -> tuple[list, int]:
+        """Copy whole records (header + framed payload) to ``dst`` from
+        offset ``at`` on; -> ([(source meta, new offset)], the new end)."""
+        placed = []
+        for meta in metas:
+            length = _record_len(meta)
+            fd, local = src.fd(meta.offset)
+            record = crc32block.pread_exact(fd, length, local)
+            at = dst.place(at, length)
+            fd, local = dst.fd(at, create=True)
+            crc32block.pwrite_all(fd, record, local)
+            placed.append((meta, at))
+            at += length
+        return placed, at
 
     def compact(self) -> int:
         """Rewrite the datafile keeping only live records; returns bytes
         reclaimed. Crash-safe via the generational commit described on the
-        class docstring."""
-        with self._lock:
-            new_gen = self.gen + 1
-            new_path = self._gen_path(new_gen)
-            new_metas: list[ShardMeta] = []
-            new_fd = os.open(new_path, os.O_RDWR | os.O_CREAT | os.O_TRUNC, 0o644)
+        class docstring.
+
+        The chunk lock is NOT held while the live records are copied and the
+        new files are synced (stage `chunk.compact`): PUTs, GETs and deletes of
+        the chunk go on against generation G. The lock is taken three times:
+        to snapshot the index (descriptors of its own for the copy: close(),
+        destroy() and a dropped extent cannot pull them), for each catch-up
+        round's listing, and last for the swap (stage `chunk.compact_swap`):
+        the records appended since the last listing are copied (at most
+        SWAP_TAIL_MAX unless the rounds ran out) and synced, every snapshot
+        record is looked up again (deleted or superseded meanwhile: its copy
+        is garbage in G+1, punched there; marked meanwhile: the mark goes with
+        it), then the gen bump and every re-offset meta commit in ONE metadb
+        batch and the descriptors are swapped. One compaction a chunk at a
+        time."""
+        with self._compact_lock, trace.stage("chunk.compact"):
+            src = _Extents(self._base_path, self.gen, self.EXTENT_SIZE)
+            with self._lock:
+                if self._closed:
+                    return 0
+                new = _Extents(self._base_path, self.gen + 1, self.EXTENT_SIZE)
+                todo = self._listed_from(0)
+                seen_to = self._size  # records below it have been listed
+                src.adopt(self._x)
+            placed: list[tuple[ShardMeta, int]] = []
+            new_size = 0
             try:
-                new_size = 0
-                for bid, meta in sorted(self.shards.items(),
-                                        key=lambda kv: kv[1].offset):
-                    length = HEADER_LEN + crc32block.encoded_len(meta.size)
-                    record = crc32block.pread_exact(self._fd, length, meta.offset)
-                    crc32block.pwrite_all(new_fd, record, new_size)
-                    new_metas.append(ShardMeta(bid=bid, vuid=meta.vuid,
-                                               offset=new_size,
-                                               size=meta.size,
-                                               status=meta.status))
-                    new_size += length
-                os.fsync(new_fd)
-                # the new file's DIRECTORY ENTRY must be durable before the gen
-                # bump commits, or a crash could leave a committed gen with no file
-                dfd = os.open(os.path.dirname(new_path) or ".", os.O_RDONLY)
-                try:
-                    os.fsync(dfd)
-                finally:
-                    os.close(dfd)
-                # commit point: gen bump + every re-offset meta, atomically.
-                # Tombstones are RETAINED: they are cluster-level delete intent
-                # ("deleted here, not lost"), not file-local garbage — purging
-                # them would let the inspector resurrect a partially-deleted blob
-                puts = [(self._gen_key(), str(new_gen).encode())]
-                puts += [(self._key(m.bid), json.dumps(m.__dict__).encode())
-                         for m in new_metas]
-                self._db.write_batch(puts=puts)
+                new.unlink_present()
+                for rounds_left in range(self.CATCH_UP_ROUNDS - 1, -1, -1):
+                    got, new_size = self._copy_records(src, new, todo, new_size)
+                    placed += got
+                    chaos.failpoint("blobnode.compact_copy")
+                    with self._lock:
+                        self._raise_if_closed()
+                        if not rounds_left or self._size - seen_to <= self.SWAP_TAIL_MAX:
+                            break  # the swap copies what is left, under the lock
+                        todo = self._listed_from(seen_to)
+                        seen_to = self._size
+                        src.adopt(self._x)
+                if not new.fds:
+                    new.fd(0, create=True)  # nothing live: an empty generation
+                new.sync()
+                with trace.stage("chunk.compact_swap"), self._lock:
+                    return self._swap_locked(new, placed, new_size, seen_to)
+            except _ChunkClosed:
+                # close() or destroy() came first: nothing is committed, and
+                # nobody will open this chunk to sweep the orphans
+                new.unlink_all()
+                return 0
             except BaseException:
-                os.close(new_fd)  # the orphan file is swept on the next open
+                new.close()  # the orphan files are swept on the next open
                 raise
-            old_path, old_size = self._data_path, self._size
-            os.close(self._fd)
-            self.gen = new_gen
-            self._data_path = new_path
-            self._fd = new_fd
-            self._size = new_size
-            self.shards = {m.bid: m for m in new_metas}
-            self.holes = 0
-            if old_path != new_path:
-                os.unlink(old_path)
-            return old_size - self._size
+            finally:
+                src.close()
+
+    def _listed_from(self, offset: int) -> list[ShardMeta]:
+        """Copies of the index entries at or past ``offset``, in file order
+        (under the lock: the copy that follows reads them outside it)."""
+        return sorted((ShardMeta(**m.__dict__) for m in self.shards.values()
+                       if m.offset >= offset), key=lambda m: m.offset)
+
+    def _raise_if_closed(self) -> None:
+        if self._closed:
+            raise _ChunkClosed(f"chunk {self.chunk_id} closed under its compaction")
+
+    def _swap_locked(self, new: _Extents, placed: list, new_size: int, seen_to: int) -> int:
+        self._raise_if_closed()
+        # what was appended since the last listing: copied under the lock
+        got, end = self._copy_records(self._x, new, self._listed_from(seen_to), new_size)
+        if got:
+            new.sync()  # no record is less durable in G+1 than it was in G
+        new_metas: list[ShardMeta] = []
+        garbage = []
+        for old, at in placed + got:
+            cur = self.shards.get(old.bid)
+            if cur is None or cur.offset != old.offset:
+                # deleted, lost or re-put while it was copied: its copy is
+                # nobody's record in the new files
+                garbage.append(ShardMeta(bid=old.bid, vuid=old.vuid, offset=at, size=old.size))
+                continue
+            new_metas.append(ShardMeta(bid=cur.bid, vuid=cur.vuid, offset=at,
+                                       size=cur.size, status=cur.status))
+        chaos.failpoint("blobnode.compact_commit")
+        # commit point: gen bump + every re-offset meta, atomically.
+        # Tombstones are RETAINED: they are cluster-level delete intent
+        # ("deleted here, not lost"), not file-local garbage — purging
+        # them would let the inspector resurrect a partially-deleted blob
+        puts = [(self._gen_key(), str(new.gen).encode())]
+        puts += [self._encode(m) for m in new_metas]
+        self._db.write_batch(puts=puts)
+        old_x, old_size, old_dead_held = self._x, self._size, self.held - self.live
+        self.gen = new.gen
+        self._x = new
+        self._data_path = new.path()
+        self._size = end
+        self.shards = {m.bid: m for m in new_metas}
+        old_x.unlink_all()
+        # the new generation's account: the skipped ends of its extents were
+        # never written; its garbage is given back where it can be
+        self.holes = end - sum(_record_len(m) for m in new_metas)
+        self.released = self.holes - sum(_record_len(g) for g in garbage)
+        self._live_in, self._kept = {}, {}
+        for m in new_metas + garbage:
+            k = new.k(m.offset)
+            self._live_in[k] = self._live_in.get(k, 0) + _record_len(m)
+        for g in garbage:
+            self._release_locked(g.offset, _record_len(g), counted=False)
+        # what went back: the dead bytes the old files still held, and the
+        # garbage (dead bytes this compaction wrote itself: counted as such,
+        # so that holes made + garbage = given back + dead and still held)
+        wrote_dead = sum(_record_len(g) for g in garbage)
+        reg = registry("blobnode")
+        reg.counter("compact_bytes", {"kind": "garbage"}).add(wrote_dead)
+        reg.counter("released_bytes").add(old_dead_held + wrote_dead - (self.held - self.live))
+        reg.counter("compact_total").add()
+        reg.counter("compact_bytes", {"kind": "copied"}).add(end)
+        reg.counter("compact_bytes", {"kind": "reclaimed"}).add(max(0, old_size - end))
+        return old_size - end
 
     def tombstone(self, bid: int):
         """Record delete intent for a bid this chunk never stored (migrations
@@ -394,28 +790,21 @@ class Chunk:
         """Delete the chunk outright: datafile, shard metas, tombstones, gen
         marker. Used when a volume unit is re-homed off this disk."""
         with self._lock:
-            self._close_fd_locked()
             keys = [k for k, _ in self._db.scan(
                 prefix=f"s/{self.chunk_id}/".encode())]
             keys.append(self._gen_key())
             self._db.write_batch(deletes=keys)
-            try:
-                os.unlink(self._data_path)
-            except OSError:
-                pass
+            self._closed = True
+            self._x.unlink_all()
             self.shards.clear()
             self.tombstones.clear()
 
-    def _close_fd_locked(self):
+    def close(self):
         """Idempotent, under the lock: a descriptor's number is reused by the
         next open in the process, so it is closed once and never read after."""
-        fd, self._fd = self._fd, -1
-        if fd >= 0:
-            os.close(fd)
-
-    def close(self):
         with self._lock:
-            self._close_fd_locked()
+            self._closed = True
+            self._x.close()
 
 
 class Disk:
@@ -475,13 +864,35 @@ class Disk:
         return {
             "disk_id": self.disk_id,
             "chunks": len(self.chunks),
+            # the datafiles' lengths (KEEP_SIZE leaves a length as it was,
+            # and a dropped extent does too) ...
             "used": sum(c.used for c in self.chunks.values()),
+            # ... and what the filesystem holds of them: less every byte a
+            # punch, a dropped extent or a compaction really gave back
+            "held": sum(c.held for c in self.chunks.values()),
         }
 
     def close(self):
         for c in self.chunks.values():
             c.close()
         self.metadb.close()
+
+
+_NODES: "weakref.WeakSet[BlobNode]" = weakref.WeakSet()
+
+
+def _collect_held() -> None:
+    """cfs_blobnode_held_bytes: what the filesystem holds of every open
+    chunk's datafile in this process, summed at the scrape (a level: it falls
+    when space really comes back, which `used`, a sum of lengths, never does)."""
+    held = 0
+    for node in list(_NODES):
+        for disk in list(node.disks.values()):
+            held += sum(c.held for c in list(disk.chunks.values()))
+    registry("blobnode").gauge("held_bytes").set(held)
+
+
+exporter.add_collector(_collect_held)
 
 
 class BlobNode:
@@ -510,6 +921,14 @@ class BlobNode:
         from chubaofs_tpu.utils.exporter import registry as _registry
 
         self._reg = _registry("blobnode")
+        # the reclaim plane's counters, made here at 0: a reader of "nothing
+        # was punched" must find a series that says 0, not none
+        for name in ("shard_delete", "hole_bytes", "punched_bytes", "punch_failed",
+                     "released_bytes", "extents_dropped", "compact_total"):
+            self._reg.counter(name)
+        _NODES.add(self)  # cfs_blobnode_held_bytes sums them at the scrape
+        for kind in ("copied", "reclaimed", "garbage"):
+            self._reg.counter("compact_bytes", {"kind": kind})
         self._iostat = None
         if iostat:
             from chubaofs_tpu.blobstore.iostat import IOStat
@@ -720,8 +1139,23 @@ class BlobNode:
         self._chunk(vuid).mark_delete(bid)
 
     def delete_shard(self, vuid: int, bid: int) -> None:
+        if not self.delete_shards(vuid, (bid,)):
+            raise NoSuchShard(f"vuid {vuid} bid {bid}")
+
+    def mark_delete_shards(self, vuid: int, bids) -> int:
+        """Phase one of a delete for a unit's BATCH of bids: one take of the
+        chunk lock, one metadb batch. Returns how many the chunk held."""
         self._refuse_unless_normal(vuid)
-        self._chunk(vuid).delete(bid)
+        return self._chunk(vuid).mark_delete_batch(bids)
+
+    def delete_shards(self, vuid: int, bids) -> int:
+        """Phase two for the batch: punch out, tombstone. Returns how many
+        records went (cfs_blobnode_shard_delete counts them)."""
+        self._refuse_unless_normal(vuid)
+        n = self._chunk(vuid).delete_batch(bids)
+        if n:
+            self._reg.counter("shard_delete").add(n)
+        return n
 
     def list_shards(self, vuid: int) -> list[ShardMeta]:
         self._refuse_unless_normal(vuid)
@@ -771,19 +1205,39 @@ class BlobNode:
 
     # -- background hygiene (core compaction + datainspect.go analogs) -------
 
-    def compact_once(self, min_hole_ratio: float = 0.25,
-                     min_holes: int = 1 << 20) -> int:
-        """Compact every chunk whose punched-hole share crosses the threshold;
-        returns total bytes reclaimed."""
-        reclaimed = 0
+    # The compaction rule, a function of a chunk's bytes alone. A chunk is
+    # rewritten when it is (a) LARGE and MOSTLY EMPTY: where punch-out has
+    # given a deleted record's blocks back at once, what a compaction buys is
+    # a shorter file and a smaller index, not free space, and at a hole share
+    # of 0.8 it copies at most one byte for every four it drops. Upstream's
+    # blobnode (core/chunk compaction, its configuration's
+    # compact_empty_rate_threshold 0.8 with compact_min_size_threshold 16 GiB,
+    # its chunk size; or a file past compact_trigger_threshold 1 TiB) compacts
+    # a chunk only once it is full-sized and four fifths empty; a chunk here
+    # is 1 GiB, so "large" is half of that. Or (b) it HOLDS more dead bytes
+    # than live ones: on a filesystem that refuses the punch a dead record is
+    # space still to reclaim, what its dying extents have not given back
+    # (Chunk.held - Chunk.live) is what a compaction frees, and it copies at
+    # most one byte for every byte it frees.
+    COMPACT_MIN_HOLE_RATIO = 0.8
+    COMPACT_MIN_DEAD_HELD = 64 << 20
+
+    def compaction_candidates(self):
+        """The chunks the rule picks now, one after the other (a caller that
+        compacts one and looks again sees the rule applied afresh)."""
         for disk in self.disks.values():
             if not self._serves(disk.disk_id):
                 continue
             for chunk in list(disk.chunks.values()):
-                if chunk.used and chunk.holes >= min_holes and \
-                        chunk.holes / chunk.used >= min_hole_ratio:
-                    reclaimed += chunk.compact()
-        return reclaimed
+                dead_held = chunk.held - chunk.live
+                if (chunk.used >= chunk.max_size // 2
+                        and chunk.holes >= self.COMPACT_MIN_HOLE_RATIO * chunk.used) \
+                        or (dead_held >= self.COMPACT_MIN_DEAD_HELD and dead_held >= chunk.live):
+                    yield chunk
+
+    def compact_once(self) -> int:
+        """Compact every chunk the rule picks; returns total bytes reclaimed."""
+        return sum(chunk.compact() for chunk in self.compaction_candidates())
 
     def inspect_once(self) -> list[tuple[int, int]]:
         """CRC scrub (blobnode/datainspect.go): re-read every live shard
@@ -799,6 +1253,8 @@ class BlobNode:
                     continue
                 try:
                     chunk.get(meta.bid)
+                except NoSuchShard:
+                    pass  # deleted since it was listed: not a finding
                 except Exception:
                     bad.append((vuid, meta.bid))
         return bad
@@ -863,6 +1319,8 @@ class BlobNode:
                 # bitrot — repairing shard-by-shard off a dying device
                 # would fight the disk-repair migration
                 pass
+            except NoSuchShard:
+                pass  # deleted since it was listed (the deleter runs beside the scrub): not bitrot
             except Exception:
                 bad.append((vuid, bid))
             scanned += 1

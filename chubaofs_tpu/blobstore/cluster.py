@@ -16,7 +16,7 @@ from chubaofs_tpu.blobstore.access import Access
 from chubaofs_tpu.blobstore.blobnode import BlobNode
 from chubaofs_tpu.blobstore.clustermgr import ClusterMgr
 from chubaofs_tpu.blobstore.proxy import Proxy
-from chubaofs_tpu.blobstore.scheduler import RepairWorker, Scheduler
+from chubaofs_tpu.blobstore.scheduler import Reclaimer, RepairWorker, Scheduler
 from chubaofs_tpu.codec.service import CodecService
 
 
@@ -61,26 +61,31 @@ class MiniCluster:
         self.scheduler = Scheduler(self.cm, self.proxy, self.nodes,
                                    codec=self.codec, cache=self.cache)
         self.worker = RepairWorker(self.scheduler, self.nodes, codec=self.codec)
+        self.reclaimer = Reclaimer(self.scheduler, self.nodes)
 
     def background_tick(self) -> dict:
         """What the daemon's ticker runs: one tick of every background loop
-        with the tasks HANDED to the repair worker's own thread, never waited
-        for. A disk rebuild outlasts any tick; heartbeats, lease reaping, the
-        deleter and compaction do not queue behind it, and neither does
-        whoever waits for the lock the tick runs under."""
+        with the tasks HANDED to the repair worker's own thread and the
+        deleter and compaction to the reclaim plane's, never waited for. A
+        disk rebuild outlasts any tick and a retention policy deletes all day;
+        heartbeats and lease reaping do not queue behind either, and neither
+        does whoever waits for the lock the tick runs under."""
         return self._tick(wait=False)
 
     def run_background_once(self) -> dict:
         """One tick driven to quiescence, for in-process callers (tests, the
         soak, tools): the same steps, with the worker's thread joined before
         the hygiene steps, so that when it returns every task the tick made
-        has run."""
+        has run, the blob_delete topic is empty and every chunk the rule
+        picks is compacted."""
         return self._tick(wait=True)
 
     def _tick(self, wait: bool) -> dict:
         """One tick of every background loop (the 16-ticker scheduleTask analog):
         detection first (heartbeats, heartbeat expiry, lease reaping, the
-        budgeted scrub), then the task planes, then host-local hygiene."""
+        budgeted scrub), then the task planes. Repair tasks, the deleter and
+        host-local hygiene (compaction) are other threads' work: kicked here,
+        joined only by the in-process driver (``wait``)."""
         # heartbeats are per-node daemon work: a dead/closed engine simply
         # stops beating, which IS the signal the expiry below consumes
         for n in list(self.nodes.values()):
@@ -98,20 +103,15 @@ class MiniCluster:
         tier_msgs = self.scheduler.run_tier()
         disk_tasks = self.scheduler.check_disks()
         balance_task = self.scheduler.check_balance()
+        ran = deleted = compacted = 0
         if wait:
             ran = self.worker.wait_idle()
+            compacted0 = self.reclaimer.compacted
+            deleted = self.reclaimer.wait_idle()
+            compacted = self.reclaimer.compacted - compacted0
         else:
-            ran = 0
             self.worker.kick()
-        deleted = self.scheduler.run_deleter()
-        # compaction is host-local work: a dark/dead node skips its own sweep
-        # without stalling the cluster's (the daemon analog runs it per host)
-        compacted = 0
-        for n in self.nodes.values():
-            try:
-                compacted += n.compact_once()
-            except Exception:
-                pass
+            self.reclaimer.kick()
         return {
             "inspect_msgs": inspected,
             "repair_msgs": polled,
@@ -128,6 +128,7 @@ class MiniCluster:
 
     def close(self):
         self.worker.close()  # first: a migrate in flight stops between stripes
+        self.reclaimer.close()
         if self._owns_codec:  # never kill a shared/injected service
             self.codec.close()
         self.access.close()

@@ -28,6 +28,7 @@ class TopicQueue:
         self._msgs: list[dict] = []
         self._offsets: dict[str, int] = {}
         self._path = path
+        self._listeners: list = []  # called after every produce, outside the lock
         if path:
             os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
             if os.path.exists(path):
@@ -45,6 +46,13 @@ class TopicQueue:
             if self._f:
                 self._f.write(json.dumps(msg) + "\n")
                 self._f.flush()
+        for wake in self._listeners:
+            wake()
+
+    def subscribe(self, wake) -> None:
+        """``wake()`` runs after every produce (a consumer's own thread is
+        woken by it: no consumer polls a topic on a timer)."""
+        self._listeners.append(wake)
 
     def consume(self, group: str, max_msgs: int = 64) -> list[dict]:
         with self._lock:
@@ -130,7 +138,14 @@ class Proxy:
         self.topics[TOPIC_SHARD_REPAIR].produce(msg)
 
     def send_blob_delete(self, vid: int, bid: int) -> None:
-        self.topics[TOPIC_BLOB_DELETE].produce({"vid": vid, "bid": bid})
+        # `ts`: when the DELETE was taken (wall clock: the topic outlives the
+        # process); the deleter's apply lag is measured from it
+        self.topics[TOPIC_BLOB_DELETE].produce(
+            {"vid": vid, "bid": bid, "ts": time.time()})
+
+    def delete_backlog(self) -> int:
+        """Messages of the blob_delete topic the deleter has not committed."""
+        return self.topics[TOPIC_BLOB_DELETE].lag("deleter")
 
     def send_blob_hot(self, vid: int, bid: int, size: int) -> None:
         """Heat signal from the cache plane: this blob crossed the promote

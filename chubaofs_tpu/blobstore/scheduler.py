@@ -15,7 +15,10 @@ via HTTPTaskAcquire, service.go:84, repair tasks served first). Shapes kept:
     share one matrix, so their jobs batch by content on the device
     (SURVEY §3.5's bulk-repair config);
   * tasks run on the worker's own thread (RepairWorker.kick): a rebuild that
-    outlasts a background tick never holds the tick.
+    outlasts a background tick never holds the tick;
+  * the blob deleter and chunk compaction run on the reclaim plane's own
+    thread (Reclaimer), woken by the blob_delete topic itself: a DELETE is
+    applied as fast as the topic fills, never once a tick.
 """
 
 from __future__ import annotations
@@ -32,7 +35,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from chubaofs_tpu.blobstore import trace
-from chubaofs_tpu.blobstore.blobnode import BlobNode, classify_io_error
+from chubaofs_tpu.blobstore.blobnode import BlobNode, NoSuchShard, ShardDeleted, classify_io_error
 from chubaofs_tpu.blobstore.clustermgr import (
     DISK_DROPPED,
     DISK_NORMAL,
@@ -68,6 +71,11 @@ _PRIORITY = [KIND_SHARD_REPAIR, KIND_DISK_REPAIR, KIND_DISK_DROP,
              KIND_BALANCE, KIND_TIER_PROMOTE, KIND_TIER_DEMOTE]
 
 _TASK_STATES = (TASK_PREPARED, TASK_WORKING, TASK_FINISHED, TASK_FAILED)
+
+# cfs_scheduler_delete_blobs{result}: `ok` every unit of the blob took both
+# phases (or held nothing of it); `partial` a unit could not be reached (a
+# dark node, a disk held BROKEN): the inspector or the rebuild finishes it
+DELETE_RESULTS = ("ok", "partial")
 
 
 def stage_overlap_ratio(stages) -> float | None:
@@ -165,6 +173,11 @@ class Scheduler:
         for name in ("rebuild_local_jobs", "rebuild_cross_az_bytes",
                      "rebuild_local_fallbacks"):
             registry("scheduler").counter(name)
+        # the deleter's, likewise: blobs by how their delete ended, and the
+        # blob_delete topic's depth
+        for result in DELETE_RESULTS:
+            registry("scheduler").counter("delete_blobs", {"result": result})
+        registry("scheduler").gauge("delete_backlog").set(proxy.delete_backlog())
 
     # -- task table (persisted in the clustermgr config KV, the reference's
     # migrate-task tables in clustermgr: migrate.go:346-347) -------------------
@@ -400,6 +413,11 @@ class Scheduler:
                         continue
                     try:
                         node.get_shard(unit.vuid, bid)  # full CRC-framed read
+                    except ShardDeleted:
+                        # deleted since this volume was listed (the deleter
+                        # runs beside this sweep): not damage, no report
+                        bad = []
+                        break
                     except Exception:
                         bad.append(idx)
                 if bad:
@@ -711,16 +729,26 @@ class Scheduler:
 
     # -- blob deleter ---------------------------------------------------------
 
-    def run_deleter(self, max_msgs: int = 64) -> int:
-        """Consume delete messages -> mark-delete then punch-hole on blobnodes
-        (blob_deleter.go two-phase analog)."""
+    def run_deleter(self, max_msgs: int = 512, pool=None) -> int:
+        """One drain of the blob_delete topic (blob_deleter.go's two phases):
+        up to ``max_msgs`` messages are grouped by the unit that holds them,
+        then EVERY unit mark-deletes its batch of bids (one take of the chunk
+        lock, one metadb batch), and only then every unit deletes it (punch
+        out, tombstones): mark on every unit before the first punch, as
+        upstream. The units run side by side on ``pool`` (the Reclaimer's;
+        None: on the caller's thread). Returns the messages consumed."""
         from chubaofs_tpu.blobstore.taskswitch import SWITCH_BLOB_DELETE
 
+        reg = registry("scheduler")
         if not self.switches.enabled(SWITCH_BLOB_DELETE):
+            reg.gauge("delete_backlog").set(self.proxy.delete_backlog())
             return 0
         topic = self.proxy.topics[TOPIC_BLOB_DELETE]
         msgs = topic.consume("deleter", max_msgs)
-        for m in msgs:
+        if not msgs:
+            reg.gauge("delete_backlog").set(0)
+            return 0
+        with trace.stage("deleter.batch"):
             # a deleted blob leaves EVERY tier. Order matters on a daemon,
             # where GETs serve CONCURRENTLY with this loop: (1) note the
             # delete so an in-flight tier promote re-checks it, (2) drop
@@ -730,26 +758,60 @@ class Scheduler:
             # under the post-bump version, and nothing would ever evict
             # those bytes again (the gateway's own delete() already did the
             # pre-delete write-through invalidation for its clients)
-            key = (m["vid"], m["bid"])
+            keys = [(m["vid"], m["bid"]) for m in msgs]
             with self._lock:
-                self._deleted_recent[key] = None
+                for key in keys:
+                    self._deleted_recent[key] = None
                 while len(self._deleted_recent) > 4096:
                     self._deleted_recent.popitem(last=False)
-            self._drop_hot_copy(*key)
-            vol = self.cm.get_volume(m["vid"])
-            for unit in vol.units:
-                node = self.nodes.get(unit.node_id)
-                if node is None:
-                    continue
-                try:
-                    node.mark_delete_shard(unit.vuid, m["bid"])
-                    node.delete_shard(unit.vuid, m["bid"])
-                except Exception:
-                    pass  # already gone or never written; repair owns the rest
-            if self.cache is not None:
-                self.cache.invalidate(*key)
-        topic.commit("deleter", len(msgs))
+            by_unit: dict[tuple[int, int], list[int]] = {}  # (node, vuid) -> bids
+            units_of: dict[int, list] = {}
+            for vid, bid in keys:
+                self._drop_hot_copy(vid, bid)
+                if vid not in units_of:
+                    try:
+                        units_of[vid] = [(u.node_id, u.vuid) for u in self.cm.get_volume(vid).units]
+                    except Exception:
+                        units_of[vid] = []  # an unknown volume holds nothing
+                for unit in units_of[vid]:
+                    by_unit.setdefault(unit, []).append(bid)
+            missed = self._each_unit(by_unit, "mark_delete_shards", pool)
+            missed |= self._each_unit(by_unit, "delete_shards", pool)
+            done = time.time()
+            for m, (vid, bid) in zip(msgs, keys):
+                if self.cache is not None:
+                    self.cache.invalidate(vid, bid)
+                partial = any(u in missed for u in units_of[vid])
+                reg.counter("delete_blobs", {"result": DELETE_RESULTS[partial]}).add()
+                if "ts" in m and not partial:
+                    # the DELETE's acknowledgement -> its last unit punched
+                    lag = max(0.0, done - m["ts"])
+                    trace.observe_stage("deleter.apply", time.perf_counter() - lag, lag)
+            topic.commit("deleter", len(msgs))
+        reg.gauge("delete_backlog").set(topic.lag("deleter"))
         return len(msgs)
+
+    def _each_unit(self, by_unit: dict, phase: str, pool) -> set:
+        """One phase of a drain on every unit's batch; -> the units that could
+        not take it (no such node, a disk the cluster manager holds BROKEN,
+        an I/O error: the repair plane owns what they keep)."""
+        def one(unit, bids):
+            node = self.nodes.get(unit[0])
+            if node is None:
+                return unit
+            try:
+                getattr(node, phase)(unit[1], bids)
+            except NoSuchShard:
+                return None  # the unit has no chunk: it holds nothing of them
+            except Exception:
+                return unit
+            return None
+
+        if pool is None:
+            out = [one(u, b) for u, b in by_unit.items()]
+        else:
+            out = [f.result() for f in [pool.submit(one, u, b) for u, b in by_unit.items()]]
+        return {u for u in out if u is not None}
 
     def _recently_deleted(self, vid: int, bid: int) -> bool:
         with self._lock:
@@ -874,7 +936,81 @@ class _PendingRow:
         return self.cut(self.fut.result())
 
 
-class RepairWorker:
+class _OwnThread:
+    """A background plane's own thread: kick() wakes it, it calls _drain()
+    until a drain that began after the newest kick has ended, wait_idle() is
+    the in-process driver's join. Started by the first kick; close() stops it."""
+
+    thread_name = "worker"
+
+    def __init__(self):
+        self._stop = threading.Event()
+        self._state = threading.Condition()
+        self._thread: threading.Thread | None = None
+        self._kicks = 0  # drains asked for
+        self._served = 0  # the newest kick a finished drain had seen
+        self._ran = 0  # what the drains have counted (tasks, messages)
+
+    def _drain(self) -> None:
+        """One drain; calls _count() for what wait_idle() reports."""
+        raise NotImplementedError
+
+    def _count(self, n: int = 1) -> None:
+        with self._state:
+            self._ran += n
+
+    def kick(self) -> None:
+        """Wake the thread: it drains until nothing is left."""
+        with self._state:
+            self._kicks += 1
+            if self._thread is None and not self._stop.is_set():
+                self._thread = threading.Thread(
+                    target=self._loop, daemon=True, name=self.thread_name)
+                self._thread.start()
+            self._state.notify_all()
+
+    def wait_idle(self) -> int:
+        """kick(), then wait until a drain that began after this call has
+        ended; returns what the drains counted meanwhile. The in-process
+        driver's join (MiniCluster.run_background_once): the daemon's tick
+        never calls it."""
+        with self._state:
+            ran0 = self._ran
+        self.kick()
+        with self._state:
+            want = self._kicks
+            while self._served < want and not self._stop.is_set():
+                self._state.wait(0.5)
+            return self._ran - ran0
+
+    def _loop(self) -> None:
+        while True:
+            with self._state:
+                while self._served == self._kicks and not self._stop.is_set():
+                    self._state.wait()
+                if self._stop.is_set():
+                    return
+                seen = self._kicks
+            try:
+                self._drain()
+            except Exception:
+                # a drain records its own failures; what reaches here is the
+                # plane's own (a cm closing under a reload)
+                registry("scheduler").counter("worker_loop_errors").add()
+            with self._state:
+                self._served = seen
+                self._state.notify_all()
+
+    def _join(self) -> None:
+        self._stop.set()
+        with self._state:
+            self._state.notify_all()
+            thread = self._thread
+        if thread is not None:
+            thread.join(timeout=5)
+
+
+class RepairWorker(_OwnThread):
     """Executes repair/migrate tasks with batched TPU reconstructs.
 
     Reference: blobnode's embedded worker (task_runner.go:171,
@@ -926,12 +1062,7 @@ class RepairWorker:
             max_workers=16, thread_name_prefix="repair-io")
         # the worker's own thread (started by the first kick): it drains the
         # task table whenever a kick is newer than the last drain it finished
-        self._stop = threading.Event()
-        self._state = threading.Condition()
-        self._thread: threading.Thread | None = None
-        self._kicks = 0  # drains asked for
-        self._served = 0  # the newest kick a finished drain had seen
-        self._ran = 0  # tasks the thread has run
+        super().__init__()
         # (task id, lease, monotonic time of its last renewal) of the migrate
         # the calling thread runs, for _keepalive
         self._lease: tuple[str, int, float] | None = None
@@ -956,61 +1087,19 @@ class RepairWorker:
         reaper or a restart requeues it, and idempotent write-back makes the
         re-execution safe. wait=False mirrors Access.close — a read wedged on
         a dead node must not stall teardown; it fails on its own deadline."""
-        self._stop.set()
-        with self._state:
-            self._state.notify_all()
-            thread = self._thread
-        if thread is not None:
-            thread.join(timeout=5)
+        self._join()
         self._stripe_pool.shutdown(wait=False)
         self._shard_pool.shutdown(wait=False)
 
     # -- the worker's own thread ------------------------------------------------
 
-    def kick(self) -> None:
-        """Wake the worker's thread: it runs tasks until none is left."""
-        with self._state:
-            self._kicks += 1
-            if self._thread is None and not self._stop.is_set():
-                self._thread = threading.Thread(
-                    target=self._loop, daemon=True, name="repair-worker")
-                self._thread.start()
-            self._state.notify_all()
+    thread_name = "repair-worker"
 
-    def wait_idle(self) -> int:
-        """kick(), then wait until a drain that began after this call has run
-        out of tasks; returns how many tasks ran meanwhile. The in-process
-        driver's join (MiniCluster.run_background_once): the daemon's tick
-        never calls it."""
-        with self._state:
-            ran0 = self._ran
-        self.kick()
-        with self._state:
-            want = self._kicks
-            while self._served < want and not self._stop.is_set():
-                self._state.wait(0.5)
-            return self._ran - ran0
-
-    def _loop(self) -> None:
-        while True:
-            with self._state:
-                while self._served == self._kicks and not self._stop.is_set():
-                    self._state.wait()
-                if self._stop.is_set():
-                    return
-                seen = self._kicks
-            ran = 0
-            try:
-                while not self._stop.is_set() and self.run_once():
-                    ran += 1
-            except Exception:
-                # run_once records task failures on the task; what reaches
-                # here is the plane's own (a cm closing under a reload)
-                registry("scheduler").counter("worker_loop_errors").add()
-            with self._state:
-                self._served = seen
-                self._ran += ran
-                self._state.notify_all()
+    def _drain(self) -> None:
+        """Run tasks until none is left (run_once records a task's failure
+        on the task)."""
+        while not self._stop.is_set() and self.run_once():
+            self._count()
 
     def _keepalive(self) -> None:
         """Between two stripes of a migrate: stop if the worker is closing,
@@ -1910,3 +1999,71 @@ class RepairWorker:
             exclude=vol_disks | {source_disk_id},
             az=self.cm.disks[source_disk_id].az,
         )
+
+
+class Reclaimer(_OwnThread):
+    """The reclaim plane's own worker: the blob deleter (blob_deleter.go's
+    Kafka consumer) and, behind it, each node's chunk compaction.
+
+    Woken by the background tick's kick (a backlog a restart found, a switch
+    released) and, in a daemon, by the blob_delete topic itself (follow_topic:
+    every produce kicks; an in-process driver steps it by
+    MiniCluster.run_background_once instead, so a test decides when a delete
+    is applied). A drain empties the topic (Scheduler.run_deleter, its units
+    side by side on the `reclaim-io` pool) and compacts what the rule picks
+    (BlobNode.compaction_candidates; a compaction copies outside its chunk's lock),
+    one chunk between two looks at the topic. Neither the tick nor the lock
+    a tick runs under ever waits for either, and with nothing to delete or
+    compact the thread sleeps."""
+
+    thread_name = "reclaim-worker"
+    IO_WORKERS = 8  # a drain's units side by side: lock, index batch and punches release the interpreter lock
+
+    def __init__(self, sched: Scheduler, nodes: dict[int, BlobNode]):
+        super().__init__()
+        self.sched = sched
+        self.nodes = nodes
+        self.compacted = 0  # bytes the drains' compactions reclaimed
+        self._pool = ThreadPoolExecutor(
+            max_workers=self.IO_WORKERS, thread_name_prefix="reclaim-io")
+        trace.declare_stages(("deleter.batch", "deleter.apply", "chunk.delete", "chunk.delete_wait",
+                              "chunk.compact", "chunk.compact_swap"))
+
+    def follow_topic(self) -> None:
+        """From now on every message produced to blob_delete wakes the
+        thread: a DELETE is applied as fast as the topic fills, with no tick
+        to wait for. What the daemon does at boot."""
+        self.sched.proxy.topics[TOPIC_BLOB_DELETE].subscribe(self.kick)
+        self.kick()  # and whatever a restart found there
+
+    def _drain(self) -> None:
+        """Until the topic is empty and the rule picks no chunk: the deleter
+        first, and ONE chunk's compaction between two looks at the topic (a
+        volume's sixteen chunks cross the rule together; their copies in a
+        row would hold the deleter for longer than a DELETE may wait)."""
+        while not self._stop.is_set():
+            n = self.sched.run_deleter(pool=self._pool)
+            if n:
+                self._count(n)
+            elif not self._compact_one():
+                break
+
+    def _compact_one(self) -> bool:
+        # compaction is host-local work: a dark/dead node skips its own sweep
+        # without stalling the cluster's (the daemon analog runs it per host)
+        for node in list(self.nodes.values()):
+            try:
+                chunk = next(node.compaction_candidates(), None)
+                got = chunk.compact() if chunk is not None else 0
+            except Exception:
+                continue
+            if got:
+                self.compacted += got
+                return True
+        return False
+
+    def close(self) -> None:
+        """Stop the thread (a drain in flight ends after its batch; what it
+        had not committed is consumed again: both phases are idempotent)."""
+        self._join()
+        self._pool.shutdown(wait=False)

@@ -353,7 +353,7 @@ def current_span() -> Span | None:
 
 # <layer>.<what>; each name is used on one kind of thread only (HTTP worker,
 # access-pipe stage, access write worker, codec dispatcher, background tick,
-# repair worker and its stripe pool),
+# repair worker and its stripe pool, reclaim worker and its pool),
 # so a name also says which thread. The set is closed: it is the declared
 # value set of the counter's `stage` label (exporter._check_bounded).
 STAGES = frozenset((
@@ -367,6 +367,14 @@ STAGES = frozenset((
     "scheduler.tick", "scheduler.scrub", "scheduler.inspect",
     "repair.gather", "repair.gather_local", "repair.decode_wait",
     "repair.write_back", "repair.commit",
+    # the served DELETE (HTTP worker) and the reclaim plane (its own worker
+    # and pool): one drain of the blob_delete topic, a DELETE's
+    # acknowledgement -> its last unit punched (observed, no thread's), one
+    # chunk's batch of a phase and, inside it, its wait for the chunk lock
+    # (observed: where the deleter and the writers meet), a compaction's copy
+    # outside the chunk lock and its catch-up + swap under it
+    "access.delete", "deleter.batch", "deleter.apply", "chunk.delete",
+    "chunk.delete_wait", "chunk.compact", "chunk.compact_swap",
 ))
 # per-shard steps, on the profiler's clock only (`mark`): six to sixteen of
 # each run per blob, and the background tick reads thousands of shards a
@@ -386,6 +394,15 @@ def _stage_summary(name: str):
     s = _stage_summaries[name] = exporter.registry("trace").summary(
         "stage_seconds", {"stage": name})
     return s
+
+
+def declare_stages(names) -> None:
+    """Make the stages' counters now, at 0: a reader of "this never ran"
+    must find a series that says 0, not none (a stage's counter is otherwise
+    made by its first run)."""
+    for name in names:
+        if name not in _stage_summaries:
+            _stage_summary(name)
 
 
 def observe_stage(name: str, start: float, dur: float,
@@ -475,13 +492,13 @@ class stage:
 
 # -- CPU by thread role: who ran, read where /metrics is rendered -----------------
 
-# The closed value set of cfs_proc_cpu_seconds' `role` label. The first six
+# The closed value set of cfs_proc_cpu_seconds' `role` label. The first seven
 # are the daemon's hot threads by the names the code gives them; `other` is
 # every other Python thread (in a benchmark cell the harness's main thread,
 # a reload, the sampling profiler's); `native` is the process's CPU clock
 # minus all of those: threads Python does not own (PJRT and the TPU runtime,
 # the jax profiler's collectors).
-ROLES = ("loop", "request", "io", "codec", "tick", "repair", "other", "native")
+ROLES = ("loop", "request", "io", "codec", "tick", "repair", "reclaim", "other", "native")
 # thread-name prefix -> role, first match: the one mapping the counter's label
 # and the sampling profiler's role totals (utils/profiler.py) both read
 _ROLE_OF_PREFIX = (
@@ -493,6 +510,7 @@ _ROLE_OF_PREFIX = (
     ("codec-svc", "codec"),  # the one dispatcher
     ("blobstore-bg", "tick"),
     ("repair-", "repair"),  # repair-worker, repair-stripe*, repair-io*
+    ("reclaim-", "reclaim"),  # reclaim-worker (deleter, compaction), reclaim-io*
 )
 
 

@@ -762,9 +762,12 @@ class BlobstoreDaemon(_Daemon):
         runner = ModuleRunner(cfg=dict(cfg))
 
         def up_cluster(c, handles):
-            return MiniCluster(c["root"], n_nodes=int(c.get("nodes", 6)),
-                               disks_per_node=int(c.get("disksPerNode", 2)),
-                               azs=int(c.get("azs", 1)))
+            cluster = MiniCluster(c["root"], n_nodes=int(c.get("nodes", 6)),
+                                  disks_per_node=int(c.get("disksPerNode", 2)),
+                                  azs=int(c.get("azs", 1)))
+            # a served DELETE is applied as the topic fills, not once a tick
+            cluster.reclaimer.follow_topic()
+            return cluster
 
         def up_gateway(c, handles):
             host, port = _addr_split(c.get("listen", "127.0.0.1:0"))
@@ -789,8 +792,9 @@ class BlobstoreDaemon(_Daemon):
 
         # under the runner lock, so a tick can never race a concurrent
         # reload's teardown of the cluster it is sweeping. Repair tasks run on
-        # the worker's own thread (background_tick hands them over): a disk
-        # rebuild never holds this lock
+        # the worker's own thread, the deleter and compaction on the reclaim
+        # plane's (background_tick hands them over): neither a disk rebuild
+        # nor a day's expiry ever holds this lock
         with trace.stage("scheduler.tick"):
             self.runner.call_with("cluster", lambda c: c.background_tick())
 
@@ -800,6 +804,7 @@ class BlobstoreDaemon(_Daemon):
         cluster = self.runner.handles.get("cluster")
         if cluster is not None:
             cluster.worker.close()
+            cluster.reclaimer.close()
         super().stop()
         self.runner.stop()
 

@@ -21,7 +21,7 @@ from chubaofs_tpu.utils import exporter, profiler
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "benchmark")
-PYTHON_ROLES = ("loop", "request", "io", "codec", "tick", "repair", "other")
+PYTHON_ROLES = ("loop", "request", "io", "codec", "tick", "repair", "reclaim", "other")
 ROLE_PCT = PYTHON_ROLES[:5]  # the roles the PUT and GET cells report as % of one core
 
 pytestmark = pytest.mark.skipif(not hasattr(time, "pthread_getcpuclockid"),
@@ -87,7 +87,7 @@ class Worker:
 
 def test_role_set_is_the_rendered_label_set():
     mapped = {role for _, role in trace._ROLE_OF_PREFIX}
-    assert mapped | {"other", "native"} == set(trace.ROLES) and len(set(trace.ROLES)) == 8
+    assert mapped | {"other", "native"} == set(trace.ROLES) and len(set(trace.ROLES)) == 9
     rendered = {k.split('"')[1] for k in scrape() if k.startswith("cfs_proc_cpu_seconds{")}
     assert rendered == set(trace.ROLES)
 
@@ -98,6 +98,7 @@ def test_role_set_is_the_rendered_label_set():
     ("access-read_15", "io"), ("access_3", "io"), ("access-probe_0", "io"),
     ("access-probe-io_2", "io"), ("codec-svc", "codec"), ("blobstore-bg", "tick"),
     ("repair-worker", "repair"), ("repair-stripe_1", "repair"), ("repair-io_9", "repair"),
+    ("reclaim-worker", "reclaim"), ("reclaim-io_3", "reclaim"),
     ("MainThread", "other"), ("cfs-prof-cont", "other"), ("blobstore-reload", "other"),
     ("Thread-4 (serve)", "other"), ("access", "other"), ("", "other"), ("?", "other"),
 ])
@@ -358,11 +359,12 @@ def test_mark_and_observed_stage_read_no_cpu_under_a_session(session):
         a.get('cfs_trace_stage_seconds_count{stage="codec.queue_wait"}', 0.0) + 1
 
 
-# -- the eighteen layer files -------------------------------------------------------
+# -- the eighteen layer files, and PR 45's three -------------------------------------------------------
 
 PUT_CELLS = ["az1.put16m", "az3.put16m", "az2.put16m"]
 GET_CELLS = ["az1.get16m-nodedown", "az2.get16m-azdown", "az1.get16m-rebuild"]
 SMALL = ["az1.small-open"]
+EXPIRE = ["az1.put16m-expire"]  # PR 45: its own sum of NINE roles, and the new role's share
 LAYERS = (
     [("host_cpu_cores", PUT_CELLS, "put_MBps"), ("get_host_cpu_cores", GET_CELLS, "get_MBps"),
      ("small_host_cpu_cores", SMALL, "op_p95_ms")]
@@ -372,15 +374,22 @@ LAYERS = (
        ("rebuild_cpu_repair_pct", ["az1.get16m-rebuild"], "get_MBps"),
        ("codec_dispatch_cpu_share", PUT_CELLS, "put_MBps"),
        ("get_codec_dispatch_cpu_share", GET_CELLS, "get_MBps"),
-       ("get_decode_wait_cpu_ms", ["az2.get16m-azdown", "az1.get16m-rebuild"], "get_MBps")])
+       ("get_decode_wait_cpu_ms", ["az2.get16m-azdown", "az1.get16m-rebuild"], "get_MBps"),
+       ("expire_host_cpu_cores", EXPIRE, "put_MBps"), ("expire_cpu_tick_pct", EXPIRE, "put_MBps"),
+       ("expire_cpu_reclaim_pct", EXPIRE, "put_MBps")])
 # what the daemon of the parent commit renders: stages and jobs, no CPU series
 PARENT = {"cfs_codec_jobs_total": 90.0,
           **{'cfs_trace_stage_seconds_%s{stage="%s"}' % (kind, s): 1.0
              for kind in ("sum", "count") for s in trace.STAGES}}
 
 
-def test_there_are_eighteen():
-    assert len(LAYERS) == len({n for n, _, _ in LAYERS}) == 18
+def test_there_are_eighteen_and_the_expiry_cells_three():
+    assert len(LAYERS) == len({n for n, _, _ in LAYERS}) == 18 + 3
+    # the eight-role sums of the older cells are files this PR may not edit: the new role is
+    # summed in the new cell's file alone, and reads ~0 where the reclaim worker sleeps
+    assert layer("expire_host_cpu_cores")["params"]["num"] == [role_series(r) for r in trace.ROLES]
+    for name in ("host_cpu_cores", "get_host_cpu_cores", "small_host_cpu_cores"):
+        assert layer(name)["params"]["num"] == [role_series(r) for r in trace.ROLES if r != "reclaim"]
 
 
 @pytest.mark.parametrize("name,cells,moves", LAYERS)

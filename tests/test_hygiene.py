@@ -89,13 +89,17 @@ def test_compaction_crash_before_commit_is_swept(tmp_path, rng):
 
 def test_compact_once_threshold(tmp_path, rng):
     node = BlobNode(node_id=1, disk_roots=[str(tmp_path / "d0")])
+    for disk in node.disks.values():
+        disk.chunk_size = 64 << 10  # eight records make the chunk "large"
     node.create_vuid(3)
     for bid in range(8):
         node.put_shard(3, bid, blob_bytes(rng, 4096))
-    assert node.compact_once(min_holes=1) == 0  # no holes yet
+    assert node.compact_once() == 0  # no holes yet
     for bid in range(6):
         node.delete_shard(3, bid)
-    assert node.compact_once(min_hole_ratio=0.25, min_holes=1) > 0
+    assert node.compact_once() == 0  # three quarters empty: not yet
+    node.delete_shard(3, 6)
+    assert node.compact_once() > 0  # large and more than four fifths empty
     node.close()
 
 
@@ -169,9 +173,8 @@ def test_deleter_then_compaction_shrinks_chunks(tmp_path, rng):
         c.access.delete(loc)
         stats = c.run_background_once()
         assert stats["deletes"] >= 1
-        # force-compact regardless of ratio thresholds
-        reclaimed = sum(n.compact_once(min_hole_ratio=0.0, min_holes=1)
-                        for n in c.nodes.values())
+        # force-compact regardless of the rule
+        reclaimed = sum(c.nodes[u.node_id]._chunk(u.vuid).compact() for u in vol.units)
         assert reclaimed > 0
         used_after = sum(
             c.nodes[u.node_id]._chunk(u.vuid).used for u in vol.units)
