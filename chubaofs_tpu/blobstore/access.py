@@ -385,10 +385,19 @@ class Access:
         """Submit one blob to the codec service; returns the stripe future.
         One composed-matrix device pass yields global AND local parity."""
         shard_len = t.shard_size(len(blob))
-        mat = np.zeros((t.N, shard_len), np.uint8)
-        flat = mat.reshape(-1)
-        flat[: len(blob)] = np.frombuffer(blob, np.uint8)
-        return self.codec.encode_tactic(t, mat)
+        # the blob's bytes go, once, into the data rows of a slot the codec
+        # lends: the job runs where they lie and the stripe is a view of the
+        # same slot. What the blob leaves of the data rows is data and is
+        # zeroed; past shard_len the slot's columns are nobody's
+        rows = self.codec.slot(t.N, shard_len, t.total - t.N)[: t.N, :shard_len]
+        src = np.frombuffer(blob, np.uint8)
+        full, rest = divmod(len(blob), shard_len)
+        rows[:full] = src[: full * shard_len].reshape(full, shard_len)
+        if full < t.N:
+            rows[full, :rest] = src[full * shard_len:]
+            rows[full, rest:] = 0
+            rows[full + 1:] = 0
+        return self.codec.encode_tactic(t, rows)
 
     def _write_blob(self, t, mode: int, vol: VolumeInfo, bid: int,
                     stripe: np.ndarray) -> VolumeInfo:
@@ -1240,8 +1249,7 @@ class Access:
         if len(got) < t.N:
             return False  # the full path re-proves and reports damage
         present = sorted(got)[: t.N]
-        survivors = np.stack(
-            [np.frombuffer(got[i], np.uint8) for i in present])
+        survivors = self.codec.slot_of([got[i] for i in present])
         with trace.stage("access.decode_wait"):  # row-sliced window decode
             rows = self.codec.decode_rows(t.N, t.M, present, survivors,
                                           need).result()

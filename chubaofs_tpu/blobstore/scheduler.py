@@ -1341,16 +1341,16 @@ class RepairWorker(_OwnThread):
         stripe position `index` (Tactic.local_stripes)."""
         return next(ls for ls in t.local_stripes() if index in ls[0])
 
-    @staticmethod
-    def _local_rows(idx: list[int], local_n: int, reads: dict):
+    def _local_rows(self, idx: list[int], local_n: int, reads: dict):
         """The first local_n rows of the AZ stripe `idx` that `reads` holds, in
         stripe order, for CodecService.decode_rows(local_n, local_m, ...):
         (their positions in the LOCAL stripe's own coordinates, (local_n, k)
-        bytes). Every stripe of a unit has the same rows where nothing else
-        is damaged, and with them the same decode matrix."""
+        bytes in a slot the codec lends). Every stripe of a unit has the same
+        rows where nothing else is damaged, and with them the same decode
+        matrix."""
         srv = [g for g in idx if g in reads][:local_n]
-        return [idx.index(g) for g in srv], np.stack(
-            [np.frombuffer(reads[g], np.uint8) for g in srv])
+        return [idx.index(g) for g in srv], self.codec.slot_of(
+            [reads[g] for g in srv])
 
     def _repair_global(self, vol: VolumeInfo, t, bid: int):
         """Global-stripe repair + recompute of any missing local parities."""
@@ -1682,8 +1682,7 @@ class RepairWorker(_OwnThread):
                     f"stripe {vol.vid}/{bid}: {len(reads)} < N={t.N} readable")
             present = sorted(reads)
             self._count_rebuild_reads(vol, unit, reads)
-            return present, np.stack(
-                [np.frombuffer(reads[i], np.uint8) for i in present])
+            return present, self.codec.slot_of([reads[i] for i in present])
 
     def _gather_local_rows(self, vol: VolumeInfo, t, unit, bid: int, span=None):
         """Local-stripe-first (work_shard_recover.go:517 recoverByLocalStripe,

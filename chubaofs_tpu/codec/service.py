@@ -7,11 +7,27 @@ each call pays host->device latency, and small stripes underfill the MXU. This
 service is the TPU-native replacement:
 
   * callers submit encode/repair jobs (numpy matrices) and get futures back;
-  * a dispatcher thread drains the queue, groups jobs by (layout, k-bucket),
-    pads each shard length up to the bucket, stacks them into one (B, n, k)
-    device batch, runs ONE fused-kernel call, then scatters results back;
+  * a dispatcher thread drains the queue, groups jobs by (matrix, k-bucket),
+    runs ONE fused-kernel call over the group's (B, n, bucket) batch, then
+    hands each job its rows back;
   * shard lengths are bucketed to powers of two (>= 16 KiB) so the jit cache
     stays small and the MXU sees few distinct shapes;
+  * a job's bytes live in ONE pooled, bucket-wide buffer (a slot) from
+    submission to delivery. Nobody pads: a GF matmul is column by column and
+    every result is cut back to the job's true length, so the columns past
+    it are never written, zeroed or read. The caller that builds a job's
+    input asks for the slot (`slot` / `slot_of`) and writes its rows there;
+    one that brings its own array has them copied in, once (the snapshot).
+    One job is launched where it lies; two and more are copied into a pooled
+    batch buffer (the dispatcher stacks). An encode's fetched parity rows go
+    into the tail rows of the job's own slot and the future gets a VIEW of
+    the slot, the stripe, so nothing is concatenated; a decode's future gets
+    its rows as a view of the fetched array, so nothing is copied at all. A
+    buffer is owned by whoever still holds an array that views it: it
+    returns to the pool when the last such array dies (`_Pool`), never by a
+    caller's say-so.
+    cfs_codec_buffer_total{kind="slot"|"batch", result="reused"|"fresh"}
+    counts every buffer taken;
   * the lowering is the process's, decided once from the resolved backend
     (rs.lowering): the compiled fused kernel on a TPU, the XLA einsum when CPU
     was asked for (tests). A backend that fails to initialise fails the job;
@@ -30,9 +46,13 @@ cfs_codec_batch_close_total{close="empty"|"full"|"held"} says how each batch clo
 
 from __future__ import annotations
 
+import math
+import mmap
 import queue
 import threading
 import time
+import weakref
+from collections import OrderedDict, deque
 from concurrent.futures import Future, InvalidStateError
 from dataclasses import dataclass, field
 
@@ -71,11 +91,15 @@ class _ChainFuture(Future):
 @dataclass
 class _Job:
     kind: str  # "encode" | "matmul"
-    n: int
-    m: int
-    data: np.ndarray  # (rows, kb) uint8 — PRE-PADDED to the shape bucket
-    k: int  # true shard length (result is sliced back to it)
-    kb: int  # bucket_len(k), computed at submission
+    n: int  # input rows: buf[:n] is what the launch reads
+    rows: int  # result rows the caller gets (a padded decode matrix makes more)
+    buf: np.ndarray  # (>= n, kb) uint8: the job's pooled slot
+    k: int  # true shard length (nothing at or past this column means anything)
+    kb: int  # bucket_len(k): the slot's width
+    # the future gets a stripe: the result rows are written to buf[n : n + rows]
+    # and buf[: n + rows] is the result. Else the result rows alone, a view of
+    # the fetched array
+    whole: bool = False
     future: Future = field(default_factory=Future)
     # the job's matrix as the launch needs it (rs.MatrixPlan): its key groups
     # the batch, its operand is resident on the device after the first batch
@@ -88,24 +112,102 @@ class _Job:
     # as named stages, so a PUT's critical-path report splits encode wait
     span: object | None = None
     t_submit: float = 0.0  # perf_counter at _submit: codec.queue_wait starts
-    # result rows the caller asked for, where decode_rows padded the matrix
-    # past them to ride a resident program; None: all of them
-    rows: int | None = None
 
 
-def _pad_to_bucket(data: np.ndarray, k: int, kb: int) -> np.ndarray:
-    """Pad (rows, k) up to (rows, kb) on the SUBMITTING thread — the drain
-    loop then only stacks, and padding cost parallelizes across callers
-    instead of serializing on the dispatcher."""
-    if k == kb:
-        return np.ascontiguousarray(data, np.uint8)
-    out = np.zeros((data.shape[0], kb), np.uint8)
-    out[:, :k] = data
-    return out
+class _Raw(mmap.mmap):
+    """A pooled buffer's memory: anonymous pages, mapped at first touch and
+    kept mapped while the pool or a borrower holds it. The type is the mark
+    by which the service knows an array it lent (`_lent`)."""
+
+    def __new__(cls, nbytes: int):
+        raw = super().__new__(cls, -1, nbytes,
+                              flags=mmap.MAP_PRIVATE | mmap.MAP_ANONYMOUS)
+        if nbytes >= 1 << 22:  # numpy's own rule for what it allocates
+            raw.madvise(mmap.MADV_HUGEPAGE)
+        return raw
+
+
+def _address(a: np.ndarray) -> int:
+    return a.__array_interface__["data"][0]
+
+
+def _lent(a: np.ndarray) -> np.ndarray | None:
+    """The owning array of the pooled buffer `a` views, or None."""
+    owner = a.base
+    raw = getattr(getattr(owner, "base", None), "obj", None)
+    return owner if isinstance(raw, _Raw) else None
+
+
+class _Pool:
+    """Buffers by shape, lent as plain arrays and taken back by reference
+    counting: a buffer is idle again when the array `take` made and every
+    view of it are dead (numpy views hold their owner), so a straggler that
+    still reads a result keeps exactly its own bytes. `take` never blocks
+    and never fails: with nothing idle of a shape it maps a new buffer. Idle
+    bytes are bounded by 4 * max_batch * the largest slot seen (a queue's
+    worth of jobs in flight, as many results still being read, and a batch
+    buffer, which is under two max_batch slots); past that the shapes taken
+    longest ago are unmapped first. What dies between two takes
+    waits, uncounted, for the next."""
+
+    def __init__(self):
+        self._lock = SanitizedLock(name="codec.pool")
+        # shape -> idle memory, the shape taken longest ago first
+        self._idle: OrderedDict[tuple, list[_Raw]] = OrderedDict()
+        self._idle_bytes = 0
+        self._largest_slot = 0
+        self._limit = 0
+        # what the finalizers hand back: they run wherever the last view
+        # dies (any thread, any moment), so they take no lock and only append
+        self._back: deque[tuple[tuple, _Raw]] = deque()
+        reg = registry("codec")
+        self._taken = {(kind, result): reg.counter(
+            "buffer_total", {"kind": kind, "result": result})
+            for kind in ("slot", "batch") for result in ("reused", "fresh")}
+
+    def take(self, kind: str, shape: tuple, max_batch: int) -> np.ndarray:
+        nbytes = math.prod(shape)
+        with self._lock:
+            if kind == "slot":
+                self._largest_slot = max(self._largest_slot, nbytes)
+            self._limit = 4 * max_batch * self._largest_slot
+            self._sweep_locked()
+            idle = self._idle.get(shape)
+            raw = idle.pop() if idle else None
+            if raw is not None:
+                self._idle_bytes -= nbytes
+                self._idle.move_to_end(shape)
+            self._trim_locked()
+        self._taken[kind, "fresh" if raw is None else "reused"].add()
+        if raw is None:
+            raw = _Raw(nbytes)
+        owner = np.frombuffer(raw, np.uint8)
+        weakref.finalize(owner, self._back.append, (shape, raw)).atexit = False
+        return owner.reshape(shape)
+
+    def _sweep_locked(self) -> None:
+        while self._back:
+            shape, raw = self._back.popleft()
+            self._idle.setdefault(shape, []).append(raw)
+            self._idle_bytes += len(raw)
+
+    def _trim_locked(self) -> None:
+        while self._idle_bytes > self._limit:
+            shape, idle = next(iter(self._idle.items()))
+            if idle:
+                self._idle_bytes -= len(idle.pop())
+            if not idle:
+                del self._idle[shape]
+
+    def idle_bytes(self) -> int:
+        with self._lock:
+            self._sweep_locked()
+            self._trim_locked()
+            return self._idle_bytes
 
 
 class CodecService:
-    """Queue -> padded device batches -> futures. Thread-safe, one device stream."""
+    """Queue -> bucket-wide device batches -> futures. Thread-safe, one device stream."""
 
     def __init__(self, max_batch: int = 32, max_wait_ms: float = 0.0,
                  mesh=None, mesh_interpret: bool = False):
@@ -138,6 +240,7 @@ class CodecService:
         # row counts decode_rows has run, by (survivors, bucket): the families
         # of compiled decode programs this process holds
         self._decode_rows_run: dict[tuple[int, int], set[int]] = {}
+        self._pool = _Pool()
 
     def _ensure_started(self):
         with self._lock:
@@ -149,16 +252,58 @@ class CodecService:
 
     # -- public API --------------------------------------------------------
 
+    def slot(self, n: int, k: int, rows: int = 0) -> np.ndarray:
+        """Lend the buffer of a job of n input rows of k bytes: a
+        (n + rows, bucket_len(k)) uint8 array out of the pool, its bytes
+        whatever the last borrower left. An encode asks for room for its
+        `rows` parity rows, and its stripe is then a view of the same slot.
+        The caller writes slot[:n, :k], all of it, and submits that view as
+        the job's data: the job runs where the bytes lie. Nothing at or past
+        column k needs writing. Nothing is given back by hand: the slot is
+        the pool's again when the last array viewing it (this one, the
+        stripe, a row of it) is dead."""
+        return self._pool.take("slot", (n + rows, bucket_len(k)), self.max_batch)
+
+    def slot_of(self, payloads) -> np.ndarray:
+        """The equal-length byte strings `payloads` (a stripe's survivors, in
+        the decode's row order) as the rows of a lent slot: the (n, k) data
+        view to submit."""
+        n, k = len(payloads), len(payloads[0])
+        data = self.slot(n, k)[:, :k]
+        for row, payload in zip(data, payloads):
+            row[:] = (payload if isinstance(payload, np.ndarray)
+                      else np.frombuffer(payload, np.uint8))
+        return data
+
+    def _job(self, kind: str, data: np.ndarray, rows: int,
+             whole: bool = False, **kw) -> Future:
+        """Queue a job over data (n, k) with `rows` result rows. A view a
+        caller filled in a lent slot (with room for the parity, where the
+        result is the `whole` stripe) is the job's buffer as it stands; any
+        other array is copied into a slot, once, unpadded: the snapshot the
+        result is built from, whatever the caller does to its array later."""
+        data = np.asarray(data, np.uint8)
+        n, k = data.shape
+        kb = bucket_len(k)
+        room = rows if whole else 0
+        owner = _lent(data)
+        if (owner is not None and data.strides == (kb, 1)
+                and owner.size >= (n + room) * kb
+                and _address(data) == _address(owner)):
+            buf = owner.reshape(-1, kb)
+        else:
+            buf = self.slot(n, k, room)
+            buf[:n, :k] = data
+        job = _Job(kind, n, rows, buf, k, kb, whole, **kw)
+        self._submit(job)
+        return job.future
+
     def encode(self, n: int, m: int, data: np.ndarray) -> Future:
         """data (n, k) uint8 -> Future[(n+m, k) uint8 full stripe]."""
         if data.shape[0] != n:
             raise ValueError(f"want {n} data rows, got {data.shape}")
-        k = data.shape[1]
-        kb = bucket_len(k)
-        job = _Job("encode", n, m, _pad_to_bucket(data, k, kb), k, kb,
-                   plan=rs.get_kernel(n, m).parity_plan)
-        self._submit(job)
-        return job.future
+        return self._job("encode", data, m, whole=True,
+                         plan=rs.get_kernel(n, m).parity_plan)
 
     def matmul(self, mat: np.ndarray, data: np.ndarray) -> Future:
         """Generic GF(2^8) matmul job: data (rows, k) uint8 ->
@@ -171,12 +316,7 @@ class CodecService:
         if data.ndim != 2 or mat.ndim != 2 or data.shape[0] != mat.shape[1]:
             raise ValueError(
                 f"matmul shape mismatch: mat {mat.shape} @ data {data.shape}")
-        k = data.shape[1]
-        kb = bucket_len(k)
-        job = _Job("matmul", data.shape[0], mat.shape[0],
-                   _pad_to_bucket(data, k, kb), k, kb, plan=rs.MatrixPlan(mat))
-        self._submit(job)
-        return job.future
+        return self._job("matmul", data, mat.shape[0], plan=rs.MatrixPlan(mat))
 
     def encode_tactic(self, t, data: np.ndarray) -> Future:
         """data (N, k) uint8 -> Future[(total, k) full stripe], local parities
@@ -192,40 +332,14 @@ class CodecService:
 
         if data.shape[0] != t.N:
             raise ValueError(f"want {t.N} data rows, got {data.shape}")
-        # snapshot ONCE (explicit copy) and build the result from the same
-        # snapshot the job computed parity from — caller-side dtype changes or
-        # post-submit mutation must never yield a stripe whose data rows don't
-        # match its parity
-        data = np.array(data, np.uint8, order="C")
-        mat = lrc_parity_matrix(t)
-        k = data.shape[1]
-        kb = bucket_len(k)
-        job = _Job("matmul", t.N, t.M + t.L, _pad_to_bucket(data, k, kb),
-                   k, kb, plan=rs.MatrixPlan(mat))
-        self._submit(job)
-        out = _ChainFuture(job.future)
-
-        def _finish(f: Future):
-            if f.cancelled() or out.cancelled():
-                # cancelled upstream (drain handshake dropped the job) or
-                # downstream (pipeline abort): nothing to deliver
-                return
-            try:
-                if f.exception():
-                    out.set_exception(f.exception())
-                else:
-                    out.set_result(
-                        np.concatenate([data, f.result()], axis=0))
-            except InvalidStateError:
-                pass  # out.cancel() raced the delivery: outcome discarded
-
-        job.future.add_done_callback(_finish)
-        return out
+        return self._job("matmul", data, t.M + t.L, whole=True,
+                         plan=rs.MatrixPlan(lrc_parity_matrix(t)))
 
     def _encode_pm(self, t, data: np.ndarray) -> Future:
-        """Product-matrix encode: shard rows reshaped (free) to sub-unit
-        rows, parity block applied as one matmul, parity rows reshaped back
-        to shards. Same snapshot discipline as the LRC path."""
+        """Product-matrix encode: the stripe's sub-unit rows are the job (the
+        parity block applied as one matmul over them), and the stripe is its
+        (N + M) * sub_units result rows seen as shards again: a view where a
+        sub-unit row is as wide as its bucket, else numpy lays it out anew."""
         from chubaofs_tpu.codec import pm
 
         if data.shape[0] != t.N:
@@ -234,21 +348,24 @@ class CodecService:
         if size % t.sub_units:
             raise ValueError(
                 f"shard size {size} not a multiple of sub_units={t.sub_units}")
-        data = np.array(data, np.uint8, order="C")
         kernel = pm.get_kernel(t.total, t.N)
-        f = self.matmul(kernel.parity_mat,
-                        data.reshape(t.N * t.sub_units, -1))
+        # (N, size) -> (N * sub_units, size / sub_units): a copy only where
+        # data's rows are not back to back (a lent slot's are a bucket apart)
+        sub = np.asarray(data, np.uint8).reshape(t.N * t.sub_units, -1)
+        f = self._job("matmul", sub, t.M * t.sub_units, whole=True,
+                      plan=rs.MatrixPlan(np.asarray(kernel.parity_mat, np.uint8)))
         out = _ChainFuture(f)
 
         def _finish(fut: Future):
             if fut.cancelled() or out.cancelled():
+                # cancelled upstream (drain handshake dropped the job) or
+                # downstream (pipeline abort): nothing to deliver
                 return
             try:
                 if fut.exception():
                     out.set_exception(fut.exception())
                 else:
-                    parity = fut.result().reshape(t.M, size)
-                    out.set_result(np.concatenate([data, parity], axis=0))
+                    out.set_result(fut.result().reshape(t.total, size))
             except InvalidStateError:
                 pass  # out.cancel() raced the delivery: outcome discarded
 
@@ -307,12 +424,9 @@ class CodecService:
             f: Future = Future()
             f.set_result(np.array(shards, copy=True))
             return f
-        k = shards.shape[1]
-        kb = bucket_len(k)
-        survivors = _pad_to_bucket(
-            np.asarray(shards, np.uint8)[np.asarray(present)], k, kb)
-        job = _Job("matmul", n, m, survivors, k, kb, plan=plan, held=held)
-        self._submit(job)
+        shards = np.asarray(shards, np.uint8)
+        job_f = self._job("matmul", self.slot_of([shards[i] for i in present]),
+                          len(missing), plan=plan, held=held)
 
         out_future: Future = Future()
 
@@ -325,7 +439,7 @@ class CodecService:
             fixed[np.asarray(missing)] = rows
             out_future.set_result(fixed)
 
-        job.future.add_done_callback(_finish)
+        job_f.add_done_callback(_finish)
         return out_future
 
     def decode_rows(self, n: int, m: int, present: list[int],
@@ -347,8 +461,7 @@ class CodecService:
         if survivors.ndim != 2 or survivors.shape[0] != n:
             raise ValueError(
                 f"want ({n}, w) survivors, got {survivors.shape}")
-        k = survivors.shape[1]
-        kb = bucket_len(k)
+        kb = bucket_len(survivors.shape[1])
         # a row count that has not run here rides the narrowest wider family
         # that has (zero rows padded on, the result cut back) rather than
         # compile its own under a request: a rebuild heals a stripe two rows
@@ -359,7 +472,7 @@ class CodecService:
         rows = plan.mat.shape[0]
         ran = self._decode_rows_run.setdefault((n, kb), set())
         if rows and rows not in ran:
-            wider = min((r for r in ran if r > rows), default=rows)
+            wider = min((r for r in tuple(ran) if r > rows), default=rows)  # submitters race
             if wider > rows:
                 # a padded COPY (the held matrix is never written), found by
                 # its content: its operand is as resident as any other
@@ -367,10 +480,7 @@ class CodecService:
                     [plan.mat, np.zeros((wider - rows, n), np.uint8)]))
             else:
                 ran.add(rows)
-        job = _Job("matmul", n, m, _pad_to_bucket(survivors, k, kb),
-                   k, kb, plan=plan, held=held, rows=rows)
-        self._submit(job)
-        return job.future
+        return self._job("matmul", survivors, rows, plan=plan, held=held)
 
     def close(self):
         """Idempotent shutdown; jobs enqueued after close() fail fast, jobs
@@ -444,30 +554,32 @@ class CodecService:
                     if job is not None and not job.future.done():
                         job.future.set_exception(RuntimeError("CodecService closed"))
                 return
-            if not batch:
-                continue
-            # honor caller-side cancellation (pipeline aborts drop their
-            # encode-ahead jobs): a cancelled job is skipped before any
-            # device work, and the running-handshake means a later cancel()
-            # fails cleanly instead of racing set_result
-            batch = [j for j in batch
-                     if j.future.set_running_or_notify_cancel()]
-            if not batch:
-                continue
-            # group by compatible shape signature (kb was bucketed at
-            # submission; the drain loop never re-derives shapes). The plan's
-            # key is the matrix's CONTENT, made once with the plan: only jobs
-            # with the identical matrix share a batch
-            groups: dict[tuple, list[_Job]] = {}
-            for j in batch:
-                groups.setdefault((j.kind, j.plan.key, j.kb), []).append(j)
-            for sig, jobs in groups.items():
-                try:
-                    self._run_group(sig, jobs)
-                except Exception as e:  # propagate to every waiter
-                    for j in jobs:
-                        if not j.future.done():
-                            j.future.set_exception(e)
+            self._dispatch(batch)
+            # the dispatcher holds no job while it waits for the next: a
+            # dropped job's slot must not outlive its last view by a poll
+            del batch
+
+    def _dispatch(self, batch: list[_Job]) -> None:
+        # honor caller-side cancellation (pipeline aborts drop their
+        # encode-ahead jobs): a cancelled job is skipped before any
+        # device work, and the running-handshake means a later cancel()
+        # fails cleanly instead of racing set_result
+        batch = [j for j in batch
+                 if j.future.set_running_or_notify_cancel()]
+        # group by compatible shape signature (kb was bucketed at
+        # submission; the drain loop never re-derives shapes). The plan's
+        # key is the matrix's CONTENT, made once with the plan: only jobs
+        # with the identical matrix share a batch
+        groups: dict[tuple, list[_Job]] = {}
+        for j in batch:
+            groups.setdefault((j.kind, j.plan.key, j.kb), []).append(j)
+        for sig, jobs in groups.items():
+            try:
+                self._run_group(sig, jobs)
+            except Exception as e:  # propagate to every waiter
+                for j in jobs:
+                    if not j.future.done():
+                        j.future.set_exception(e)
 
     def stats_snapshot(self) -> dict:
         """Consistent copy of the legacy counters (no torn reads)."""
@@ -505,11 +617,22 @@ class CodecService:
         for j in jobs:
             trace.observe_stage("codec.queue_wait", j.t_submit,
                                 t0 - j.t_submit, span=j.span)
-        # jobs arrive pre-padded to the bucket: stacking is the whole job
-        # here, and one job is launched where it lies (a view, no copy)
+        # one job is launched where it lies (its slot's input rows, a view);
+        # two and more are copied into a batch buffer out of the pool, with
+        # room for the next power of two of them (few shapes, their pages
+        # mapped by the batches before). Columns past a job's k carry whatever
+        # the buffers held: no result column depends on another, and every
+        # result is cut back to k
+        n, kb = jobs[0].n, jobs[0].kb
         with trace.stage("codec.stack"):
-            stack = (jobs[0].data[None] if len(jobs) == 1
-                     else np.stack([j.data for j in jobs]))
+            if len(jobs) == 1:
+                stack = jobs[0].buf[None, :n]
+            else:
+                room = 1 << (len(jobs) - 1).bit_length()
+                stack = self._pool.take(
+                    "batch", (room, n, kb), self.max_batch)[: len(jobs)]
+                for i, j in enumerate(jobs):
+                    stack[i, :, : j.k] = j.buf[:n, : j.k]
         t_mm = time.perf_counter()
         # both paths go through the host-boundary grouped entry: batches of
         # stripes are viewed (free numpy reshape) as MXU-row-filling groups
@@ -520,17 +643,7 @@ class CodecService:
         plan = jobs[0].plan
         mesh = self._mesh_mm
         ready = plan.ready(len(jobs)) if mesh is None else plan.expanded
-
-        def mm():
-            if mesh is None:
-                return rs.gf_matmul_hostbatch(plan, stack)
-            return mesh(plan.bits(), stack)
-
-        if sig[0] == "encode":
-            parity = mm()
-            with trace.stage("codec.concat"):
-                out = np.concatenate([stack, parity], axis=1)  # (B, n+m, kb)
-        else:
+        if sig[0] != "encode":
             # the matrix's bits, where this launch has to make its operand
             # from them: a dozen small numpy calls, each a chance to hand the
             # interpreter lock over, so they have a name of their own;
@@ -538,7 +651,17 @@ class CodecService:
             with trace.stage("codec.expand"):
                 if not ready:
                     plan.bits()
-            out = mm()
+        if mesh is None:
+            out = rs.gf_matmul_hostbatch(plan, stack)
+        else:
+            out = mesh(plan.bits(), stack)
+        # a stripe's parity rows go into the tail rows of its own slot: the
+        # future gets a view of the slot, and nothing is concatenated. Rows
+        # asked for alone (a decode's) are handed out where they were fetched
+        with trace.stage("codec.concat"):
+            for i, j in enumerate(jobs):
+                if j.whole:
+                    j.buf[n: n + j.rows, : j.k] = out[i, : j.rows, : j.k]
         t_done = time.perf_counter()
         with trace.stage("codec.deliver"):  # bookkeeping, then the results
             self._record_batch(len(jobs), t_done - t0, kind=str(sig[0]),
@@ -555,7 +678,8 @@ class CodecService:
                     j.span.add_stage("codec.matmul", start=t_mm,
                                      dur=t_done - t_mm)
             for i, j in enumerate(jobs):
-                j.future.set_result(out[i, : j.rows, : j.k])
+                j.future.set_result(j.buf[: n + j.rows, : j.k] if j.whole
+                                    else out[i, : j.rows, : j.k])
 
 
 _default: CodecService | None = None

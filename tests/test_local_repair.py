@@ -105,7 +105,8 @@ def test_configuration_is_az2_with_one_disk_broken_in_each_az():
     reported = {m["name"] for g in ("end_to_end", "per_layer") for m in bench[g]
                 if "workloads" not in m or cell["name"] in m["workloads"]}
     lrc = {m["name"] for m in bench["per_layer"] if m["name"].startswith("lrc_")}
-    assert len(lrc) == 19 and lrc | {"get_MBps", "setup_s"} == reported
+    # and, since PR 46, the one shared metric that lists this cell: the codec pool's reuse share
+    assert len(lrc) == 19 and lrc | {"get_MBps", "setup_s", "get_codec_buffer_reuse_share"} == reported
     assert all(m["workloads"] == [cell["name"]] and m["moves"] == "get_MBps"
                for m in bench["per_layer"] if m["name"] in lrc)
 

@@ -389,8 +389,9 @@ def test_cancelled_one_job_batch_does_no_device_work(monkeypatch):
 @pytest.mark.parametrize("kind,expect", [
     ("encode", ["codec.stack", "hostbatch.group", "hostbatch.launch", "hostbatch.fetch",
                 "codec.concat", "codec.deliver"]),
+    # codec.concat is entered for every kind of job (an encode's parity goes into its slot there)
     ("decode_rows", ["codec.stack", "codec.expand", "hostbatch.group", "hostbatch.launch",
-                     "hostbatch.fetch", "codec.deliver"]),
+                     "hostbatch.fetch", "codec.concat", "codec.deliver"]),
 ])
 def test_dispatcher_stage_list_and_order_are_the_same_on_a_hit(monkeypatch, kind, expect):
     """A miss (the first batch of a matrix) and a hit enter the same stages in
